@@ -58,6 +58,9 @@ def main(argv=None) -> int:
     if args.quick:
         os.environ["ASH_BENCH_QUICK"] = "1"
     sys.path.insert(0, "src")
+    from repro import compile_cache
+
+    compile_cache.enable()
     from benchmarks import kernel_bench, paper_figures, serving_bench
 
     groups = (
@@ -127,6 +130,12 @@ def main(argv=None) -> int:
         elif args.quick or args.filter:
             print("# quick/filtered run: snapshot files skipped "
                   "(pass --json OUT for a combined file)")
+    errored = [r["name"] for rows in results.values() for r in rows
+               if r["error"] is not None]
+    if errored:
+        print(f"# {len(errored)} benchmark(s) errored: "
+              f"{', '.join(errored)}", file=sys.stderr)
+        return 1
     return 0
 
 

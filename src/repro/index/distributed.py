@@ -174,16 +174,7 @@ def _make_searcher(
         in_specs=(P(axes), P(axes), P(axes), P(axes), P()),
         out_specs=(P(), P()),
     )
-    if hasattr(jax, "shard_map"):  # jax >= 0.6: top-level, check_vma
-        fn = jax.shard_map(
-            local_then_merge, mesh=mesh, check_vma=False, **specs
-        )
-    else:
-        from jax.experimental.shard_map import shard_map
-
-        fn = shard_map(
-            local_then_merge, mesh=mesh, check_rep=False, **specs
-        )
+    fn = jax.shard_map(local_then_merge, mesh=mesh, check_vma=False, **specs)
     jitted = jax.jit(fn)
 
     def search(payload, queries, stats=None, raw=None, valid=None):

@@ -28,10 +28,21 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import quantization as Q
-from repro.kernels.ash_score import _unpack_block
 
 DEFAULT_BLOCK_S = 512
 _NEG_INF = -1e30
+
+
+def _unpack_block(words: jax.Array, b: int, compute_dtype) -> jax.Array:
+    """(s_blk, w_blk) uint32 -> (s_blk, w_blk * 32//b) grid values."""
+    k = 32 // b
+    shifts = (jnp.arange(k, dtype=jnp.uint32) * b).astype(jnp.uint32)
+    mask = jnp.uint32(2**b - 1)
+    grouped = (words[:, :, None] >> shifts[None, None, :]) & mask
+    levels = grouped.reshape(words.shape[0], -1)
+    return (
+        2 * levels.astype(jnp.int32) - (2**b - 1)
+    ).astype(compute_dtype)
 
 
 def _kernel(

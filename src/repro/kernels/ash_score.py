@@ -2,13 +2,13 @@
 
 The TPU adaptation of the paper's AVX-512 Code 1 (see DESIGN.md §2):
 batched scoring of m queries against n packed ASH codes is a dense
-matmul, so every kernel in this file
+matmul, so every dense kernel in this file
 
-  1. streams packed uint32 code words HBM -> VMEM one (n_blk, w_blk)
+  1. streams packed uint32 code words HBM -> VMEM one (w_blk, n_blk)
      tile at a time (codes never exist unpacked in HBM: 32/b codes per
      word, a 16x-32x traffic reduction vs fp32 vectors);
-  2. unpacks in-register (shift/mask -> odd-integer grid values, bf16);
-  3. feeds the MXU: acc += q_tile (m_blk, d_blk) @ codes_tile^T;
+  2. unpacks in-register (shift/mask -> odd-integer grid values);
+  3. feeds the MXU: acc += q_tile (m_blk, d_blk) @ values (d_blk, n_blk);
   4. on the last reduction step applies a metric epilogue over the
      accumulated Eq. (20) base score, entirely in VMEM.
 
@@ -16,60 +16,69 @@ Metric epilogues (``metric=``) — all emit HIGHER-IS-BETTER scores:
 
   dot   base = acc * SCALE + one_hot(cluster) lookup of <q, mu_c>
              + OFFSET            (Eq. 20; the landmark lookup is itself
-             an MXU-friendly one-hot matmul, C <= 256)
+             an MXU one-hot matmul)
   l2    2 * base - ||q||^2 - L2CONST_i          == -||q - x_i||^2
   cos   base * (1/||q||) * (1/||x_i||)          (Eq. A.5 norm estimate)
 
-The l2/cos row constants (``L2CONST_i = ||x-mu*||^2 + 2<x,mu*> -
-||mu*||^2`` and the Eq. A.5 inverse norm) are query-independent and
-recovered once at encode/build time into an ``ASHStats`` structure (see
-``repro.core.types``), so neither metric ever unpacks the database in
-HBM — they are pure per-tile epilogues over the same packed-code MXU
-accumulation as dot.
+The l2/cos row constants are query-independent and recovered once at
+encode/build time into an ``ASHStats`` structure (``repro.core.types``),
+so neither metric ever unpacks the database in HBM.
+
+Chip layout rules every kernel here follows (the TPU compiler refuses
+a block whose last two dimensions are neither (8, 128)-aligned nor
+equal to the array's, and lays every array out in (8, 128) tiles):
+
+  * Codes are read LANE-DENSE, as the (W, n) transpose of the stored
+    (n, W) array.  XLA keeps a narrow (n, W) array column-major, so the
+    transpose is a free view; a row-major (n, W) kernel operand would
+    instead force a copy padded to 128 lanes (512 B/row for W=4, 32x the
+    codes).  Blocks are (w_blk, n_blk), n_blk a multiple of 128; the
+    last n block may be ragged (its columns are masked or dropped), so
+    the codes are never padded or copied per call.
+  * Per-row vectors ride as (1, n) rows, per-query vectors as (m, 1)
+    columns.
+  * Fused selection writes its candidate strip TRANSPOSED: candidates
+    on sublanes, queries on lanes, one (k̃₈, m_blk) block per tile
+    (k̃₈ = k̃ rounded up to 8) — a (m_blk, k̃) block with k̃ = 10 would
+    be refused, and padding k̃ to 128 lanes would cost 12.8x the
+    candidate bytes.
 
 Fused selection (:func:`ash_score_topk_pallas`): instead of writing the
 (m, n) score matrix back to HBM and running a separate ``top_k`` pass,
 each (m_blk, n_blk) output tile keeps only its partial top-k̃ of
-(score, global id) pairs — an iterative VPU max/argmax sweep in VMEM —
-and the kernel emits a (m, n_blocks * k̃) candidate strip merged by one
-small final two-key sort on the host side of the call.  HBM traffic for
-selection drops from O(m·n) fp32 to O(m · n/block_n · k̃).
+(score, global id) pairs — k̃ VPU max/argmax sweeps in VMEM — and one
+small ``lax.top_k`` merges the strip.  HBM traffic for selection drops
+from O(m·n) fp32 to O(m · n/block_n · k̃).
 
-  * k̃ accuracy/VMEM trade-off: results are EXACTLY the materialized
-    ``lax.top_k`` (values and indices, including tie order) whenever
-    k <= k̃, because a row's global rank-r element ranks <= r inside
-    its own tile.  k̃ < k trades exactness for a smaller candidate
-    strip and fewer selection sweeps (recall-style operation; the
-    routed index paths never do this).  Cost: k̃ VPU sweeps over each
-    tile + 2 * k̃ * n/block_n fp32+int32 VMEM per query row.
+  * Results are EXACTLY the materialized ``lax.top_k`` (values and
+    indices, including tie order) whenever k <= k̃, because a row's
+    global rank-r element ranks <= r inside its own tile.  k̃ < k trades
+    exactness for a smaller strip (recall-style; routed index paths
+    never do this).
   * Ties follow the ``lax.top_k`` convention (lowest id first): tiles
-    select by (score desc, id asc) and the merge sorts candidates with
-    a two-key ``lax.sort`` on (-score, id).
-  * Rows beyond the real n (block padding) are masked to -inf inside
-    the kernel; exhausted tiles emit int32-max sentinel ids which the
-    merge maps to -1 (they can only surface when k exceeds the number
-    of candidates actually emitted, i.e. never for k <= min(n, k̃)).
+    select by (score desc, id asc) and the merge keeps the lowest strip
+    position, which is the lowest id.
+  * Invalid rows (ragged block tail, masked rows) never surface;
+    exhausted and padding slots emit int32-max sentinel ids which the
+    merge maps to -1.
 
 Masked-gather variants (:func:`ash_score_gather_pallas`,
 :func:`ash_score_gather_topk_pallas`): the same epilogues and fused
-selection over PER-QUERY candidate lists (IVF partial probes) instead
-of a dense row range.  The candidate row ids arrive as a
-scalar-prefetch operand (``pltpu.PrefetchScalarGridSpec``) so the
-kernel DMA-gathers each candidate's PACKED code word strip HBM -> VMEM
-directly — neither the unpacked codes nor the (m, R) score matrix ever
-exist in HBM, only the 16x-32x-compressed words of the rows actually
-probed move.  Pad entries (row id -1) are masked to ``-inf`` in the
-epilogue.  The dense selection kernel absorbs row-validity masking the
-same way: a runtime (1, n) int32 mask operand folds the sharded
-backend's ``n_real`` pad truncation AND the index layers' tombstone
-(deleted-row) bitmap into the kernel's id masking, so one compiled
-program serves every shard of a shard_map and every mutation state
-(deletes never recompile; the gather path instead drops tombstoned ids
-from the candidate lists before any DMA is issued).
+selection over PER-QUERY candidate lists (IVF partial probes).  XLA
+gathers the probed rows' PACKED words (W words per candidate) and
+header values into per-query, lane-dense (m, ·, R) operands; the kernel
+unpacks and scores eight queries per grid step on the VPU (each query
+has its own candidates, so the product is a batched mat-vec) and fuses
+the selection.  Neither the unpacked codes nor the (m, R) score matrix
+reach HBM.  Pad entries (row id -1) are masked to ``-inf``.
 
-Grid: (n_blocks, m_blocks, d_blocks), d innermost for accumulation in a
-VMEM fp32 scratch tile; the gather variants use (m, r_blocks, d_blocks)
-— one query per row step, since each query gathers its own candidates.
+Row-validity masking: a runtime (1, n) int32 mask operand folds the
+sharded backend's ``n_real`` pad truncation AND the tombstone bitmap
+into the dense selection kernel's id masking, so one compiled program
+serves every shard and every mutation state.
+
+Grids: dense (n_blocks, m_blocks, d_blocks); gather (m/8, r_blocks,
+d_blocks); d innermost for accumulation in a VMEM scratch tile.
 """
 from __future__ import annotations
 
@@ -77,39 +86,53 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import quantization as Q
 
 DEFAULT_BLOCK_M = 128
-DEFAULT_BLOCK_N = 512
+DEFAULT_BLOCK_N = 2048
 DEFAULT_BLOCK_D = 512
+DEFAULT_BLOCK_R = 512
+_GATHER_BLOCK_Q = 8  # queries per gather grid step (one f32 sublane tile)
 
 METRICS = ("dot", "l2", "cos")
 _ID_SENTINEL = jnp.iinfo(jnp.int32).max
+# quiet NaN with the sign bit set (0xFFC00000); numpy, so it is an HLO
+# literal and never a device array captured by the kernels' traces
+_NEG_NAN = np.array(-0x400000, np.int32).view(np.float32)
 
 
-def _unpack_block(words: jax.Array, b: int, compute_dtype) -> jax.Array:
-    """(n_blk, w_blk) uint32 -> (n_blk, w_blk * 32//b) grid values."""
+def _round_up(x: int, mult: int) -> int:
+    return ((x + mult - 1) // mult) * mult
+
+
+def _unpack_lanes(words: jax.Array, b: int, compute_dtype) -> jax.Array:
+    """(..., w_blk, n_blk) uint32 -> (..., w_blk * 32//b, n_blk) grid
+    values, lane-dense: value row ``w * 32//b + s`` is code ``s`` of
+    word ``w`` (the ``Q.unpack_codes`` order, transposed)."""
     k = 32 // b
-    shifts = (jnp.arange(k, dtype=jnp.uint32) * b).astype(jnp.uint32)
-    mask = jnp.uint32(2**b - 1)
-    grouped = (words[:, :, None] >> shifts[None, None, :]) & mask
-    levels = grouped.reshape(words.shape[0], -1)
+    shifts = (
+        jax.lax.broadcasted_iota(jnp.int32, (k, 1), 0) * b
+    ).astype(jnp.uint32)
+    grouped = (words[..., None, :] >> shifts) & jnp.uint32(2**b - 1)
+    levels = grouped.reshape(
+        words.shape[:-2] + (words.shape[-2] * k, words.shape[-1])
+    )
     return (
         2 * levels.astype(jnp.int32) - (2**b - 1)
     ).astype(compute_dtype)
 
 
-def _accumulate(q_ref, codes_ref, acc_ref, *, b, compute_dtype):
-    """acc += q_tile @ unpack(codes_tile)^T — shared matmul prologue."""
-    vals = _unpack_block(codes_ref[...], b, compute_dtype)  # (n_blk, d_blk)
-    q = q_ref[...].astype(compute_dtype)
-    acc_ref[...] += jax.lax.dot_general(
-        q,
-        vals,
-        (((1,), (1,)), ((), ())),
+def _mxu(a: jax.Array, b: jax.Array) -> jax.Array:
+    """a (M, K) @ b (K, N) -> f32.  f32 operands run at full precision
+    (the chip's default rounds them to bf16 first)."""
+    precise = a.dtype == jnp.float32 and b.dtype == jnp.float32
+    return jax.lax.dot_general(
+        a, b, (((1,), (0,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST if precise else None,
         preferred_element_type=jnp.float32,
     )
 
@@ -119,24 +142,19 @@ def _bias_lookup(cluster_ref, ipq_ref):
     (exactly one non-zero term per column, so it is bitwise equal to the
     oracle's gather)."""
     C = ipq_ref.shape[1]
-    cl = cluster_ref[0, :]  # (n_blk,)
     onehot = (
-        cl[:, None] == jax.lax.broadcasted_iota(jnp.int32, (1, C), 1)
-    ).astype(jnp.float32)  # (n_blk, C)
-    return jax.lax.dot_general(
-        ipq_ref[...].astype(jnp.float32),
-        onehot,
-        (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )  # (m_blk, n_blk)
+        jax.lax.broadcasted_iota(jnp.int32, (C, 1), 0) == cluster_ref[...]
+    ).astype(jnp.float32)  # (C, n_blk)
+    return _mxu(ipq_ref[...].astype(jnp.float32), onehot)
 
 
-def _metric_tail(base, qterm_ref, rowterm_ref, metric):
-    """Shared l2/cos epilogue over an Eq. (20) base-score tile."""
+def _epilogue(dot, scale, bias, offset, qcol, rrow, metric):
+    """Eq. (20) base score + metric tail, broadcasting (m_blk, 1)
+    query columns against (1, n_blk) or (m_blk, n_blk) row terms.  The
+    op order is mirrored by the oracles in ``ref.py``."""
+    base = dot * scale + bias + offset
     if metric == "dot":
         return base
-    qcol = qterm_ref[0, :].astype(jnp.float32)[:, None]  # (m_blk, 1)
-    rrow = rowterm_ref[0, :].astype(jnp.float32)[None, :]  # (1, n_blk)
     if metric == "l2":
         return (2.0 * base - qcol) - rrow  # == -||q - x||^2
     if metric == "cos":
@@ -144,218 +162,267 @@ def _metric_tail(base, qterm_ref, rowterm_ref, metric):
     raise ValueError(metric)
 
 
-def _epilogue_scores(
-    acc, scale_ref, offset_ref, cluster_ref, ipq_ref, qterm_ref,
-    rowterm_ref, *, metric,
-):
-    """Tile scores (m_blk, n_blk) fp32 from the accumulated DOT-PROD.
-
-    The exact op order here is mirrored by ``ref.ash_score_metric_ref``
-    so compiled/interpreted kernels and the jnp oracle agree to the
-    reduction-order level.
-    """
-    bias = _bias_lookup(cluster_ref, ipq_ref)
-    base = (
-        acc * scale_ref[0, :][None, :].astype(jnp.float32)
-        + bias
-        + offset_ref[0, :][None, :].astype(jnp.float32)
-    )
-    return _metric_tail(base, qterm_ref, rowterm_ref, metric)
-
-
-def _kernel(
-    q_ref,  # (m_blk, d_blk)
-    codes_ref,  # (n_blk, w_blk) uint32
-    scale_ref,  # (1, n_blk)
-    offset_ref,  # (1, n_blk)
-    cluster_ref,  # (1, n_blk) int32
-    ipq_ref,  # (m_blk, C)
-    qterm_ref,  # (1, m_blk) metric query term (zeros for dot)
-    rowterm_ref,  # (1, n_blk) metric row term (zeros for dot)
-    out_ref,  # (m_blk, n_blk)
-    acc_ref,  # scratch (m_blk, n_blk) fp32
-    *,
-    b: int,
-    n_d_blocks: int,
-    compute_dtype,
-    metric: str,
-):
-    k_idx = pl.program_id(2)
-
-    @pl.when(k_idx == 0)
-    def _zero():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    _accumulate(q_ref, codes_ref, acc_ref, b=b, compute_dtype=compute_dtype)
-
-    @pl.when(k_idx == n_d_blocks - 1)
-    def _epilogue():
-        out_ref[...] = _epilogue_scores(
-            acc_ref[...], scale_ref, offset_ref, cluster_ref, ipq_ref,
-            qterm_ref, rowterm_ref, metric=metric,
-        ).astype(out_ref.dtype)
-
-
 def _select_topk(scores, valid, col0, k_tilde, vals_ref, ids_ref):
     """Per-tile partial top-k̃ of ``scores`` (m_blk, n_blk) into the
-    (m_blk, k_tilde) output refs; shared by the dense and gather
-    selection kernels.
+    transposed (1, k̃₈, m_blk) strip blocks ``vals_ref``/``ids_ref``.
 
-    Iterative partial top-k̃: k̃ VPU max sweeps over the tile, ties to
-    the LOWEST id (the lax.top_k convention) via a min over the argmax
-    candidate set.  ``valid`` (not a -inf re-mask) tracks taken columns
-    so rows whose scores are genuinely -inf are still emitted once
-    each, in ascending-id order; invalid columns (block padding, masked
-    rows, gather pad ids) never surface.  Emitted ids are
-    ``col0 + column``; exhausted tiles emit the int32-max sentinel.
+    k̃ VPU max sweeps over the tile, ties to the LOWEST id (the lax.top_k
+    convention) via a min over the argmax candidate set.  ``valid``
+    (not a -inf re-mask) tracks taken columns so rows whose scores are
+    genuinely -inf are still emitted once each, in ascending-id order;
+    invalid columns never surface.  Emitted ids are ``col0 + column``;
+    exhausted sweeps and the k̃ -> k̃₈ padding slots emit -inf / the
+    int32-max sentinel.
     """
+    m_blk, n_blk = scores.shape
+    kt_pad = vals_ref.shape[1]
+    slots = _round_up(kt_pad, 128)  # lane-dense in-register accumulator
     local = jax.lax.broadcasted_iota(jnp.int32, scores.shape, 1)
+    slot = jax.lax.broadcasted_iota(jnp.int32, (m_blk, slots), 1)
     neg_inf = jnp.float32(-jnp.inf)
-    n_blk = scores.shape[1]
-    for t in range(k_tilde):
-        masked = jnp.where(valid, scores, neg_inf)
-        best = jnp.max(masked, axis=1)  # (m_blk,)
-        cand = jnp.where(
-            valid & (masked == best[:, None]), local, n_blk
-        )
-        bid = jnp.min(cand, axis=1)  # n_blk == tile exhausted
+
+    def sweep(t, carry):
+        avail, out_v, out_i = carry
+        ok = avail != 0
+        masked = jnp.where(ok, scores, neg_inf)
+        best = jnp.max(masked, axis=1, keepdims=True)  # (m_blk, 1)
+        cand = jnp.where(ok & (masked == best), local, n_blk)
+        bid = jnp.min(cand, axis=1, keepdims=True)  # n_blk == exhausted
         has = bid < n_blk
-        vals_ref[:, t] = jnp.where(has, best, neg_inf)
-        ids_ref[:, t] = jnp.where(has, bid + col0, _ID_SENTINEL)
-        valid = valid & (local != bid[:, None])
+        here = slot == t
+        out_v = jnp.where(here, jnp.where(has, best, neg_inf), out_v)
+        out_i = jnp.where(
+            here, jnp.where(has, bid + col0, _ID_SENTINEL), out_i
+        )
+        return jnp.where(local == bid, 0, avail), out_v, out_i
+
+    init = (
+        valid.astype(jnp.int32),
+        jnp.full((m_blk, slots), neg_inf, jnp.float32),
+        jnp.full((m_blk, slots), _ID_SENTINEL, jnp.int32),
+    )
+    _, out_v, out_i = jax.lax.fori_loop(0, k_tilde, sweep, init)
+    vals_ref[0] = out_v.T[:kt_pad]
+    ids_ref[0] = out_i.T[:kt_pad]
 
 
-def _topk_kernel(
-    q_ref,
-    codes_ref,
-    scale_ref,
-    offset_ref,
-    cluster_ref,
-    ipq_ref,
-    qterm_ref,
-    rowterm_ref,
-    *rest,  # [mask_ref,] vals_ref, ids_ref, acc_ref — see use_mask
+def _merge_strip(vals, ids, m: int, k: int):
+    """Merge a (m_blocks, N, m_blk) transposed candidate strip into the
+    top-k (scores, ids), each (m, k), in (score desc, id asc) order —
+    bit-equal to ``lax.top_k`` over the materialized row.
+
+    One ``lax.top_k``: along the strip, tiles ascend in id and each
+    tile emits equal scores in ascending id, so top_k's lowest-position
+    tie rule IS the lowest-id rule.  Sentinel slots are keyed -NaN,
+    which top_k's total float order puts below every score, ``-inf``
+    included, so they never displace a real row.  (A two-key sort says
+    the same but takes ~30 s to compile for the chip, and an int32 key
+    ~15 s; float top_k compiles in well under one.)"""
+    mb, N, bm = vals.shape
+    vals = vals.transpose(0, 2, 1).reshape(mb * bm, N)[:m]
+    ids = ids.transpose(0, 2, 1).reshape(mb * bm, N)[:m]
+    _, pos = jax.lax.top_k(jnp.where(ids == _ID_SENTINEL, _NEG_NAN, vals), k)
+    return (
+        jnp.take_along_axis(vals, pos, axis=1),
+        jnp.take_along_axis(ids, pos, axis=1),
+    )
+
+
+def _strip_outputs(k, k_tilde, tile, n_tiles, q_blocks, q_blk, index_map):
+    """(k̃, out_specs, out_shape) of a fused-selection call: one
+    (1, k̃₈, q_blk) vals/ids block per tile of ``tile`` candidates."""
+    kt = min(k if k_tilde is None else k_tilde, tile)
+    if k > n_tiles * kt:
+        raise ValueError(
+            f"k={k} exceeds the {n_tiles} x k_tilde={kt} candidate "
+            f"strip; raise k_tilde or use the materializing kernel"
+        )
+    kt_pad = _round_up(kt, 8)
+    spec = pl.BlockSpec((1, kt_pad, q_blk), index_map)
+    shape = (q_blocks, n_tiles * kt_pad, q_blk)
+    return kt, [spec, spec], [
+        jax.ShapeDtypeStruct(shape, jnp.float32),
+        jax.ShapeDtypeStruct(shape, jnp.int32),
+    ]
+
+
+# ---------------------------------------------------------------------------
+# Dense kernels (asymmetric and symmetric-int8 coarse, score and select)
+# ---------------------------------------------------------------------------
+
+
+def _dense_kernel(
+    *refs,
     b: int,
     n_d_blocks: int,
     compute_dtype,
     metric: str,
-    k_tilde: int,
+    coarse: bool,
+    k_tilde: int | None,
     block_n: int,
     n_real: int,
     use_mask: bool,
 ):
-    # refs after the shared operand block depend on the masking mode:
-    #   use_mask:  mask_ref (1, n_blk) int32 runtime row-validity
-    #              (0 = masked), then vals/ids outputs + acc scratch
-    #   else:      vals/ids outputs + acc scratch only — validity is
-    #              the static block-padding predicate col < n_real
-    if use_mask:
-        mask_ref, vals_ref, ids_ref, acc_ref = rest
-    else:
-        vals_ref, ids_ref, acc_ref = rest
-    k_idx = pl.program_id(2)
+    """One body for the four dense kernels.  Refs, in order:
+    q (m_blk, d_blk), codes (w_blk, n_blk), scale/offset/cluster
+    (1, n_blk), ipq (m_blk, C); [qterm (m_blk, 1), rowterm (1, n_blk)]
+    unless dot; [qscale, qcorr (m_blk, 1)] if coarse; [mask (1, n_blk)]
+    if use_mask; outputs — scores (m_blk, n_blk), or the vals/ids strip
+    blocks when ``k_tilde`` is set; the accumulator scratch."""
+    it = iter(refs)
+    q_ref, codes_ref, scale_ref, offset_ref, cluster_ref, ipq_ref = (
+        next(it) for _ in range(6)
+    )
+    qterm_ref = rowterm_ref = None
+    if metric != "dot":
+        qterm_ref, rowterm_ref = next(it), next(it)
+    if coarse:
+        qscale_ref, qcorr_ref = next(it), next(it)
+    mask_ref = next(it) if use_mask else None
+    outs = [next(it), next(it)] if k_tilde else [next(it)]
+    acc_ref = next(it)
+
+    kd = pl.program_id(2)
     # program_id must be read outside the pl.when body (interpret mode
     # lowers the body through lax.cond, where the primitive is absent)
     col0 = pl.program_id(0) * block_n
 
-    @pl.when(k_idx == 0)
+    @pl.when(kd == 0)
     def _zero():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    _accumulate(q_ref, codes_ref, acc_ref, b=b, compute_dtype=compute_dtype)
+    if coarse:
+        # int8 (b <= 4) or int32 (b = 8: grid values reach +-255)
+        # operands, exact int32 accumulation
+        dt = jnp.int8 if b <= 4 else jnp.int32
+        acc_ref[...] += jax.lax.dot_general(
+            q_ref[...].astype(dt), _unpack_lanes(codes_ref[...], b, dt),
+            (((1,), (0,)), ((), ())), preferred_element_type=jnp.int32,
+        )
+    else:
+        acc_ref[...] += _mxu(
+            q_ref[...].astype(compute_dtype),
+            _unpack_lanes(codes_ref[...], b, compute_dtype),
+        )
 
-    @pl.when(k_idx == n_d_blocks - 1)
-    def _select():
-        scores = _epilogue_scores(
-            acc_ref[...], scale_ref, offset_ref, cluster_ref, ipq_ref,
-            qterm_ref, rowterm_ref, metric=metric,
+    @pl.when(kd == n_d_blocks - 1)
+    def _finish():
+        bias = _bias_lookup(cluster_ref, ipq_ref)
+        dot = acc_ref[...]
+        if coarse:
+            dot = dot.astype(jnp.float32) * qscale_ref[...]
+            bias = bias + qcorr_ref[...]
+        scores = _epilogue(
+            dot, scale_ref[...], bias, offset_ref[...],
+            None if qterm_ref is None else qterm_ref[...],
+            None if rowterm_ref is None else rowterm_ref[...],
+            metric,
         )  # (m_blk, n_blk) fp32
+        if not k_tilde:
+            outs[0][...] = scores
+            return
+        local = jax.lax.broadcasted_iota(jnp.int32, scores.shape, 1)
+        # the ragged tail of the last block holds unspecified data
+        valid = (local + col0) < n_real
         if use_mask:
-            # the mask operand is a RUNTIME per-row validity vector
-            # folding three maskings into one id mask: block-padding
-            # columns beyond the real n (always 0 there), the sharded
-            # backend's per-shard n_real truncation, and tombstoned
-            # (deleted) rows — one compiled program serves every shard
-            # and every mutation state
-            valid = jnp.broadcast_to(mask_ref[...] != 0, scores.shape)
-        else:
-            # unmasked scan (no deletes, no sharding): block padding is
-            # the only invalid region and n is static — no operand
-            local = jax.lax.broadcasted_iota(jnp.int32, scores.shape, 1)
-            valid = (local + col0) < n_real
-        _select_topk(scores, valid, col0, k_tilde, vals_ref, ids_ref)
+            # runtime row validity: the sharded backend's per-shard
+            # n_real truncation and tombstoned rows, one operand for
+            # every shard and every mutation state
+            valid = valid & (mask_ref[...] != 0)
+        _select_topk(scores, valid, col0, k_tilde, *outs)
 
 
-def _pad_operands(
-    codes, q_proj, scale, offset, cluster, ip_q_landmarks, qterm, rowterm,
-    *, b, block_m, block_n, block_d,
+def _dense_call(
+    codes, q, scale, offset, cluster, ipq, qterm, rowterm, *,
+    b, metric, block_m, block_n, block_d, interpret, compute_dtype,
+    coarse_q=None, n_valid=None, row_valid=None, k=None, k_tilde=None,
 ):
-    """Pad every operand to block multiples; returns padded operands +
-    the (m_p, n_p, grid) geometry.  Scores for padded rows/cols are
-    sliced away (materializing kernel) or masked (selection kernel);
-    padded q columns are zero so they add nothing."""
-    n, Wd = codes.shape
-    m, d_pad = q_proj.shape
-    k = Q.codes_per_word(b)
-    assert Wd * k == d_pad, (Wd, k, d_pad)
+    """Shared wrapper of the dense kernels: materialized (m, n) scores,
+    or — with ``k`` — the merged fused top-k (scores, ids)."""
+    assert metric in METRICS, metric
+    n, W = codes.shape
+    m, d_pad = q.shape
+    kpw = Q.codes_per_word(b)
+    assert W * kpw == d_pad, (W, kpw, d_pad)
 
-    block_m = min(block_m, _round_up(m, 8))
-    block_n = min(block_n, _round_up(n, 128))
-    block_d = min(block_d, d_pad)
-    assert block_d % k == 0
-    block_w = block_d // k
+    bm = min(block_m, _round_up(m, 8))
+    bn = min(block_n, _round_up(n, 128))
+    bd = min(block_d, d_pad)
+    assert bd % kpw == 0
+    bw = bd // kpw
+    m_p = _round_up(m, bm)
+    d_p = _round_up(d_pad, bd)
+    grid = (pl.cdiv(n, bn), m_p // bm, d_p // bd)
 
-    m_p = _round_up(m, block_m)
-    n_p = _round_up(n, block_n)
-    d_p = _round_up(d_pad, block_d)
-    w_p = d_p // k
-    codes = jnp.pad(codes, ((0, n_p - n), (0, w_p - Wd)))
-    q_proj = jnp.pad(q_proj, ((0, m_p - m), (0, d_p - d_pad)))
-    scale2 = jnp.pad(scale, (0, n_p - n)).reshape(1, n_p)
-    offset2 = jnp.pad(offset, (0, n_p - n)).reshape(1, n_p)
-    cluster2 = jnp.pad(cluster, (0, n_p - n)).reshape(1, n_p)
-    ipq = jnp.pad(ip_q_landmarks, ((0, m_p - m), (0, 0)))
-    if qterm is None:
-        qterm = jnp.zeros((m,), jnp.float32)
-    if rowterm is None:
-        rowterm = jnp.zeros((n,), jnp.float32)
-    qterm2 = jnp.pad(
-        qterm.astype(jnp.float32), (0, m_p - m)
-    ).reshape(1, m_p)
-    rowterm2 = jnp.pad(
-        rowterm.astype(jnp.float32), (0, n_p - n)
-    ).reshape(1, n_p)
+    def col(v):  # per-query (m,) -> (m_p, 1) f32
+        return jnp.pad(v.astype(jnp.float32), (0, m_p - m)).reshape(m_p, 1)
 
-    grid = (n_p // block_n, m_p // block_m, d_p // block_d)
-    operands = (
-        q_proj, codes, scale2, offset2, cluster2, ipq, qterm2, rowterm2
-    )
-    geom = dict(
-        m=m, n=n, m_p=m_p, n_p=n_p, grid=grid,
-        block_m=block_m, block_n=block_n, block_d=block_d,
-        block_w=block_w, C=ip_q_landmarks.shape[1],
-    )
-    return operands, geom
+    def row(v, dt=jnp.float32):  # per-row (n,) -> (1, n), never padded
+        return v.astype(dt).reshape(1, n)
 
-
-def _in_specs(g):
-    # trailing *_ tolerates grid specs that append extra index_map args
-    # (kept permissive; the dense kernels run on a plain grid — the
-    # selection kernel's row mask is a regular blocked operand now)
-    return [
-        pl.BlockSpec(
-            (g["block_m"], g["block_d"]), lambda i, j, k_, *_: (j, k_)
-        ),
-        pl.BlockSpec(
-            (g["block_n"], g["block_w"]), lambda i, j, k_, *_: (i, k_)
-        ),
-        pl.BlockSpec((1, g["block_n"]), lambda i, j, k_, *_: (0, i)),
-        pl.BlockSpec((1, g["block_n"]), lambda i, j, k_, *_: (0, i)),
-        pl.BlockSpec((1, g["block_n"]), lambda i, j, k_, *_: (0, i)),
-        pl.BlockSpec((g["block_m"], g["C"]), lambda i, j, k_, *_: (j, 0)),
-        pl.BlockSpec((1, g["block_m"]), lambda i, j, k_, *_: (0, j)),
-        pl.BlockSpec((1, g["block_n"]), lambda i, j, k_, *_: (0, i)),
+    row_spec = pl.BlockSpec((1, bn), lambda i, j, kd: (0, i))
+    col_spec = pl.BlockSpec((bm, 1), lambda i, j, kd: (j, 0))
+    operands = [
+        jnp.pad(q, ((0, m_p - m), (0, d_p - d_pad))),
+        codes.T,  # (W, n): a free view of the column-major codes
+        row(scale), row(offset), row(cluster, jnp.int32),
+        jnp.pad(ipq, ((0, m_p - m), (0, 0))),
     ]
+    in_specs = [
+        pl.BlockSpec((bm, bd), lambda i, j, kd: (j, kd)),
+        pl.BlockSpec((bw, bn), lambda i, j, kd: (kd, i)),
+        row_spec, row_spec, row_spec,
+        pl.BlockSpec((bm, ipq.shape[1]), lambda i, j, kd: (j, 0)),
+    ]
+    if metric != "dot":
+        operands += [col(qterm), row(rowterm)]
+        in_specs += [col_spec, row_spec]
+    coarse = coarse_q is not None
+    if coarse:
+        operands += [col(coarse_q[0]), col(coarse_q[1])]
+        in_specs += [col_spec, col_spec]
+    use_mask = n_valid is not None or row_valid is not None
+    if use_mask:
+        mask = (
+            jnp.ones((n,), jnp.int32) if row_valid is None
+            else row_valid.astype(jnp.int32)
+        )
+        if n_valid is not None:
+            mask = mask * (
+                jnp.arange(n, dtype=jnp.int32)
+                < jnp.asarray(n_valid, jnp.int32)
+            ).astype(jnp.int32)
+        operands.append(row(mask, jnp.int32))
+        in_specs.append(row_spec)
+
+    kt = None
+    if k is None:
+        out_specs = pl.BlockSpec((bm, bn), lambda i, j, kd: (j, i))
+        out_shape = jax.ShapeDtypeStruct((m_p, n), jnp.float32)
+    else:
+        kt, out_specs, out_shape = _strip_outputs(
+            k, k_tilde, bn, grid[0], grid[1], bm,
+            lambda i, j, kd: (j, i, 0),
+        )
+    out = pl.pallas_call(
+        functools.partial(
+            _dense_kernel, b=b, n_d_blocks=grid[2],
+            compute_dtype=compute_dtype, metric=metric, coarse=coarse,
+            k_tilde=kt, block_n=bn, n_real=n, use_mask=use_mask,
+        ),
+        grid=grid,
+        in_specs=in_specs,
+        out_specs=out_specs,
+        out_shape=out_shape,
+        scratch_shapes=[
+            pltpu.VMEM((bm, bn), jnp.int32 if coarse else jnp.float32)
+        ],
+        interpret=interpret,
+    )(*operands)
+    if k is None:
+        return out[:m]
+    s, i = _merge_strip(*out, m, k)
+    return s, jnp.where(i == _ID_SENTINEL, -1, i)
 
 
 @functools.partial(
@@ -390,32 +457,12 @@ def ash_score_pallas(
     ``repro.kernels.ops._metric_operands``) and match
     ``ref.ash_score_metric_ref``.
     """
-    assert metric in METRICS, metric
-    operands, g = _pad_operands(
+    return _dense_call(
         codes, q_proj, scale, offset, cluster, ip_q_landmarks,
-        qterm, rowterm,
-        b=b, block_m=block_m, block_n=block_n, block_d=block_d,
+        qterm, rowterm, b=b, metric=metric, block_m=block_m,
+        block_n=block_n, block_d=block_d, interpret=interpret,
+        compute_dtype=compute_dtype,
     )
-    out = pl.pallas_call(
-        functools.partial(
-            _kernel,
-            b=b,
-            n_d_blocks=g["grid"][2],
-            compute_dtype=compute_dtype,
-            metric=metric,
-        ),
-        grid=g["grid"],
-        in_specs=_in_specs(g),
-        out_specs=pl.BlockSpec(
-            (g["block_m"], g["block_n"]), lambda i, j, k_: (j, i)
-        ),
-        out_shape=jax.ShapeDtypeStruct((g["m_p"], g["n_p"]), jnp.float32),
-        scratch_shapes=[
-            pltpu.VMEM((g["block_m"], g["block_n"]), jnp.float32)
-        ],
-        interpret=interpret,
-    )(*operands)
-    return out[: g["m"], : g["n"]]
 
 
 @functools.partial(
@@ -450,322 +497,168 @@ def ash_score_topk_pallas(
     """Fused scan + selection: top-k (scores, ids), each (m, k).
 
     The (m, n) score matrix never exists — each output tile emits its
-    partial top-k̃ and one two-key sort merges the (m, n_blocks * k̃)
-    candidate strip.  Exactly equal to ``top_k(ash_score_pallas(...))``
-    (values, ids and tie order) for ``k <= k̃``; ``k̃`` defaults to
-    ``k``.  Ids of exhausted slots come back as -1 (only reachable when
+    partial top-k̃ and one ``lax.top_k`` merges the candidate strip.
+    Exactly equal to ``top_k(ash_score_pallas(...))`` (values, ids and
+    tie order) for ``k <= k̃``; ``k̃`` defaults to ``k``.  Ids of
+    exhausted slots come back as -1 (only reachable when
     ``k > min(n, k̃)``).
 
     Row-validity masking: when either ``n_valid`` or ``row_valid`` is
-    given, they fold into ONE runtime (1, n_p) int32 mask operand — no
+    given, they fold into ONE runtime (1, n) int32 mask operand — no
     recompilation between mutation states or shard shapes.  Without
-    them (the common unmutated, unsharded scan) no mask operand exists
-    at all: block padding is masked by a static predicate.
+    them (the common unmutated, unsharded scan) no mask operand exists.
 
     * ``n_valid`` (scalar): rows at or beyond it score ``-inf`` — the
       sharded backend's per-shard ``n_real`` pad-row truncation.
-    * ``row_valid`` ((n,) bool): rows whose entry is 0 score ``-inf``
-      and are excluded from selection exactly like block padding — the
-      index layers' tombstone (deleted-row) bitmap.
+    * ``row_valid`` ((n,) bool): rows whose entry is 0 are excluded
+      from selection — the index layers' tombstone bitmap.
     """
-    assert metric in METRICS, metric
-    n = codes.shape[0]
-    operands, g = _pad_operands(
+    return _dense_call(
         codes, q_proj, scale, offset, cluster, ip_q_landmarks,
-        qterm, rowterm,
-        b=b, block_m=block_m, block_n=block_n, block_d=block_d,
+        qterm, rowterm, b=b, metric=metric, block_m=block_m,
+        block_n=block_n, block_d=block_d, interpret=interpret,
+        compute_dtype=compute_dtype, n_valid=n_valid,
+        row_valid=row_valid, k=k, k_tilde=k_tilde,
     )
-    use_mask = n_valid is not None or row_valid is not None
-    in_specs = _in_specs(g)
-    if use_mask:
-        if row_valid is None:
-            mask = jnp.ones((n,), jnp.int32)
-        else:
-            mask = row_valid.astype(jnp.int32)
-        if n_valid is not None:
-            mask = mask * (
-                jnp.arange(n, dtype=jnp.int32)
-                < jnp.asarray(n_valid, jnp.int32)
-            ).astype(jnp.int32)
-        operands = operands + (
-            jnp.pad(mask, (0, g["n_p"] - n)).reshape(1, g["n_p"]),
-        )
-        in_specs = in_specs + [
-            pl.BlockSpec((1, g["block_n"]), lambda i, j, k_: (0, i)),
-        ]
-    if k_tilde is None:
-        k_tilde = k
-    k_tilde = min(k_tilde, g["block_n"])
-    n_blocks = g["grid"][0]
-    if k > n_blocks * k_tilde:
-        raise ValueError(
-            f"k={k} exceeds the {n_blocks} x k_tilde={k_tilde} candidate "
-            f"strip; raise k_tilde or use the materializing kernel"
-        )
-    vals, ids = pl.pallas_call(
-        functools.partial(
-            _topk_kernel,
-            b=b,
-            n_d_blocks=g["grid"][2],
-            compute_dtype=compute_dtype,
-            metric=metric,
-            k_tilde=k_tilde,
-            block_n=g["block_n"],
-            n_real=n,
-            use_mask=use_mask,
-        ),
-        grid=g["grid"],
-        in_specs=in_specs,
-        out_specs=[
-            pl.BlockSpec(
-                (g["block_m"], k_tilde), lambda i, j, k_: (j, i)
-            ),
-            pl.BlockSpec(
-                (g["block_m"], k_tilde), lambda i, j, k_: (j, i)
-            ),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((g["m_p"], n_blocks * k_tilde), jnp.float32),
-            jax.ShapeDtypeStruct((g["m_p"], n_blocks * k_tilde), jnp.int32),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((g["block_m"], g["block_n"]), jnp.float32)
-        ],
-        interpret=interpret,
-    )(*operands)
-    vals, ids = vals[: g["m"]], ids[: g["m"]]
-    # Merge: (score desc, id asc) — bit-equal to lax.top_k over the
-    # materialized row (candidate tiles are already in ascending-id
-    # order, so the two-key sort reproduces top_k's tie behaviour).
-    neg, sid = jax.lax.sort((-vals, ids), dimension=1, num_keys=2)
-    out_s, out_i = -neg[:, :k], sid[:, :k]
-    return out_s, jnp.where(out_i == _ID_SENTINEL, -1, out_i)
-
-
-def _round_up(x: int, mult: int) -> int:
-    return ((x + mult - 1) // mult) * mult
 
 
 # ---------------------------------------------------------------------------
 # Masked-gather kernels (IVF partial probes: per-query candidate lists)
 # ---------------------------------------------------------------------------
 
-DEFAULT_BLOCK_R = 128
 
-
-def _gather_tile(
-    rows_sref, codes_hbm, codes_vmem, sem, r0, block_r, block_w,
+def _gather_kernel(
+    *refs,
+    b: int,
+    n_d_blocks: int,
+    compute_dtype,
+    metric: str,
+    k_tilde: int | None,
+    block_r: int,
 ):
-    """DMA-gather one (block_r, block_w) packed-code tile into VMEM.
-
-    ``rows_sref`` is the scalar-prefetch candidate-row table; row ids
-    drive per-candidate async copies of the packed word strip for the
-    current d-block (pad ids are clamped to row 0 — their scores are
-    masked in the epilogue, the fetch just has to be in-bounds).  All
-    copies start before any is awaited so the gather pipelines.
-    """
-    i = pl.program_id(0)
+    """Refs: q (8, d_blk, 1), codes (8, w_blk, r_blk), scale/bias/
+    offset/rows (8, r_blk); [qterm (8, 1), rowterm (8, r_blk)] unless
+    dot; outputs (scores, or the vals/ids strip blocks); accumulator."""
+    q_ref, codes_ref, scale_ref, bias_ref, offset_ref, rows_ref = refs[:6]
+    rest = refs[6:]
+    qterm_ref = rowterm_ref = None
+    if metric != "dot":
+        qterm_ref, rowterm_ref, *rest = rest
+    *outs, acc_ref = rest
     kd = pl.program_id(2)
-    w0 = kd * block_w
-    for t in range(block_r):
-        row = jnp.maximum(rows_sref[i, r0 + t], 0)
-        pltpu.make_async_copy(
-            codes_hbm.at[row, pl.ds(w0, block_w)],
-            codes_vmem.at[t],
-            sem.at[t],
-        ).start()
-    for t in range(block_r):
-        row = jnp.maximum(rows_sref[i, r0 + t], 0)
-        pltpu.make_async_copy(
-            codes_hbm.at[row, pl.ds(w0, block_w)],
-            codes_vmem.at[t],
-            sem.at[t],
-        ).wait()
-
-
-def _gather_accumulate(
-    rows_sref, codes_hbm, codes_vmem, sem, q_ref, acc_ref,
-    *, b, block_r, block_w, compute_dtype,
-):
-    """Shared prologue of both gather kernels: zero the accumulator on
-    the first d-step, DMA-gather this (r-tile, d-block) of packed
-    codes, unpack in-register and accumulate the DOT-PROD term.
-    Returns (k_idx, r0) for the caller's epilogue predicate."""
-    k_idx = pl.program_id(2)
     r0 = pl.program_id(1) * block_r
 
-    @pl.when(k_idx == 0)
+    @pl.when(kd == 0)
     def _zero():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    _gather_tile(rows_sref, codes_hbm, codes_vmem, sem, r0, block_r, block_w)
-    vals = _unpack_block(codes_vmem[...], b, compute_dtype)
-    acc_ref[...] += jax.lax.dot_general(
-        q_ref[...].astype(compute_dtype),
-        vals,
-        (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )
-    return k_idx, r0
+    # per-query mat-vec on the VPU: (8, d_blk, 1) x (8, d_blk, r_blk),
+    # exact f32 products of the (compute_dtype-rounded) query and the
+    # small-integer grid values, reduced over d
+    vals = _unpack_lanes(codes_ref[...], b, jnp.float32)
+    q = q_ref[...].astype(compute_dtype).astype(jnp.float32)
+    acc_ref[...] += jnp.sum(q * vals, axis=1)
 
-
-def _gather_kernel(
-    rows_sref,  # scalar prefetch: (m, R_p) int32 candidate rows, -1 pad
-    q_ref,  # (1, d_blk)
-    codes_hbm,  # (n, w_p) uint32, HBM-resident (pl.ANY)
-    scale_ref,  # (1, r_blk) gathered per-candidate
-    offset_ref,  # (1, r_blk)
-    cluster_ref,  # (1, r_blk) int32
-    ipq_ref,  # (1, C)
-    qterm_ref,  # (1, 1)
-    rowterm_ref,  # (1, r_blk)
-    rows_ref,  # (1, r_blk) int32 — VMEM copy of the tile's row ids
-    out_ref,  # (1, r_blk)
-    codes_vmem,  # scratch (r_blk, w_blk) uint32
-    acc_ref,  # scratch (1, r_blk) fp32
-    sem,  # DMA semaphores (r_blk,)
-    *,
-    b: int,
-    n_d_blocks: int,
-    compute_dtype,
-    metric: str,
-    block_r: int,
-    block_w: int,
-):
-    k_idx, _ = _gather_accumulate(
-        rows_sref, codes_hbm, codes_vmem, sem, q_ref, acc_ref,
-        b=b, block_r=block_r, block_w=block_w,
-        compute_dtype=compute_dtype,
-    )
-
-    @pl.when(k_idx == n_d_blocks - 1)
-    def _epilogue():
-        scores = _epilogue_scores(
-            acc_ref[...], scale_ref, offset_ref, cluster_ref, ipq_ref,
-            qterm_ref, rowterm_ref, metric=metric,
+    @pl.when(kd == n_d_blocks - 1)
+    def _finish():
+        scores = _epilogue(
+            acc_ref[...], scale_ref[...], bias_ref[...], offset_ref[...],
+            None if qterm_ref is None else qterm_ref[...],
+            None if rowterm_ref is None else rowterm_ref[...],
+            metric,
         )
-        out_ref[...] = jnp.where(
-            rows_ref[...] >= 0, scores, jnp.float32(-jnp.inf)
-        )
-
-
-def _gather_topk_kernel(
-    rows_sref,
-    q_ref,
-    codes_hbm,
-    scale_ref,
-    offset_ref,
-    cluster_ref,
-    ipq_ref,
-    qterm_ref,
-    rowterm_ref,
-    rows_ref,
-    vals_ref,  # (1, k_tilde) fp32
-    ids_ref,  # (1, k_tilde) int32 — candidate POSITIONS in the list
-    codes_vmem,
-    acc_ref,
-    sem,
-    *,
-    b: int,
-    n_d_blocks: int,
-    compute_dtype,
-    metric: str,
-    block_r: int,
-    block_w: int,
-    k_tilde: int,
-):
-    k_idx, r0 = _gather_accumulate(
-        rows_sref, codes_hbm, codes_vmem, sem, q_ref, acc_ref,
-        b=b, block_r=block_r, block_w=block_w,
-        compute_dtype=compute_dtype,
-    )
-
-    @pl.when(k_idx == n_d_blocks - 1)
-    def _select():
-        scores = _epilogue_scores(
-            acc_ref[...], scale_ref, offset_ref, cluster_ref, ipq_ref,
-            qterm_ref, rowterm_ref, metric=metric,
-        )
-        # pad-id masking IS the validity mask: padded positions (and
-        # R-padding, which also carries id -1) never surface
+        # pad-id masking IS the validity mask: list padding, dropped
+        # (tombstoned) rows and R-padding all carry id -1
         valid = rows_ref[...] >= 0
-        _select_topk(scores, valid, r0, k_tilde, vals_ref, ids_ref)
+        if k_tilde:
+            _select_topk(scores, valid, r0, k_tilde, *outs)
+        else:
+            outs[0][...] = jnp.where(valid, scores, jnp.float32(-jnp.inf))
 
 
-def _pad_gather_operands(
-    codes, rows, q_proj, scale, offset, cluster, ip_q_landmarks,
-    qterm, rowterm, *, b, block_r, block_d,
+def _gather_call(
+    codes, rows, q, scale, offset, cluster, ipq, qterm, rowterm, *,
+    b, metric, block_r, block_d, interpret, compute_dtype,
+    k=None, k_tilde=None,
 ):
-    """Pad/gather the masked-gather operands; mirrors
-    :func:`_pad_operands` for the per-candidate layout.
-
-    The candidate axis pads with id -1 (masked like real pad entries);
-    per-row header vectors are pre-gathered to (m, R_p) on the host —
-    they are the same size as the output and tiny next to the packed
-    codes, which stay in HBM and are DMA-gathered in-kernel.
-    """
-    n, Wd = codes.shape
-    m, d_pad = q_proj.shape
+    """Shared wrapper of the gather kernels: (m, R) scores, or — with
+    ``k`` — the fused top-k (scores, candidate POSITIONS) plus the
+    -1-padded (m, R_p) row table."""
+    assert metric in METRICS, metric
+    n, W = codes.shape
+    m, d_pad = q.shape
     R = rows.shape[1]
     kpw = Q.codes_per_word(b)
-    assert Wd * kpw == d_pad, (Wd, kpw, d_pad)
+    assert W * kpw == d_pad, (W, kpw, d_pad)
 
-    block_r = min(block_r, _round_up(R, 128))
-    block_d = min(block_d, d_pad)
-    assert block_d % kpw == 0
-    block_w = block_d // kpw
+    bq = _GATHER_BLOCK_Q
+    br = min(block_r, _round_up(R, 128))
+    bd = min(block_d, d_pad)
+    assert bd % kpw == 0
+    bw = bd // kpw
+    m_p = _round_up(m, bq)
+    R_p = _round_up(R, br)
+    d_p = _round_up(d_pad, bd)
+    grid = (m_p // bq, R_p // br, d_p // bd)
 
-    R_p = _round_up(R, block_r)
-    d_p = _round_up(d_pad, block_d)
-    w_p = d_p // kpw
-    rows_p = jnp.pad(rows.astype(jnp.int32), ((0, 0), (0, R_p - R)),
-                     constant_values=-1)
+    rows_p = jnp.pad(
+        rows.astype(jnp.int32), ((0, m_p - m), (0, R_p - R)),
+        constant_values=-1,
+    )
     safe = jnp.maximum(rows_p, 0)
-    codes_p = jnp.pad(codes, ((0, 0), (0, w_p - Wd)))
-    q_p = jnp.pad(q_proj, ((0, 0), (0, d_p - d_pad)))
-    scale_g = scale.astype(jnp.float32)[safe]
-    offset_g = offset.astype(jnp.float32)[safe]
-    cluster_g = cluster[safe].astype(jnp.int32)
-    if qterm is None:
-        qterm = jnp.zeros((m,), jnp.float32)
-    if rowterm is None:
-        rowterm_g = jnp.zeros((m, R_p), jnp.float32)
+    # the probed rows' packed words, per query and lane-dense: (m_p,
+    # w_p, R_p) — only W words per candidate move, never unpacked
+    codes_g = jnp.pad(
+        jnp.swapaxes(codes[safe], 1, 2),
+        ((0, 0), (0, d_p // kpw - W), (0, 0)),
+    )
+    q3 = jnp.pad(q, ((0, m_p - m), (0, d_p - d_pad)))[:, :, None]
+    bias_g = jnp.take_along_axis(
+        jnp.pad(ipq.astype(jnp.float32), ((0, m_p - m), (0, 0))),
+        cluster[safe].astype(jnp.int32), axis=1,
+    )
+    tile = pl.BlockSpec((bq, br), lambda i, j, kd: (i, j))
+    operands = [
+        q3, codes_g, scale.astype(jnp.float32)[safe], bias_g,
+        offset.astype(jnp.float32)[safe], rows_p,
+    ]
+    in_specs = [
+        pl.BlockSpec((bq, bd, 1), lambda i, j, kd: (i, kd, 0)),
+        pl.BlockSpec((bq, bw, br), lambda i, j, kd: (i, kd, j)),
+        tile, tile, tile, tile,
+    ]
+    if metric != "dot":
+        operands += [
+            jnp.pad(qterm.astype(jnp.float32), (0, m_p - m))[:, None],
+            rowterm.astype(jnp.float32)[safe],
+        ]
+        in_specs += [pl.BlockSpec((bq, 1), lambda i, j, kd: (i, 0)), tile]
+
+    kt = None
+    if k is None:
+        out_specs = tile
+        out_shape = jax.ShapeDtypeStruct((m_p, R_p), jnp.float32)
     else:
-        rowterm_g = rowterm.astype(jnp.float32)[safe]
-    qterm2 = qterm.astype(jnp.float32).reshape(m, 1)
-
-    grid = (m, R_p // block_r, d_p // block_d)
-    operands = (
-        rows_p, q_p, codes_p, scale_g, offset_g, cluster_g,
-        ip_q_landmarks, qterm2, rowterm_g, rows_p,
-    )
-    geom = dict(
-        m=m, R=R, R_p=R_p, grid=grid, block_r=block_r,
-        block_d=block_d, block_w=block_w, C=ip_q_landmarks.shape[1],
-    )
-    return operands, geom
-
-
-def _gather_in_specs(g):
-    return [
-        pl.BlockSpec((1, g["block_d"]), lambda i, j, kd, *_: (i, kd)),
-        pl.BlockSpec(memory_space=pltpu.ANY),  # codes stay in HBM
-        pl.BlockSpec((1, g["block_r"]), lambda i, j, kd, *_: (i, j)),
-        pl.BlockSpec((1, g["block_r"]), lambda i, j, kd, *_: (i, j)),
-        pl.BlockSpec((1, g["block_r"]), lambda i, j, kd, *_: (i, j)),
-        pl.BlockSpec((1, g["C"]), lambda i, j, kd, *_: (i, 0)),
-        pl.BlockSpec((1, 1), lambda i, j, kd, *_: (i, 0)),
-        pl.BlockSpec((1, g["block_r"]), lambda i, j, kd, *_: (i, j)),
-        pl.BlockSpec((1, g["block_r"]), lambda i, j, kd, *_: (i, j)),
-    ]
-
-
-def _gather_scratch(g):
-    return [
-        pltpu.VMEM((g["block_r"], g["block_w"]), jnp.uint32),
-        pltpu.VMEM((1, g["block_r"]), jnp.float32),
-        pltpu.SemaphoreType.DMA((g["block_r"],)),
-    ]
+        kt, out_specs, out_shape = _strip_outputs(
+            k, k_tilde, br, grid[1], grid[0], bq,
+            lambda i, j, kd: (i, j, 0),
+        )
+    out = pl.pallas_call(
+        functools.partial(
+            _gather_kernel, b=b, n_d_blocks=grid[2],
+            compute_dtype=compute_dtype, metric=metric, k_tilde=kt,
+            block_r=br,
+        ),
+        grid=grid,
+        in_specs=in_specs,
+        out_specs=out_specs,
+        out_shape=out_shape,
+        scratch_shapes=[pltpu.VMEM((bq, br), jnp.float32)],
+        interpret=interpret,
+    )(*operands)
+    if k is None:
+        return out[:m, :R]
+    return _merge_strip(*out, m, k), rows_p[:m]
 
 
 @functools.partial(
@@ -797,40 +690,13 @@ def ash_score_gather_pallas(
     ``ref.ash_score_gather_ref``.
 
     Query i is scored against its own candidate list ``rows[i]`` (IVF
-    partial probes).  Candidate row ids ride a scalar-prefetch operand
-    and the kernel DMA-gathers each candidate's packed word strip
-    HBM -> VMEM — the database is never unpacked in HBM and only probed
-    rows move.
+    partial probes), straight off the probed rows' packed words.
     """
-    assert metric in METRICS, metric
-    operands, g = _pad_gather_operands(
+    return _gather_call(
         codes, rows, q_proj, scale, offset, cluster, ip_q_landmarks,
-        qterm, rowterm, b=b, block_r=block_r, block_d=block_d,
+        qterm, rowterm, b=b, metric=metric, block_r=block_r,
+        block_d=block_d, interpret=interpret, compute_dtype=compute_dtype,
     )
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=g["grid"],
-        in_specs=_gather_in_specs(g),
-        out_specs=pl.BlockSpec(
-            (1, g["block_r"]), lambda i, j, kd, *_: (i, j)
-        ),
-        scratch_shapes=_gather_scratch(g),
-    )
-    out = pl.pallas_call(
-        functools.partial(
-            _gather_kernel,
-            b=b,
-            n_d_blocks=g["grid"][2],
-            compute_dtype=compute_dtype,
-            metric=metric,
-            block_r=g["block_r"],
-            block_w=g["block_w"],
-        ),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((g["m"], g["R_p"]), jnp.float32),
-        interpret=interpret,
-    )(*operands)
-    return out[:, : g["R"]]
 
 
 @functools.partial(
@@ -869,57 +735,14 @@ def ash_score_gather_topk_pallas(
     convention) for ``k <= k̃``.  Slots without a candidate (pad ids,
     or k beyond the emitted strip) come back score ``-inf`` / row -1.
     """
-    assert metric in METRICS, metric
-    operands, g = _pad_gather_operands(
+    (out_s, out_p), rows_p = _gather_call(
         codes, rows, q_proj, scale, offset, cluster, ip_q_landmarks,
-        qterm, rowterm, b=b, block_r=block_r, block_d=block_d,
+        qterm, rowterm, b=b, metric=metric, block_r=block_r,
+        block_d=block_d, interpret=interpret, compute_dtype=compute_dtype,
+        k=k, k_tilde=k_tilde,
     )
-    if k_tilde is None:
-        k_tilde = k
-    k_tilde = min(k_tilde, g["block_r"])
-    n_r_blocks = g["grid"][1]
-    if k > n_r_blocks * k_tilde:
-        raise ValueError(
-            f"k={k} exceeds the {n_r_blocks} x k_tilde={k_tilde} "
-            f"candidate strip; raise k_tilde or use the materializing "
-            f"kernel"
-        )
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=g["grid"],
-        in_specs=_gather_in_specs(g),
-        out_specs=[
-            pl.BlockSpec((1, k_tilde), lambda i, j, kd, *_: (i, j)),
-            pl.BlockSpec((1, k_tilde), lambda i, j, kd, *_: (i, j)),
-        ],
-        scratch_shapes=_gather_scratch(g),
-    )
-    vals, pos = pl.pallas_call(
-        functools.partial(
-            _gather_topk_kernel,
-            b=b,
-            n_d_blocks=g["grid"][2],
-            compute_dtype=compute_dtype,
-            metric=metric,
-            block_r=g["block_r"],
-            block_w=g["block_w"],
-            k_tilde=k_tilde,
-        ),
-        grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct((g["m"], n_r_blocks * k_tilde),
-                                 jnp.float32),
-            jax.ShapeDtypeStruct((g["m"], n_r_blocks * k_tilde),
-                                 jnp.int32),
-        ],
-        interpret=interpret,
-    )(*operands)
-    # merge identical to the dense kernel: (score desc, position asc)
-    neg, spos = jax.lax.sort((-vals, pos), dimension=1, num_keys=2)
-    out_s, out_p = -neg[:, :k], spos[:, :k]
-    rows_p = operands[0]  # (m, R_p), -1-padded
     out_rows = jnp.take_along_axis(
-        rows_p, jnp.clip(out_p, 0, g["R_p"] - 1), axis=1
+        rows_p, jnp.clip(out_p, 0, rows_p.shape[1] - 1), axis=1
     )
     return out_s, jnp.where(out_p == _ID_SENTINEL, -1, out_rows)
 
@@ -940,158 +763,9 @@ def ash_score_gather_topk_pallas(
 # |v| <= 2^b - 1 <= 255), so every partial sum stays below
 # 127 * 255 * 512 < 2^24 for d_pad <= 512 — the int32 accumulation here,
 # the oracle's fp32 matmul over the same integers, and the CoarseCodes
-# fp32 value-cache path all produce identical scores bit for bit.
-
-
-def _coarse_operand_dtype(b: int):
-    # grid values reach +-(2^b - 1): int8 holds them for b <= 4, b=8
-    # (+-255) promotes both operands to int32 (accumulation unchanged)
-    return jnp.int8 if b <= 4 else jnp.int32
-
-
-def _coarse_accumulate(q_ref, codes_ref, acc_ref, *, b):
-    """acc(int32) += q_int8 @ unpack(codes)^T — integer MXU prologue."""
-    dt = _coarse_operand_dtype(b)
-    vals = _unpack_block(codes_ref[...], b, dt)  # (n_blk, d_blk)
-    acc_ref[...] += jax.lax.dot_general(
-        q_ref[...].astype(dt),
-        vals,
-        (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.int32,
-    )
-
-
-def _coarse_epilogue_scores(
-    acc, qscale_ref, qcorr_ref, scale_ref, offset_ref, cluster_ref,
-    ipq_ref, qterm_ref, rowterm_ref, *, metric,
-):
-    """Coarse tile scores (m_blk, n_blk) fp32; op order mirrored by
-    ``ref.ash_score_coarse_ref`` (and its ``_coarse_base`` helper)."""
-    bias = _bias_lookup(cluster_ref, ipq_ref)
-    dotc = (
-        acc.astype(jnp.float32)
-        * qscale_ref[0, :].astype(jnp.float32)[:, None]
-    )
-    biasq = bias + qcorr_ref[0, :].astype(jnp.float32)[:, None]
-    base = (
-        dotc * scale_ref[0, :][None, :].astype(jnp.float32)
-        + biasq
-        + offset_ref[0, :][None, :].astype(jnp.float32)
-    )
-    return _metric_tail(base, qterm_ref, rowterm_ref, metric)
-
-
-def _coarse_kernel(
-    q_ref,  # (m_blk, d_blk) int8
-    codes_ref,  # (n_blk, w_blk) uint32
-    scale_ref,  # (1, n_blk)
-    offset_ref,  # (1, n_blk)
-    cluster_ref,  # (1, n_blk) int32
-    ipq_ref,  # (m_blk, C)
-    qterm_ref,  # (1, m_blk)
-    rowterm_ref,  # (1, n_blk)
-    qscale_ref,  # (1, m_blk) per-query int8 scale
-    qcorr_ref,  # (1, m_blk) per-query residual correction
-    out_ref,  # (m_blk, n_blk)
-    acc_ref,  # scratch (m_blk, n_blk) int32
-    *,
-    b: int,
-    n_d_blocks: int,
-    metric: str,
-):
-    k_idx = pl.program_id(2)
-
-    @pl.when(k_idx == 0)
-    def _zero():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    _coarse_accumulate(q_ref, codes_ref, acc_ref, b=b)
-
-    @pl.when(k_idx == n_d_blocks - 1)
-    def _epilogue():
-        out_ref[...] = _coarse_epilogue_scores(
-            acc_ref[...], qscale_ref, qcorr_ref, scale_ref, offset_ref,
-            cluster_ref, ipq_ref, qterm_ref, rowterm_ref, metric=metric,
-        ).astype(out_ref.dtype)
-
-
-def _coarse_topk_kernel(
-    q_ref,
-    codes_ref,
-    scale_ref,
-    offset_ref,
-    cluster_ref,
-    ipq_ref,
-    qterm_ref,
-    rowterm_ref,
-    qscale_ref,
-    qcorr_ref,
-    *rest,  # [mask_ref,] vals_ref, ids_ref, acc_ref — see use_mask
-    b: int,
-    n_d_blocks: int,
-    metric: str,
-    k_tilde: int,
-    block_n: int,
-    n_real: int,
-    use_mask: bool,
-):
-    # trailing refs follow the _topk_kernel convention: an optional
-    # runtime (1, n_blk) int32 row-validity operand, then the vals/ids
-    # candidate-strip outputs and the int32 accumulator scratch
-    if use_mask:
-        mask_ref, vals_ref, ids_ref, acc_ref = rest
-    else:
-        vals_ref, ids_ref, acc_ref = rest
-    k_idx = pl.program_id(2)
-    col0 = pl.program_id(0) * block_n
-
-    @pl.when(k_idx == 0)
-    def _zero():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    _coarse_accumulate(q_ref, codes_ref, acc_ref, b=b)
-
-    @pl.when(k_idx == n_d_blocks - 1)
-    def _select():
-        scores = _coarse_epilogue_scores(
-            acc_ref[...], qscale_ref, qcorr_ref, scale_ref, offset_ref,
-            cluster_ref, ipq_ref, qterm_ref, rowterm_ref, metric=metric,
-        )  # (m_blk, n_blk) fp32
-        if use_mask:
-            valid = jnp.broadcast_to(mask_ref[...] != 0, scores.shape)
-        else:
-            local = jax.lax.broadcasted_iota(jnp.int32, scores.shape, 1)
-            valid = (local + col0) < n_real
-        _select_topk(scores, valid, col0, k_tilde, vals_ref, ids_ref)
-
-
-def _pad_coarse_operands(
-    codes, q_int8, q_scale, q_corr, scale, offset, cluster,
-    ip_q_landmarks, qterm, rowterm, *, b, block_m, block_n, block_d,
-):
-    """Coarse-operand padding: the shared 8-operand block (query side is
-    the int8 matrix — zero padding contributes nothing to the integer
-    accumulation) plus the two per-query (1, m_p) epilogue vectors."""
-    operands, g = _pad_operands(
-        codes, q_int8, scale, offset, cluster, ip_q_landmarks,
-        qterm, rowterm,
-        b=b, block_m=block_m, block_n=block_n, block_d=block_d,
-    )
-    m, m_p = g["m"], g["m_p"]
-    qscale2 = jnp.pad(
-        q_scale.astype(jnp.float32), (0, m_p - m)
-    ).reshape(1, m_p)
-    qcorr2 = jnp.pad(
-        q_corr.astype(jnp.float32), (0, m_p - m)
-    ).reshape(1, m_p)
-    return operands + (qscale2, qcorr2), g
-
-
-def _coarse_in_specs(g):
-    return _in_specs(g) + [
-        pl.BlockSpec((1, g["block_m"]), lambda i, j, k_, *_: (0, j)),
-        pl.BlockSpec((1, g["block_m"]), lambda i, j, k_, *_: (0, j)),
-    ]
+# fp32 value-cache path all produce identical scores bit for bit.  No
+# ``compute_dtype`` knob: the operand dtype is fixed by the bitrate
+# (int8 for b <= 4, int32 for b=8) and accumulation is always int32.
 
 
 @functools.partial(
@@ -1120,37 +794,13 @@ def ash_score_coarse_pallas(
     interpret: bool = False,
 ) -> jax.Array:
     """Materializing coarse scan: (m, n) fp32 symmetric int8 scores,
-    higher-is-better.  Matches ``ref.ash_score_coarse_ref`` bitwise.
-
-    No ``compute_dtype`` knob: the matmul operand dtype is fixed by the
-    bitrate (int8 for b <= 4, int32 for b=8) and accumulation is always
-    int32 — the whole point of the coarse pass.
-    """
-    assert metric in METRICS, metric
-    operands, g = _pad_coarse_operands(
-        codes, q_int8, q_scale, q_corr, scale, offset, cluster,
-        ip_q_landmarks, qterm, rowterm,
-        b=b, block_m=block_m, block_n=block_n, block_d=block_d,
+    higher-is-better.  Matches ``ref.ash_score_coarse_ref`` bitwise."""
+    return _dense_call(
+        codes, q_int8, scale, offset, cluster, ip_q_landmarks,
+        qterm, rowterm, b=b, metric=metric, block_m=block_m,
+        block_n=block_n, block_d=block_d, interpret=interpret,
+        compute_dtype=None, coarse_q=(q_scale, q_corr),
     )
-    out = pl.pallas_call(
-        functools.partial(
-            _coarse_kernel,
-            b=b,
-            n_d_blocks=g["grid"][2],
-            metric=metric,
-        ),
-        grid=g["grid"],
-        in_specs=_coarse_in_specs(g),
-        out_specs=pl.BlockSpec(
-            (g["block_m"], g["block_n"]), lambda i, j, k_: (j, i)
-        ),
-        out_shape=jax.ShapeDtypeStruct((g["m_p"], g["n_p"]), jnp.float32),
-        scratch_shapes=[
-            pltpu.VMEM((g["block_m"], g["block_n"]), jnp.int32)
-        ],
-        interpret=interpret,
-    )(*operands)
-    return out[: g["m"], : g["n"]]
 
 
 @functools.partial(
@@ -1190,75 +840,13 @@ def ash_score_coarse_topk_pallas(
     Same selection machinery, mask folding, and ``lax.top_k`` tie
     contract as :func:`ash_score_topk_pallas`, over the integer-domain
     coarse scores: exactly ``top_k(ash_score_coarse_pallas(...), k)``
-    for ``k <= k̃``.  The (m, n) coarse score matrix never reaches HBM;
-    the emitted ids feed ``ash_score_gather_topk_pallas`` for the
-    asymmetric refine.
+    for ``k <= k̃``.  The emitted ids feed
+    ``ash_score_gather_topk_pallas`` for the asymmetric refine.
     """
-    assert metric in METRICS, metric
-    n = codes.shape[0]
-    operands, g = _pad_coarse_operands(
-        codes, q_int8, q_scale, q_corr, scale, offset, cluster,
-        ip_q_landmarks, qterm, rowterm,
-        b=b, block_m=block_m, block_n=block_n, block_d=block_d,
+    return _dense_call(
+        codes, q_int8, scale, offset, cluster, ip_q_landmarks,
+        qterm, rowterm, b=b, metric=metric, block_m=block_m,
+        block_n=block_n, block_d=block_d, interpret=interpret,
+        compute_dtype=None, coarse_q=(q_scale, q_corr),
+        n_valid=n_valid, row_valid=row_valid, k=k, k_tilde=k_tilde,
     )
-    use_mask = n_valid is not None or row_valid is not None
-    in_specs = _coarse_in_specs(g)
-    if use_mask:
-        if row_valid is None:
-            mask = jnp.ones((n,), jnp.int32)
-        else:
-            mask = row_valid.astype(jnp.int32)
-        if n_valid is not None:
-            mask = mask * (
-                jnp.arange(n, dtype=jnp.int32)
-                < jnp.asarray(n_valid, jnp.int32)
-            ).astype(jnp.int32)
-        operands = operands + (
-            jnp.pad(mask, (0, g["n_p"] - n)).reshape(1, g["n_p"]),
-        )
-        in_specs = in_specs + [
-            pl.BlockSpec((1, g["block_n"]), lambda i, j, k_: (0, i)),
-        ]
-    if k_tilde is None:
-        k_tilde = k
-    k_tilde = min(k_tilde, g["block_n"])
-    n_blocks = g["grid"][0]
-    if k > n_blocks * k_tilde:
-        raise ValueError(
-            f"k={k} exceeds the {n_blocks} x k_tilde={k_tilde} candidate "
-            f"strip; raise k_tilde or use the materializing kernel"
-        )
-    vals, ids = pl.pallas_call(
-        functools.partial(
-            _coarse_topk_kernel,
-            b=b,
-            n_d_blocks=g["grid"][2],
-            metric=metric,
-            k_tilde=k_tilde,
-            block_n=g["block_n"],
-            n_real=n,
-            use_mask=use_mask,
-        ),
-        grid=g["grid"],
-        in_specs=in_specs,
-        out_specs=[
-            pl.BlockSpec(
-                (g["block_m"], k_tilde), lambda i, j, k_: (j, i)
-            ),
-            pl.BlockSpec(
-                (g["block_m"], k_tilde), lambda i, j, k_: (j, i)
-            ),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((g["m_p"], n_blocks * k_tilde), jnp.float32),
-            jax.ShapeDtypeStruct((g["m_p"], n_blocks * k_tilde), jnp.int32),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((g["block_m"], g["block_n"]), jnp.int32)
-        ],
-        interpret=interpret,
-    )(*operands)
-    vals, ids = vals[: g["m"]], ids[: g["m"]]
-    neg, sid = jax.lax.sort((-vals, ids), dimension=1, num_keys=2)
-    out_s, out_i = -neg[:, :k], sid[:, :k]
-    return out_s, jnp.where(out_i == _ID_SENTINEL, -1, out_i)

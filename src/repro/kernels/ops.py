@@ -166,7 +166,7 @@ def ash_score_topk(
     """Fused scan + on-chip selection: top-k (scores, row ids), (m, k).
 
     On TPU the (m, n) score matrix never reaches HBM — each output tile
-    emits a partial top-k̃ merged by one small two-key sort.  Results
+    emits a partial top-k̃ merged by one small ``lax.top_k``.  Results
     equal ``lax.top_k(ash_score(...), k)`` exactly (values, ids, tie
     order) for ``k <= k̃`` (default ``k̃ = k``).  The CPU oracle
     materializes and calls ``lax.top_k`` — identical semantics.
@@ -212,7 +212,7 @@ def ash_score_gather(
 
     Query i scores its own candidate list ``rows[i]`` (payload row ids,
     -1 = padding → score ``-inf``) — the IVF partial-probe primitive.
-    On TPU the kernel DMA-gathers packed code rows via scalar prefetch;
+    On TPU the kernel scores the probed rows' packed words in place;
     the CPU oracle (``ref.ash_score_gather_ref``) is rowwise and
     batch-shape-invariant, so engine bucketing stays bit-identical.
     """

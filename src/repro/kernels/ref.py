@@ -52,7 +52,7 @@ def ash_score_metric_ref(
     fused kernel family.
 
     Applies the same epilogue op order as the kernel's
-    ``_epilogue_scores`` over the Eq. (20) base score:
+    ``_epilogue`` over the Eq. (20) base score:
       dot: base;  l2: 2*base - qterm - rowterm (== -||q - x||^2);
       cos: base * qterm * rowterm.
     ``qterm``/``rowterm`` come from ``ops._metric_operands``.
@@ -117,7 +117,7 @@ def ash_score_gather_ref(
     i's scores are identical whatever the query-batch size — the
     bit-identity invariant the serving engine's bucketing relies on.
     The epilogue applies the same op order as the dense kernel's
-    ``_epilogue_scores`` (the landmark bias has a single non-zero
+    ``_epilogue`` (the landmark bias has a single non-zero
     one-hot term, so gather and one-hot matmul agree bitwise).
     """
     m, R = rows.shape

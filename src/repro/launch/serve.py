@@ -32,6 +32,7 @@ import time
 import jax
 import numpy as np
 
+from repro import compile_cache
 from repro.core import ASHConfig
 from repro.data.synthetic import embedding_dataset, isotropy_diagnostics
 from repro.index import AshIndex
@@ -40,6 +41,12 @@ from repro.serving.compactor import BackgroundCompactor
 from repro.serving.engine import QueryEngine
 from repro.serving.frontend import ServingFrontend
 from repro.serving.wal import DurableIndex
+
+
+def _device_label() -> str:
+    """The device the QPS figures were measured on, as JAX reports it."""
+    d = jax.devices()[0]
+    return f"{d.platform} {d.device_kind} x{len(jax.devices())}"
 
 
 def _print_engine_report(engine, mut_tickets=()):
@@ -176,7 +183,8 @@ def _run_concurrent(args, index, engine, Q, search_kw):
     served = lat.size * args.req_batch
     p50, p99 = np.percentile(lat, [50, 99])
     print(f"[serve] {served} queries via {n_clients} closed-loop "
-          f"clients in {dt:.2f}s ({served / dt:.0f} QPS on this CPU)")
+          f"clients in {dt:.2f}s ({served / dt:.0f} QPS on "
+          f"{_device_label()})")
     print(f"[latency] p50={1e3 * p50:.1f}ms p99={1e3 * p99:.1f}ms "
           f"per request")
     _print_engine_report(engine)
@@ -262,6 +270,7 @@ def _run_http(args, index, engine, search_kw):
 
 
 def main(argv=None):
+    compile_cache.enable()
     p = argparse.ArgumentParser()
     p.add_argument("--n", type=int, default=100_000)
     p.add_argument("--dim", type=int, default=256)
@@ -475,7 +484,7 @@ def main(argv=None):
                              [50, 99])
     print(f"[serve] {args.queries} queries "
           f"({len(tickets)} requests x {args.req_batch}) in {dt:.2f}s "
-          f"({args.queries / dt:.0f} QPS on this CPU)")
+          f"({args.queries / dt:.0f} QPS on {_device_label()})")
     print(f"[latency] p50={1e3 * p50:.1f}ms "
           f"p99={1e3 * p99:.1f}ms per request")
     snap = _print_engine_report(engine)

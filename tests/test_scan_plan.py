@@ -1,6 +1,6 @@
 """ScanPlan: one scoring path for dense, gathered and sharded scans.
 
-Covers: the masked-gather kernel family (scalar-prefetch DMA gather)
+Covers: the masked-gather kernel family (packed-word gather)
 against its rowwise oracle on ragged candidate lists with pad ids;
 exact equality of fused gather selection vs materialize-then-``top_k``;
 the dynamic ``n_valid`` row masking of the dense selection kernel;
@@ -70,7 +70,7 @@ CASES = [
 @pytest.mark.parametrize("metric", METRICS)
 @pytest.mark.parametrize("b,d,n,m,C_,R", CASES)
 def test_gather_kernel_vs_rowwise_oracle(metric, b, d, n, m, C_, R):
-    """The scalar-prefetch DMA-gather kernel matches the rowwise oracle
+    """The masked-gather kernel matches the rowwise oracle
     on ragged candidate lists; pad ids score exactly -inf."""
     codes, q, scale, offset, cluster, ipq, qterm, rowterm = _mk_inputs(
         jax.random.PRNGKey(b * 31 + d), b, d, n, m, C_

@@ -1,0 +1,146 @@
+"""Compile the served Pallas kernels for a TPU v5e, without the chip.
+
+The TPU compiler ships with the installed libtpu and compiles for a
+described, unattached chip.  That catches what interpret mode cannot:
+block shapes the chip refuses, VMEM overruns, and HBM blow-ups from
+layouts XLA must copy into.  Shapes are the served ones: the
+sift-128-euclidean smoke corpus (1M rows, d=64, b=2, 1024 landmarks,
+L2) and a 10M-row scan for the memory bound.  Nothing runs; no result
+is checked here (the interpret-mode oracle tests do that).
+
+The topology is described inside a module fixture, never at import:
+only one process may load libtpu at a time, and under several pytest
+workers only the worker given this file may touch it.
+"""
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.kernels.ash_score import (
+    DEFAULT_BLOCK_N,
+    ash_score_coarse_topk_pallas,
+    ash_score_gather_topk_pallas,
+    ash_score_topk_pallas,
+)
+
+N, D, B, C = 1_000_000, 64, 2, 1024
+W = D * B // 32  # packed uint32 words per row
+# IVF candidates per query at nprobe=16: 16 lists padded to 4096 rows,
+# above the smoke corpus's longest list (1,670 rows at seed 0)
+SMOKE_IVF_R = 16 * 4096
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        return topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def spec(topo):
+    """ShapeDtypeStruct factory on one described chip, with JAX's
+    persistent cache off (a chip compile written there cannot be read
+    back without the chip)."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    chip = SingleDeviceSharding(topo.devices[0])
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=chip)
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def _dense(spec, n, m):
+    """codes, q_proj, scale, offset, cluster, ipq, qterm, rowterm."""
+    f32 = jnp.float32
+    return [
+        spec((n, W), jnp.uint32), spec((m, D), f32), spec((n,), f32),
+        spec((n,), f32), spec((n,), jnp.int32), spec((m, C), f32),
+        spec((m,), f32), spec((n,), f32),
+    ]
+
+
+def _compile(fn, *args):
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    return compiled
+
+
+@pytest.mark.parametrize("k", (10, 100))
+def test_dense_fused_topk_compiles(spec, k):
+    _compile(
+        lambda *a: ash_score_topk_pallas(
+            *a, b=B, k=k, metric="l2", compute_dtype=jnp.float32
+        ),
+        *_dense(spec, N, 8),
+    )
+
+
+def test_dense_fused_topk_row_mask_compiles(spec):
+    """The tombstone / shard-padding variant: a (1, n) mask operand."""
+    _compile(
+        lambda *a: ash_score_topk_pallas(
+            *a[:8], None, a[8], b=B, k=10, metric="l2",
+            compute_dtype=jnp.float32,
+        ),
+        *_dense(spec, N, 8), spec((N,), jnp.bool_),
+    )
+
+
+def test_coarse_fused_topk_compiles(spec):
+    f32 = jnp.float32
+    args = [
+        spec((N, W), jnp.uint32), spec((8, D), jnp.int8),
+        spec((8,), f32), spec((8,), f32), spec((N,), f32),
+        spec((N,), f32), spec((N,), jnp.int32), spec((8, C), f32),
+        spec((8,), f32), spec((N,), f32),
+    ]
+    _compile(
+        lambda *a: ash_score_coarse_topk_pallas(
+            *a, b=B, k=32, metric="l2"
+        ),
+        *args,
+    )
+
+
+def test_gather_fused_topk_compiles_at_ivf_width(spec):
+    codes, q, scale, offset, cluster, ipq, qterm, rowterm = _dense(
+        spec, N, 8
+    )
+    rows = spec((8, SMOKE_IVF_R), jnp.int32)
+    _compile(
+        lambda *a: ash_score_gather_topk_pallas(
+            *a, b=B, k=10, metric="l2", compute_dtype=jnp.float32
+        ),
+        codes, rows, q, scale, offset, cluster, ipq, qterm, rowterm,
+    )
+
+
+@pytest.mark.parametrize("m", (8, 128))
+def test_dense_fused_topk_memory_at_10m_rows(spec, m):
+    """Temporaries stay within twice the codes plus the logical
+    candidate strip: no lane-padded copy of the narrow codes array
+    (once 32x the codes) and no per-call padding of it."""
+    n, k = 10_000_000, 10
+    compiled = _compile(
+        lambda *a: ash_score_topk_pallas(
+            *a[:6], b=B, k=k, metric="dot", compute_dtype=jnp.float32
+        ),
+        *_dense(spec, n, m)[:6],
+    )
+    codes_bytes = n * W * 4
+    strip_bytes = m * -(-n // DEFAULT_BLOCK_N) * k * 8
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp <= 2 * (codes_bytes + strip_bytes), (temp, codes_bytes)
