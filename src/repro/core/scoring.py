@@ -30,13 +30,14 @@ _EPS = 1e-12
 @jax.jit
 def prepare_queries(model: ASHModel, q: jax.Array) -> QueryPrep:
     """One-time per-query work: q_breve = W q, <q, mu_c>, ||q||^2."""
-    q32 = q.astype(jnp.float32)
-    return QueryPrep(
-        q=q32,
-        q_proj=q32 @ model.W.T,
-        ip_q_landmarks=q32 @ model.landmarks.T,
-        q_sq_norm=jnp.sum(q32 * q32, axis=-1),
-    )
+    with jax.named_scope("ash.prep"):
+        q32 = q.astype(jnp.float32)
+        return QueryPrep(
+            q=q32,
+            q_proj=q32 @ model.W.T,
+            ip_q_landmarks=q32 @ model.landmarks.T,
+            q_sq_norm=jnp.sum(q32 * q32, axis=-1),
+        )
 
 
 # ---------------------------------------------------------------------------

@@ -542,13 +542,14 @@ def exact_rerank(
     (m, k); entries without a valid candidate get score ``NEG_INF`` and
     id ``-1``.
     """
-    cand = raw[jnp.maximum(shortlist_rows, 0)].astype(jnp.float32)
-    exact = exact_scores(prep, cand, metric)
-    exact = jnp.where(jnp.isneginf(shortlist_scores), NEG_INF, exact)
-    rs, ri = jax.lax.top_k(exact, k)
-    rows_k = jnp.take_along_axis(shortlist_rows, ri, axis=1)
-    out = rows_k if ids is None else ids[jnp.maximum(rows_k, 0)]
-    return rs, jnp.where(jnp.isneginf(rs), -1, out)
+    with jax.named_scope("ash.rerank"):
+        cand = raw[jnp.maximum(shortlist_rows, 0)].astype(jnp.float32)
+        exact = exact_scores(prep, cand, metric)
+        exact = jnp.where(jnp.isneginf(shortlist_scores), NEG_INF, exact)
+        rs, ri = jax.lax.top_k(exact, k)
+        rows_k = jnp.take_along_axis(shortlist_rows, ri, axis=1)
+        out = rows_k if ids is None else ids[jnp.maximum(rows_k, 0)]
+        return rs, jnp.where(jnp.isneginf(rs), -1, out)
 
 
 # ---------------------------------------------------------------------------

@@ -323,11 +323,12 @@ def _probe_lists(
     best-first.  Nearest by L2 == max <q,mu> - ||mu||^2/2, computed
     from the prep's landmark inner products (already materialized for
     residual centering), so exposing it costs one top-k."""
-    coarse = (
-        prep.ip_q_landmarks
-        - 0.5 * index.model.landmark_sq_norms[None, :]
-    )
-    return jax.lax.top_k(coarse, nprobe)[1]  # (m, nprobe)
+    with jax.named_scope("ash.probe"):
+        coarse = (
+            prep.ip_q_landmarks
+            - 0.5 * index.model.landmark_sq_norms[None, :]
+        )
+        return jax.lax.top_k(coarse, nprobe)[1]  # (m, nprobe)
 
 
 @functools.partial(
@@ -420,14 +421,16 @@ def _score_probed_impl(
     the packed codes (pad ids mask to ``-inf``) and fuses the
     selection; no (m, nprobe*L) score matrix reaches HBM on TPU."""
     m = prep.q.shape[0]
-    cand_rows = index.invlists[probe].reshape(m, -1)  # (m, nprobe*L)
-    if index.live is not None:
-        # drop tombstoned rows pre-DMA: mapped to the -1 pad id, the
-        # gather kernel never issues a copy for them and the epilogue
-        # masks the slot to -inf — identical to list padding
-        cand_rows = jnp.where(
-            index.live[jnp.maximum(cand_rows, 0)], cand_rows, -1
-        )
+    with jax.named_scope("ash.candidates"):
+        cand_rows = index.invlists[probe].reshape(m, -1)  # (m, nprobe*L)
+        if index.live is not None:
+            # drop tombstoned rows pre-DMA: mapped to the -1 pad id, the
+            # gather kernel never issues a copy for them and the
+            # epilogue masks the slot to -inf — identical to list
+            # padding
+            cand_rows = jnp.where(
+                index.live[jnp.maximum(cand_rows, 0)], cand_rows, -1
+            )
     plan = C.ScanPlan(
         metric=index.metric, k=k, rerank=rerank, rows=cand_rows,
         ids=index.ids, coarse=coarse, shortlist=shortlist,

@@ -219,13 +219,16 @@ def _merge_strip(vals, ids, m: int, k: int):
     the same but takes ~30 s to compile for the chip, and an int32 key
     ~15 s; float top_k compiles in well under one.)"""
     mb, N, bm = vals.shape
-    vals = vals.transpose(0, 2, 1).reshape(mb * bm, N)[:m]
-    ids = ids.transpose(0, 2, 1).reshape(mb * bm, N)[:m]
-    _, pos = jax.lax.top_k(jnp.where(ids == _ID_SENTINEL, _NEG_NAN, vals), k)
-    return (
-        jnp.take_along_axis(vals, pos, axis=1),
-        jnp.take_along_axis(ids, pos, axis=1),
-    )
+    with jax.named_scope("ash.merge"):
+        vals = vals.transpose(0, 2, 1).reshape(mb * bm, N)[:m]
+        ids = ids.transpose(0, 2, 1).reshape(mb * bm, N)[:m]
+        _, pos = jax.lax.top_k(
+            jnp.where(ids == _ID_SENTINEL, _NEG_NAN, vals), k
+        )
+        return (
+            jnp.take_along_axis(vals, pos, axis=1),
+            jnp.take_along_axis(ids, pos, axis=1),
+        )
 
 
 def _strip_outputs(k, k_tilde, tile, n_tiles, q_blocks, q_blk, index_map):
@@ -606,32 +609,35 @@ def _gather_call(
         constant_values=-1,
     )
     safe = jnp.maximum(rows_p, 0)
-    # the probed rows' packed words, per query and lane-dense: (m_p,
-    # w_p, R_p) — only W words per candidate move, never unpacked
-    codes_g = jnp.pad(
-        jnp.swapaxes(codes[safe], 1, 2),
-        ((0, 0), (0, d_p // kpw - W), (0, 0)),
-    )
     q3 = jnp.pad(q, ((0, m_p - m), (0, d_p - d_pad)))[:, :, None]
-    bias_g = jnp.take_along_axis(
-        jnp.pad(ipq.astype(jnp.float32), ((0, m_p - m), (0, 0))),
-        cluster[safe].astype(jnp.int32), axis=1,
-    )
+    with jax.named_scope("ash.gather"):
+        # the probed rows' packed words, per query and lane-dense:
+        # (m_p, w_p, R_p) — only W words per candidate move, never
+        # unpacked
+        codes_g = jnp.pad(
+            jnp.swapaxes(codes[safe], 1, 2),
+            ((0, 0), (0, d_p // kpw - W), (0, 0)),
+        )
+        bias_g = jnp.take_along_axis(
+            jnp.pad(ipq.astype(jnp.float32), ((0, m_p - m), (0, 0))),
+            cluster[safe].astype(jnp.int32), axis=1,
+        )
+        operands = [
+            q3, codes_g, scale.astype(jnp.float32)[safe], bias_g,
+            offset.astype(jnp.float32)[safe], rows_p,
+        ]
+        if metric != "dot":
+            operands += [
+                jnp.pad(qterm.astype(jnp.float32), (0, m_p - m))[:, None],
+                rowterm.astype(jnp.float32)[safe],
+            ]
     tile = pl.BlockSpec((bq, br), lambda i, j, kd: (i, j))
-    operands = [
-        q3, codes_g, scale.astype(jnp.float32)[safe], bias_g,
-        offset.astype(jnp.float32)[safe], rows_p,
-    ]
     in_specs = [
         pl.BlockSpec((bq, bd, 1), lambda i, j, kd: (i, kd, 0)),
         pl.BlockSpec((bq, bw, br), lambda i, j, kd: (i, kd, j)),
         tile, tile, tile, tile,
     ]
     if metric != "dot":
-        operands += [
-            jnp.pad(qterm.astype(jnp.float32), (0, m_p - m))[:, None],
-            rowterm.astype(jnp.float32)[safe],
-        ]
         in_specs += [pl.BlockSpec((bq, 1), lambda i, j, kd: (i, 0)), tile]
 
     kt = None

@@ -53,6 +53,22 @@ def _auto_interpret() -> bool:
     return jax.default_backend() == "cpu"
 
 
+def _scan_scope(fn):
+    """Run a scoring entry point, kernel or oracle, under the
+    ``ash.scan`` scope.  It wraps the jitted kernel wrapper from
+    outside: a scope inside the wrapper would rename the kernel's
+    custom-call instruction.  (One ``named_scope`` object used as the
+    decorator would keep its saved name stack on itself, shared by
+    every thread and every nested call; each call opens its own.)"""
+
+    @functools.wraps(fn)
+    def scoped(*args, **kwargs):
+        with jax.named_scope("ash.scan"):
+            return fn(*args, **kwargs)
+
+    return scoped
+
+
 def _metric_operands(
     model: ASHModel,
     prep: QueryPrep,
@@ -101,6 +117,7 @@ def _score_args(prep: QueryPrep, payload: ASHPayload):
     )
 
 
+@_scan_scope
 def ash_score(
     model: ASHModel,
     prep: QueryPrep,
@@ -148,6 +165,7 @@ def mask_valid_rows(
     return ref.mask_rows_ref(scores, n_valid, row_valid)
 
 
+@_scan_scope
 def ash_score_topk(
     model: ASHModel,
     prep: QueryPrep,
@@ -188,7 +206,8 @@ def ash_score_topk(
             *args, qterm, rowterm, b=payload.b, metric=metric
         )
         scores = mask_valid_rows(scores, n_valid, row_valid)
-        return jax.lax.top_k(scores, k)
+        with jax.named_scope("ash.merge"):
+            return jax.lax.top_k(scores, k)
     return ash_score_topk_pallas(
         *args, qterm, rowterm, n_valid, row_valid, b=payload.b, k=k,
         k_tilde=k_tilde, metric=metric, interpret=interpret,
@@ -196,6 +215,7 @@ def ash_score_topk(
     )
 
 
+@_scan_scope
 def ash_score_gather(
     model: ASHModel,
     prep: QueryPrep,
@@ -234,6 +254,7 @@ def ash_score_gather(
     )
 
 
+@_scan_scope
 def ash_score_gather_topk(
     model: ASHModel,
     prep: QueryPrep,
@@ -266,8 +287,9 @@ def ash_score_gather_topk(
             codes, rows, q_proj, scale, offset, cluster, ipq,
             qterm, rowterm, b=payload.b, metric=metric,
         )
-        s, pos = jax.lax.top_k(scores, k)
-        return s, jnp.take_along_axis(rows, pos, axis=1)
+        with jax.named_scope("ash.merge"):
+            s, pos = jax.lax.top_k(scores, k)
+            return s, jnp.take_along_axis(rows, pos, axis=1)
     return ash_score_gather_topk_pallas(
         codes, rows, q_proj, scale, offset, cluster, ipq, qterm, rowterm,
         b=payload.b, k=k, k_tilde=k_tilde, metric=metric,
@@ -355,6 +377,7 @@ def _coarse_gather_ref_scores(
     )
 
 
+@_scan_scope
 def ash_score_coarse(
     model: ASHModel,
     prep: QueryPrep,
@@ -395,6 +418,7 @@ def ash_score_coarse(
     )
 
 
+@_scan_scope
 def ash_score_coarse_topk(
     model: ASHModel,
     prep: QueryPrep,
@@ -439,7 +463,8 @@ def ash_score_coarse_topk(
             interpret=interpret,
         )
         scores = mask_valid_rows(scores, n_valid, row_valid)
-        return jax.lax.top_k(scores, k)
+        with jax.named_scope("ash.merge"):
+            return jax.lax.top_k(scores, k)
     return ash_score_coarse_topk_pallas(
         *args, qterm, rowterm, n_valid, row_valid, b=payload.b, k=k,
         k_tilde=k_tilde, metric=metric, interpret=interpret,

@@ -97,11 +97,59 @@ working); when a ``serving.frontend.ServingFrontend`` drives the
 engine (``engine.driven``), ``result()`` only waits — the driver owns
 the flush cadence, so an eager caller cannot defeat batching by
 flushing a group early.
+
+Tracing
+-------
+
+The serving path emits host spans (``jax.profiler.TraceAnnotation``)
+that land in the profiler's trace beside the device operations, on the
+same clock.  With no profiler session running each costs about a
+microsecond and formats nothing.  Parentage is nesting on one thread;
+metadata arrives as the event's stats.
+
+==========================  ==========================================
+span                        times (metadata)
+==========================  ==========================================
+``ash.submit``              ``QueryEngine.submit`` (rows)
+``ash.submit.backpressure`` ``ServingFrontend.submit`` blocked on a
+                            full queue
+``ash.flush``               one popped group, all its chunks (reason,
+                            requests, rows)
+``ash.mutations``           one applied mutation batch (rows)
+``ash.plan``                the flush's chunk planning (chunks)
+``ash.call``                one fused call (call: the engine-wide
+                            sequence number that the call's tickets
+                            carry as ``RequestStats.call_id``; rows,
+                            bucket, k, nprobe — 0 for a dense scan)
+``ash.prep``                query prep for the bucket (hits, misses)
+``ash.prep.hash``           row keys and prep-cache lookup
+``ash.prep.device``         ``prepare`` on the device, to ready
+``ash.prep.fill``           device-to-host copies and cache puts
+``ash.prep.stack``          host stack of cached rows, one device put
+``ash.search.dispatch``     ``search_prepped`` until it returns
+``ash.search.wait``         waiting for its result to be ready
+``ash.fetch``               copying scores and ids to the host
+``ash.scatter``             per-request slicing and ticket resolution
+==========================  ==========================================
+
+Inside ``ash.call`` the children run in the order listed.  The driver's
+idle wait between ticks carries no span.  The jitted search programs
+carry ``jax.named_scope`` names in their operations' metadata
+(``ash.prep``, ``ash.probe``, ``ash.candidates``, ``ash.gather``,
+``ash.scan``, ``ash.merge``, ``ash.rerank``; the innermost names the
+layer).  To see both, trace a stretch of serving and open the result in
+the xprof / TensorBoard trace viewer::
+
+    with jax.profiler.trace("/tmp/ash-trace"):
+        for q in queries:
+            frontend.submit(q, k=10)
+        ...  # wait for the tickets
 """
 from __future__ import annotations
 
 import dataclasses
 import hashlib
+import itertools
 import threading
 import time
 from collections import OrderedDict
@@ -117,6 +165,8 @@ from repro.serving.cache import ByteLRU
 from repro.testing import faults
 
 NEG_INF = float("-inf")
+
+_span = jax.profiler.TraceAnnotation
 
 # backends that route coarsely through inverted lists: nprobe grouping,
 # the candidate-row cost model and adaptive probing apply to all of
@@ -277,11 +327,16 @@ def _pad_rows(rows: np.ndarray, bucket: int) -> np.ndarray:
 class RequestStats:
     """Per-request serving stats, filled when the request completes."""
 
-    queue_wait_s: float = 0.0  # submit -> scoring start
+    # submit -> start of the fused call, after query prep (the start
+    # of the ash.search.dispatch span)
+    queue_wait_s: float = 0.0
     latency_s: float = 0.0  # submit -> result scattered back
     batch_rows: int = 0  # real rows in the fused call
     bucket_rows: int = 0  # padded rows (the trace shape)
     scoring_us: float = 0.0  # fused scoring call, whole bucket
+    # the fused call that served this request: the ``call`` of its
+    # ash.call span, an engine-wide sequence number from 1
+    call_id: int = 0
     prep_hits: int = 0  # this request's rows found in the prep cache
     prep_misses: int = 0
     # "size" | "budget" (the group's deduped candidate-row bill hit
@@ -639,6 +694,8 @@ class QueryEngine:
         # incrementally so the budget check stays O(nprobe) per request
         # instead of re-deduping the whole group's probes every time
         self._group_bills: Dict[tuple, tuple] = {}
+        # fused-call sequence numbers (RequestStats.call_id)
+        self._call_ids = itertools.count(1)
         # set by ServingFrontend: when True, submit() signals the
         # driver instead of flushing inline and result() only waits
         self.driven = False
@@ -1023,84 +1080,86 @@ class QueryEngine:
             raise ValueError(f"k must be >= 1: {k}")
         if deadline_s is not None and deadline_s < 0:
             raise ValueError(f"deadline_s must be >= 0: {deadline_s}")
-        backend = idx.backend
-        if backend not in _IVF_LIKE:
-            nprobe = None  # only IVF routes coarsely; don't split groups
-        else:
-            # normalize to the effective value (default applied, clamped
-            # to the invlist count) so nprobe=None, the explicit default
-            # and any over-large value share one group/bucket/trace
-            nprobe = idx._backend.resolve_nprobe(idx._state, nprobe)
-        # rerank requests must reproduce the direct path's shortlist of
-        # max(rerank, k) candidates, so that size is part of the group
-        # key and _run_batch clamps k_run to it.  Requests with
-        # rerank >= k all share one group (shortlist == rerank); a
-        # request with rerank < k gets its own (shortlist == its k) —
-        # mixed-k groups there cannot share a fused call bit-identically.
-        shortlist = max(rerank, k) if rerank else None
-        group = (index, nprobe, rerank, shortlist,
-                 tuple(sorted(opts.items())))
+        with _span("ash.submit", rows=q.shape[0]):
+            backend = idx.backend
+            if backend not in _IVF_LIKE:
+                nprobe = None  # only IVF routes coarsely; don't split groups
+            else:
+                # normalize to the effective value (default applied, clamped
+                # to the invlist count) so nprobe=None, the explicit default
+                # and any over-large value share one group/bucket/trace
+                nprobe = idx._backend.resolve_nprobe(idx._state, nprobe)
+            # rerank requests must reproduce the direct path's shortlist of
+            # max(rerank, k) candidates, so that size is part of the group
+            # key and _run_batch clamps k_run to it.  Requests with
+            # rerank >= k all share one group (shortlist == rerank); a
+            # request with rerank < k gets its own (shortlist == its k) —
+            # mixed-k groups there cannot share a fused call bit-identically.
+            shortlist = max(rerank, k) if rerank else None
+            group = (index, nprobe, rerank, shortlist,
+                     tuple(sorted(opts.items())))
 
-        driven = self.driven
-        if not driven:
-            # bounded queue: free space by serving, never by dropping
+            driven = self.driven
+            if not driven:
+                # bounded queue: free space by serving, never by dropping
+                with self._lock:
+                    pressured = (
+                        self._pending_rows + q.shape[0]
+                        > self.config.max_pending
+                        and self._pending_rows > 0
+                    )
+                if pressured:
+                    self._try_flush(self._flush_all, "pressure")
+
+            probe = None
+            if self._cost_model_on(idx, nprobe):
+                probe = self._host_probe(index, idx, q, nprobe)
+
+            now = time.perf_counter()
+            deadline = None if deadline_s is None else now + deadline_s
+            ticket = Ticket(self, group, k, q.shape[0], deadline)
             with self._lock:
-                pressured = (
-                    self._pending_rows + q.shape[0] > self.config.max_pending
-                    and self._pending_rows > 0
+                self._pending.setdefault(group, []).append(
+                    _Request(q, k, ticket, now, deadline, probe)
                 )
-            if pressured:
-                self._try_flush(self._flush_all, "pressure")
+                if probe is not None:
+                    self._bill_probe(group, index, idx, probe)
+                self._pending_rows += q.shape[0]
+                self.stats.requests += 1
+                self.stats.queue_hwm = max(
+                    self.stats.queue_hwm, self._pending_rows
+                )
+                group_full = (
+                    self._group_rows(group) >= self.config.batch_buckets[-1]
+                )
+                over_bound = self._pending_rows > self.config.max_pending
+                # cost model: a group whose deduped candidate-row bill
+                # already exceeds the budget gains nothing by waiting for
+                # the bucket to fill — every extra query only deepens the
+                # serialized gather behind all its tickets
+                budget_full = (
+                    not group_full
+                    and probe is not None
+                    and self._group_over_budget(group)
+                )
 
-        probe = None
-        if self._cost_model_on(idx, nprobe):
-            probe = self._host_probe(index, idx, q, nprobe)
-
-        now = time.perf_counter()
-        deadline = None if deadline_s is None else now + deadline_s
-        ticket = Ticket(self, group, k, q.shape[0], deadline)
-        with self._lock:
-            self._pending.setdefault(group, []).append(
-                _Request(q, k, ticket, now, deadline, probe)
-            )
-            if probe is not None:
-                self._bill_probe(group, index, idx, probe)
-            self._pending_rows += q.shape[0]
-            self.stats.requests += 1
-            self.stats.queue_hwm = max(
-                self.stats.queue_hwm, self._pending_rows
-            )
-            group_full = (
-                self._group_rows(group) >= self.config.batch_buckets[-1]
-            )
-            over_bound = self._pending_rows > self.config.max_pending
-            # cost model: a group whose deduped candidate-row bill
-            # already exceeds the budget gains nothing by waiting for
-            # the bucket to fill — every extra query only deepens the
-            # serialized gather behind all its tickets
-            budget_full = (
-                not group_full
-                and probe is not None
-                and self._group_over_budget(group)
-            )
-
-        if driven:
-            # wake the driver only when this submit made something
-            # flushable — a fillable bucket, an over-budget bill, or
-            # queue pressure.  Sub-bucket groups ride the driver's
-            # poll tick instead (bounded by poll_interval_s), so a
-            # burst of submits costs one driver scan, not one per row
-            if group_full or budget_full or over_bound:
-                self._notify_work()
-        elif group_full or over_bound:
-            # bucket fillable, or a single request alone exceeds the
-            # queue bound: serve now rather than sit past max_pending
-            self._try_flush(self._flush_group, group, "size")
-        elif budget_full:
-            self._try_flush(self._flush_group, group, "budget")
-        else:
-            self._try_flush(self.poll)
-        return ticket
+            if driven:
+                # wake the driver only when this submit made something
+                # flushable — a fillable bucket, an over-budget bill, or
+                # queue pressure.  Sub-bucket groups ride the driver's
+                # poll tick instead (bounded by poll_interval_s), so a
+                # burst of submits costs one driver scan, not one per row
+                if group_full or budget_full or over_bound:
+                    self._notify_work()
+            elif group_full or over_bound:
+                # bucket fillable, or a single request alone exceeds the
+                # queue bound: serve now rather than sit past max_pending
+                self._try_flush(self._flush_group, group, "size")
+            elif budget_full:
+                self._try_flush(self._flush_group, group, "budget")
+            else:
+                self._try_flush(self.poll)
+            return ticket
 
     def search(self, queries, k: int = 10, **kw):
         """Synchronous convenience: submit + resolve immediately.
@@ -1218,98 +1277,105 @@ class QueryEngine:
                     or self._pending_deletes.get(name)
                     or idx.pending_rows
                 )
+                backlog = self._mutation_backlog(name)
             if not has_work:
                 return 0
-            # fired before the batch leaves the queues: a failure here
-            # (crash or transient error) leaves everything queued for a
-            # clean retry
-            faults.fire(_FAULT_APPLY)
-            with self._lock:
-                adds = self._add_tickets.pop(name, [])
-                dels = self._pending_deletes.pop(name, [])
-                self._mutation_t0.pop(name, None)
-                wal = self._wals.get(name)
-            if not adds and not dels and idx.pending_rows == 0:
-                return 0
-            if wal is not None and (adds or dels):
-                try:
-                    # submission order: adds before deletes, matching
-                    # the apply below — replay is order-faithful.  A
-                    # ticket logged by an earlier, failed apply keeps
-                    # its seqno (idempotent retry, no double record).
-                    for ticket in adds:
-                        if ticket.wal_seqno is None:
-                            ticket.wal_seqno = wal.log_add(
-                                ticket._rows, ticket.ids
-                            )
-                        ticket._rows = None
-                    for del_ids, ticket in dels:
-                        if ticket.wal_seqno is None:
-                            ticket.wal_seqno = wal.log_delete(del_ids)
-                except Exception as e:
-                    # logging failed (disk full, ...): requeue the
-                    # whole batch for a later retry — tickets stay
-                    # unresolved rather than acknowledging work the
-                    # log does not hold
-                    with self._lock:
-                        self._add_tickets[name] = (
-                            adds + self._add_tickets.get(name, [])
-                        )
-                        self._pending_deletes[name] = (
-                            dels + self._pending_deletes.get(name, [])
-                        )
-                        pending = (
-                            adds + [t for _, t in dels]
-                        )
-                        self._mutation_t0[name] = min(
-                            t.t_enqueue for t in pending
-                        )
-                        self.stats.wal_failures += 1
-                        self.stats.wal_last_error = repr(e)
-                    raise
-            faults.fire(_FAULT_APPLY_LOGGED)
-            t0 = time.perf_counter()
+            with _span("ash.mutations", rows=backlog):
+                return self._apply_batch(name, idx)
+
+    def _apply_batch(self, name: str, idx: AshIndex) -> int:
+        """The body of :meth:`_apply_mutations` once there is work
+        (caller holds the index's mutation barrier)."""
+        # fired before the batch leaves the queues: a failure here
+        # (crash or transient error) leaves everything queued for a
+        # clean retry
+        faults.fire(_FAULT_APPLY)
+        with self._lock:
+            adds = self._add_tickets.pop(name, [])
+            dels = self._pending_deletes.pop(name, [])
+            self._mutation_t0.pop(name, None)
+            wal = self._wals.get(name)
+        if not adds and not dels and idx.pending_rows == 0:
+            return 0
+        if wal is not None and (adds or dels):
             try:
-                applied = idx.apply_pending()
-                removed = 0
+                # submission order: adds before deletes, matching
+                # the apply below — replay is order-faithful.  A
+                # ticket logged by an earlier, failed apply keeps
+                # its seqno (idempotent retry, no double record).
+                for ticket in adds:
+                    if ticket.wal_seqno is None:
+                        ticket.wal_seqno = wal.log_add(
+                            ticket._rows, ticket.ids
+                        )
+                    ticket._rows = None
                 for del_ids, ticket in dels:
-                    removed_now = idx.delete(del_ids)
-                    ticket._result = removed_now
-                    removed += removed_now
+                    if ticket.wal_seqno is None:
+                        ticket.wal_seqno = wal.log_delete(del_ids)
             except Exception as e:
-                for ticket in adds + [t for _, t in dels]:
-                    ticket._fail(e)
+                # logging failed (disk full, ...): requeue the
+                # whole batch for a later retry — tickets stay
+                # unresolved rather than acknowledging work the
+                # log does not hold
+                with self._lock:
+                    self._add_tickets[name] = (
+                        adds + self._add_tickets.get(name, [])
+                    )
+                    self._pending_deletes[name] = (
+                        dels + self._pending_deletes.get(name, [])
+                    )
+                    pending = (
+                        adds + [t for _, t in dels]
+                    )
+                    self._mutation_t0[name] = min(
+                        t.t_enqueue for t in pending
+                    )
+                    self.stats.wal_failures += 1
+                    self.stats.wal_last_error = repr(e)
                 raise
-            faults.fire(_FAULT_APPLY_APPLIED)
-            if (
-                dels
-                and self.config.auto_compact is not None
-                and idx.dead_fraction > self.config.auto_compact
-            ):
-                if self._compactor is not None:
-                    # compaction cost leaves the serving path: the
-                    # worker builds survivor arrays off-thread and
-                    # swaps them in between flushes
-                    self._compactor.request(name)
-                else:
-                    n_before = idx.n
-                    idx.compact(self.config.auto_compact)
-                    if idx.n != n_before:
-                        with self._lock:
-                            self.stats.compactions += 1
-                        if wal is not None:
-                            wal.log_marker("compact")
-            dt = time.perf_counter() - t0
-            for ticket in adds:
-                ticket._result = ticket.ids
+        faults.fire(_FAULT_APPLY_LOGGED)
+        t0 = time.perf_counter()
+        try:
+            applied = idx.apply_pending()
+            removed = 0
+            for del_ids, ticket in dels:
+                removed_now = idx.delete(del_ids)
+                ticket._result = removed_now
+                removed += removed_now
+        except Exception as e:
             for ticket in adds + [t for _, t in dels]:
-                ticket.apply_s = dt
-                ticket._fire()
-            with self._lock:
-                self.stats.mutation_batches += 1
-                self.stats.added_rows += applied
-                self.stats.deleted_rows += removed
-            return applied + removed
+                ticket._fail(e)
+            raise
+        faults.fire(_FAULT_APPLY_APPLIED)
+        if (
+            dels
+            and self.config.auto_compact is not None
+            and idx.dead_fraction > self.config.auto_compact
+        ):
+            if self._compactor is not None:
+                # compaction cost leaves the serving path: the
+                # worker builds survivor arrays off-thread and
+                # swaps them in between flushes
+                self._compactor.request(name)
+            else:
+                n_before = idx.n
+                idx.compact(self.config.auto_compact)
+                if idx.n != n_before:
+                    with self._lock:
+                        self.stats.compactions += 1
+                    if wal is not None:
+                        wal.log_marker("compact")
+        dt = time.perf_counter() - t0
+        for ticket in adds:
+            ticket._result = ticket.ids
+        for ticket in adds + [t for _, t in dels]:
+            ticket.apply_s = dt
+            ticket._fire()
+        with self._lock:
+            self.stats.mutation_batches += 1
+            self.stats.added_rows += applied
+            self.stats.deleted_rows += removed
+        return applied + removed
 
     # -- flushing -----------------------------------------------------
 
@@ -1527,30 +1593,33 @@ class QueryEngine:
                 self._group_bills.pop(group, None)
                 if not reqs:
                     return 0
-                self._pending_rows -= sum(
-                    r.queries.shape[0] for r in reqs
-                )
+                n_rows = sum(r.queries.shape[0] for r in reqs)
+                self._pending_rows -= n_rows
                 self.stats.flushes[reason] += 1
                 self._space.notify_all()  # queue rows freed
-            eff_nprobe, chunks, bills = self._plan_chunks(
-                group, reqs, pressure
-            )
-            for i, chunk in enumerate(chunks):
-                try:
-                    self._run_batch(
-                        group, chunk, reason,
-                        eff_nprobe=eff_nprobe, billed=bills[i],
+            with _span("ash.flush", reason=reason, requests=len(reqs),
+                       rows=n_rows):
+                with _span("ash.plan") as plan_span:
+                    eff_nprobe, chunks, bills = self._plan_chunks(
+                        group, reqs, pressure
                     )
-                except Exception as e:
-                    # the failed chunk's tickets carry the error
-                    # already (_run_batch); later chunks were popped
-                    # off the queue too, so resolve them with it as
-                    # well — no request may end up neither served nor
-                    # errored
-                    for later in chunks[i + 1:]:
-                        for r in later:
-                            r.ticket._fail(e)
-                    raise
+                    plan_span.set_metadata(chunks=len(chunks))
+                for i, chunk in enumerate(chunks):
+                    try:
+                        self._run_batch(
+                            group, chunk, reason,
+                            eff_nprobe=eff_nprobe, billed=bills[i],
+                        )
+                    except Exception as e:
+                        # the failed chunk's tickets carry the error
+                        # already (_run_batch); later chunks were
+                        # popped off the queue too, so resolve them
+                        # with it as well — no request may end up
+                        # neither served nor errored
+                        for later in chunks[i + 1:]:
+                            for r in later:
+                                r.ticket._fail(e)
+                        raise
             return len(reqs)
 
     def _plan_chunks(
@@ -1666,87 +1735,106 @@ class QueryEngine:
             # have degraded nprobe below the group's requested value
             nprobe = eff_nprobe
         idx = self._indexes[name]
-        try:
-            rows = np.concatenate([r.queries for r in reqs], axis=0)
-            n_real = rows.shape[0]
-            bucket = _bucketize(self.config.batch_buckets, n_real)
-            rows = _pad_rows(rows, bucket)
-            k_max = max(r.k for r in reqs)
-            k_run = min(
-                _bucketize(self.config.k_buckets, k_max), idx.n
-            )
-            if shortlist is not None:
-                # rerank: the backend's shortlist is max(rerank, k_run);
-                # the direct path's is max(rerank, k).  Every request in
-                # this group shares shortlist == max(rerank, its k)
-                # >= k_max (the group key guarantees it), so clamping
-                # k_run keeps the fused call's shortlist — hence its
-                # rerank candidates and results — bit-identical to
-                # per-request search.
-                k_run = min(k_run, shortlist)
-
-            prep, hit_rows = self._prep_for(name, idx, rows, n_real)
-            t_score = time.perf_counter()  # after prep/hash: the stat
-            scores, ids = jax.block_until_ready(  # is the fused call
-                idx.search_prepped(
-                    prep, k=k_run, nprobe=nprobe, rerank=rerank,
-                    **dict(opts),
+        call_id = next(self._call_ids)
+        with _span("ash.call", call=call_id) as call_span:
+            try:
+                rows = np.concatenate([r.queries for r in reqs], axis=0)
+                n_real = rows.shape[0]
+                bucket = _bucketize(self.config.batch_buckets, n_real)
+                rows = _pad_rows(rows, bucket)
+                k_max = max(r.k for r in reqs)
+                k_run = min(
+                    _bucketize(self.config.k_buckets, k_max), idx.n
                 )
-            )
-        except Exception as e:
-            # resolve every ticket with the error (a later result()
-            # re-raises it) before surfacing at the flush site — an
-            # explicit flush()/poll(); submit-triggered flushes swallow
-            # it (_try_flush) so the caller still gets its Ticket
-            for r in reqs:
-                r.ticket._fail(e)
-            raise
-        scoring_us = (time.perf_counter() - t_score) * 1e6
-        scores = np.asarray(scores)
-        ids = np.asarray(ids)
-
-        with self._lock:
-            self.stats.batches += 1
-            self.stats.batched_rows += n_real
-            self.stats.padded_rows += bucket - n_real
-            self.stats.compiled_buckets.add(
-                (name, idx.backend, bucket, k_run, nprobe, rerank, opts)
-            )
-
-        offset = 0
-        missed = 0
-        for r in reqs:
-            m = r.queries.shape[0]
-            s = scores[offset:offset + m]
-            i = ids[offset:offset + m]
-            if r.k <= k_run:  # top-k prefix of the bucket's top-k_run
-                s, i = s[:, : r.k], i[:, : r.k]
-            else:  # k > n: pad out with the missing-candidate sentinel
-                pad = r.k - k_run
-                s = np.concatenate(
-                    [s, np.full((m, pad), NEG_INF, s.dtype)], axis=1
+                if shortlist is not None:
+                    # rerank: the backend's shortlist is max(rerank,
+                    # k_run); the direct path's is max(rerank, k).
+                    # Every request in this group shares shortlist ==
+                    # max(rerank, its k) >= k_max (the group key
+                    # guarantees it), so clamping k_run keeps the fused
+                    # call's shortlist — hence its rerank candidates
+                    # and results — bit-identical to per-request
+                    # search.
+                    k_run = min(k_run, shortlist)
+                call_span.set_metadata(
+                    rows=n_real, bucket=bucket, k=k_run,
+                    nprobe=nprobe or 0,
                 )
-                i = np.concatenate(
-                    [i, np.full((m, pad), -1, i.dtype)], axis=1
+
+                with _span("ash.prep") as prep_span:
+                    prep, hit_rows = self._prep_for(
+                        name, idx, rows, n_real
+                    )
+                    hits = int(hit_rows.sum())
+                    prep_span.set_metadata(hits=hits, misses=n_real - hits)
+                # after prep: the stat is the fused call
+                t_score = time.perf_counter()
+                with _span("ash.search.dispatch"):
+                    out = idx.search_prepped(
+                        prep, k=k_run, nprobe=nprobe, rerank=rerank,
+                        **dict(opts),
+                    )
+                with _span("ash.search.wait"):
+                    scores, ids = jax.block_until_ready(out)
+            except Exception as e:
+                # resolve every ticket with the error (a later result()
+                # re-raises it) before surfacing at the flush site — an
+                # explicit flush()/poll(); submit-triggered flushes
+                # swallow it (_try_flush) so the caller still gets its
+                # Ticket
+                for r in reqs:
+                    r.ticket._fail(e)
+                raise
+            scoring_us = (time.perf_counter() - t_score) * 1e6
+            with _span("ash.fetch"):
+                scores = np.asarray(scores)
+                ids = np.asarray(ids)
+
+            with self._lock:
+                self.stats.batches += 1
+                self.stats.batched_rows += n_real
+                self.stats.padded_rows += bucket - n_real
+                self.stats.compiled_buckets.add(
+                    (name, idx.backend, bucket, k_run, nprobe, rerank,
+                     opts)
                 )
-            now = time.perf_counter()
-            st = r.ticket.stats
-            st.queue_wait_s = t_score - r.t_enqueue
-            st.latency_s = now - r.t_enqueue
-            st.batch_rows = n_real
-            st.bucket_rows = bucket
-            st.scoring_us = scoring_us
-            st.prep_hits = int(hit_rows[offset:offset + m].sum())
-            st.prep_misses = m - st.prep_hits
-            st.flush_reason = reason
-            if r.probe is not None and nprobe is not None:
-                st.effective_nprobe = nprobe
-                st.scanned_rows = billed
-            if r.deadline is not None and now > r.deadline:
-                st.deadline_missed = True
-                missed += 1
-            r.ticket._settle((s, i))
-            offset += m
+
+            offset = 0
+            missed = 0
+            with _span("ash.scatter"):
+                for r in reqs:
+                    m = r.queries.shape[0]
+                    s = scores[offset:offset + m]
+                    i = ids[offset:offset + m]
+                    if r.k <= k_run:  # top-k prefix of the bucket's top-k_run
+                        s, i = s[:, : r.k], i[:, : r.k]
+                    else:  # k > n: pad out with the missing-candidate sentinel
+                        pad = r.k - k_run
+                        s = np.concatenate(
+                            [s, np.full((m, pad), NEG_INF, s.dtype)], axis=1
+                        )
+                        i = np.concatenate(
+                            [i, np.full((m, pad), -1, i.dtype)], axis=1
+                        )
+                    now = time.perf_counter()
+                    st = r.ticket.stats
+                    st.queue_wait_s = t_score - r.t_enqueue
+                    st.latency_s = now - r.t_enqueue
+                    st.batch_rows = n_real
+                    st.bucket_rows = bucket
+                    st.scoring_us = scoring_us
+                    st.call_id = call_id
+                    st.prep_hits = int(hit_rows[offset:offset + m].sum())
+                    st.prep_misses = m - st.prep_hits
+                    st.flush_reason = reason
+                    if r.probe is not None and nprobe is not None:
+                        st.effective_nprobe = nprobe
+                        st.scanned_rows = billed
+                    if r.deadline is not None and now > r.deadline:
+                        st.deadline_missed = True
+                        missed += 1
+                    r.ticket._settle((s, i))
+                    offset += m
         if missed:
             with self._lock:
                 self.stats.deadline_missed += missed
@@ -1764,50 +1852,61 @@ class QueryEngine:
         if not self.config.prep_cache_enabled:
             with self._lock:
                 self.stats.prep_misses += n_real
-            return idx.prepare(jnp.asarray(rows)), hit_rows
+            with _span("ash.prep.device"):
+                return idx.prepare(jnp.asarray(rows)), hit_rows
 
-        keys = [
-            (name, hashlib.blake2b(rows[i].tobytes(),
-                                   digest_size=16).digest())
-            for i in range(bucket)
-        ]
-        row_preps: list = [None] * bucket
-        miss = []
-        with self._lock:
-            for i, key in enumerate(keys):
-                cached = self._prep_cache.get(key)
-                if cached is not None:
-                    row_preps[i] = cached
-                    if i < n_real:
-                        hit_rows[i] = True
-                else:
-                    miss.append(i)
-            self.stats.prep_hits += int(hit_rows.sum())
-            self.stats.prep_misses += n_real - int(hit_rows.sum())
+        with _span("ash.prep.hash"):
+            keys = [
+                (name, hashlib.blake2b(rows[i].tobytes(),
+                                       digest_size=16).digest())
+                for i in range(bucket)
+            ]
+            row_preps: list = [None] * bucket
+            miss = []
+            with self._lock:
+                for i, key in enumerate(keys):
+                    cached = self._prep_cache.get(key)
+                    if cached is not None:
+                        row_preps[i] = cached
+                        if i < n_real:
+                            hit_rows[i] = True
+                    else:
+                        miss.append(i)
+                self.stats.prep_hits += int(hit_rows.sum())
+                self.stats.prep_misses += n_real - int(hit_rows.sum())
 
         if not miss:
-            return self._stack_prep(row_preps), hit_rows
+            with _span("ash.prep.stack"):
+                return self._stack_prep(row_preps), hit_rows
         if len(miss) == bucket:
             # cold bucket: one prepare over the padded rows, no restack
             # (only real rows are cached — pad rows recur only while
             # buckets run underfilled and would waste LRU capacity)
-            prep = jax.block_until_ready(idx.prepare(jnp.asarray(rows)))
-            self._cache_prep_rows(keys, prep, range(n_real))
+            with _span("ash.prep.device"):
+                prep = jax.block_until_ready(
+                    idx.prepare(jnp.asarray(rows))
+                )
+            with _span("ash.prep.fill"):
+                self._cache_prep_rows(keys, prep, range(n_real))
             return prep, hit_rows
         # warm bucket: prepare only the misses (padded to a bucket shape
         # so prepare traces stay bounded), then merge with cached rows
         mb = _bucketize(self.config.batch_buckets, len(miss))
         miss_rows = _pad_rows(rows[miss], mb)
-        mp = jax.block_until_ready(idx.prepare(jnp.asarray(miss_rows)))
-        mp_np = tuple(np.asarray(a) for a in
-                      (mp.q, mp.q_proj, mp.ip_q_landmarks, mp.q_sq_norm))
-        for j, i in enumerate(miss):
-            row_preps[i] = tuple(a[j] for a in mp_np)
-        with self._lock:
-            for i in miss:
-                if i < n_real:
-                    self._prep_cache.put(keys[i], row_preps[i])
-        return self._stack_prep(row_preps), hit_rows
+        with _span("ash.prep.device"):
+            mp = jax.block_until_ready(idx.prepare(jnp.asarray(miss_rows)))
+        with _span("ash.prep.fill"):
+            mp_np = tuple(np.asarray(a) for a in
+                          (mp.q, mp.q_proj, mp.ip_q_landmarks,
+                           mp.q_sq_norm))
+            for j, i in enumerate(miss):
+                row_preps[i] = tuple(a[j] for a in mp_np)
+            with self._lock:
+                for i in miss:
+                    if i < n_real:
+                        self._prep_cache.put(keys[i], row_preps[i])
+        with _span("ash.prep.stack"):
+            return self._stack_prep(row_preps), hit_rows
 
     def _cache_prep_rows(self, keys, prep: QueryPrep, idxs) -> None:
         arrs = tuple(np.asarray(a) for a in

@@ -57,6 +57,7 @@ import time
 from typing import Optional
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.serving.engine import MutationTicket, QueryEngine, Ticket
 
@@ -258,30 +259,38 @@ class ServingFrontend:
             else time.perf_counter() + self.config.submit_timeout_s
         )
         with eng._space:
-            while (
-                not self._closed
-                and eng._pending_rows + n_rows > self._max_rows
-                and eng._pending_rows > 0
-            ):
-                remaining = (
-                    None if deadline is None
-                    else deadline - time.perf_counter()
-                )
-                if remaining is not None and remaining <= 0:
-                    raise TimeoutError(
-                        f"queue full ({eng._pending_rows} rows) for "
-                        f"{self.config.submit_timeout_s}s"
-                    )
-                self._work.set()  # make sure the driver is draining
-                eng._space.wait(
-                    remaining if remaining is not None
-                    else self.config.poll_interval_s
-                )
+            if self._must_wait(n_rows):
+                with TraceAnnotation("ash.submit.backpressure"):
+                    while self._must_wait(n_rows):
+                        remaining = (
+                            None if deadline is None
+                            else deadline - time.perf_counter()
+                        )
+                        if remaining is not None and remaining <= 0:
+                            raise TimeoutError(
+                                f"queue full ({eng._pending_rows} rows) for "
+                                f"{self.config.submit_timeout_s}s"
+                            )
+                        self._work.set()  # make sure the driver is draining
+                        eng._space.wait(
+                            remaining if remaining is not None
+                            else self.config.poll_interval_s
+                        )
             if self._closed:
                 raise FrontendClosed("frontend stopped")
             # still under the (re-entrant) lock: the space check and
             # the enqueue are atomic, so the bound is hard
             return eng.submit(queries, k, **kw)
+
+    def _must_wait(self, n_rows: int) -> bool:
+        """Whether ``n_rows`` more would pass the queue bound (caller
+        holds the engine's lock)."""
+        eng = self.engine
+        return (
+            not self._closed
+            and eng._pending_rows + n_rows > self._max_rows
+            and eng._pending_rows > 0
+        )
 
     def search(self, queries, k: int = 10, timeout: Optional[float] = None,
                **kw):

@@ -6,19 +6,28 @@ block shapes the chip refuses, VMEM overruns, and HBM blow-ups from
 layouts XLA must copy into.  Shapes are the served ones: the
 sift-128-euclidean smoke corpus (1M rows, d=64, b=2, 1024 landmarks,
 L2) and a 10M-row scan for the memory bound.  Nothing runs; no result
-is checked here (the interpret-mode oracle tests do that).
+is checked here (the interpret-mode oracle tests do that).  The served
+flat and IVF search programs are also compiled whole, at small shapes,
+to see the named scopes reach the compiled operations while the
+kernels' instruction names stay as they are.
 
 The topology is described inside a module fixture, never at import:
 only one process may load libtpu at a time, and under several pytest
 workers only the worker given this file may touch it.
 """
+import importlib
 import os
+import re
 
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.core import ASHConfig
+from repro.core import scoring as S
+from repro.data.synthetic import embedding_dataset
+from repro.index import AshIndex
 from repro.kernels.ash_score import (
     DEFAULT_BLOCK_N,
     ash_score_coarse_topk_pallas,
@@ -144,3 +153,74 @@ def test_dense_fused_topk_memory_at_10m_rows(spec, m):
     strip_bytes = m * -(-n // DEFAULT_BLOCK_N) * k * 8
     temp = compiled.memory_analysis().temp_size_in_bytes
     assert temp <= 2 * (codes_bytes + strip_bytes), (temp, codes_bytes)
+
+
+SCOPES = {
+    "ash.prep", "ash.probe", "ash.candidates", "ash.gather", "ash.scan",
+    "ash.merge", "ash.rerank",
+}
+
+
+@pytest.fixture(scope="module")
+def served_programs(spec):
+    """The served flat and IVF search programs and the query prep,
+    compiled for the chip at small shapes: {name: compiled text}.  The
+    index layers pick the Pallas kernels only where the default backend
+    is not the CPU, so that choice is forced here, and jit's caches are
+    dropped on both sides so no CPU test ever sees a chip trace."""
+    ops = importlib.import_module("repro.kernels.ops")
+    flat = importlib.import_module("repro.index.flat")
+    ivf = importlib.import_module("repro.index.ivf")
+    kx, kb, kq = jax.random.split(jax.random.PRNGKey(11), 3)
+    X = embedding_dataset(kx, 4096, 128)
+    cfg = ASHConfig(b=2, d=64, n_landmarks=16)
+    fi = AshIndex.build(kb, X, cfg, backend="flat", metric="l2",
+                        keep_raw=True)
+    ii = AshIndex.build(kb, X, cfg, backend="ivf", metric="l2",
+                        keep_raw=True, model=fi.model)
+    q = embedding_dataset(kq, 8, 128)
+
+    def on_chip(tree):
+        return jax.tree_util.tree_map(lambda a: spec(a.shape, a.dtype), tree)
+
+    prep = on_chip(S.prepare_queries(fi.model, q))
+    auto = ops._auto_interpret
+    jax.clear_caches()
+    ops._auto_interpret = lambda: False
+    try:
+        lowered = {
+            "prepare": jax.jit(S.prepare_queries).lower(
+                on_chip(fi.model), on_chip(q)),
+            "flat": flat._search_prepped.lower(
+                on_chip(fi._state), prep, k=10, rerank=100),
+            "ivf": ivf._score_gathered.lower(
+                on_chip(ii._state), prep, 10, 4, 100),
+        }
+        yield {name: lo.compile().as_text() for name, lo in lowered.items()}
+    finally:
+        ops._auto_interpret = auto
+        jax.clear_caches()
+
+
+def _op_scopes(text):
+    names = " ".join(re.findall(r'op_name="([^"]*)"', text))
+    return set(re.findall(r"ash\.[a-z]+", names))
+
+
+def test_served_programs_keep_kernel_names_and_carry_scopes(served_programs):
+    """Named scopes are metadata: the kernels' custom calls keep the
+    names the benchmark's roofline readers match, and every scope of
+    the served path reaches the compiled operations' ``op_name``."""
+    kernels = {
+        name: re.findall(
+            r'%(\S+) = .*custom_call_target="tpu_custom_call"', text)
+        for name, text in served_programs.items()
+    }
+    assert kernels["prepare"] == []
+    assert kernels["flat"] and all(
+        k.startswith("ash_score_topk_pallas") for k in kernels["flat"])
+    assert kernels["ivf"] and all(
+        k.startswith("ash_score_gather_topk_pallas") for k in kernels["ivf"])
+    seen = set().union(*(_op_scopes(t) for t in served_programs.values()))
+    assert seen == SCOPES
+    assert "ash.gather" in _op_scopes(served_programs["ivf"])
