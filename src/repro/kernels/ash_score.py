@@ -46,15 +46,27 @@ equal to the array's, and lays every array out in (8, 128) tiles):
 Fused selection (:func:`ash_score_topk_pallas`): instead of writing the
 (m, n) score matrix back to HBM and running a separate ``top_k`` pass,
 each (m_blk, n_blk) output tile keeps only its partial top-k̃ of
-(score, global id) pairs — k̃ VPU max/argmax sweeps in VMEM — and one
+(score, global id) pairs — VPU max/argmax sweeps in VMEM — and one
 small ``lax.top_k`` merges the strip.  HBM traffic for selection drops
 from O(m·n) fp32 to O(m · n/block_n · k̃).
 
+  * Running threshold: the row tiles of one query block run in order
+    (grid below), and a VMEM buffer carries each query's best k scores
+    emitted so far.  A tile admits only the rows strictly above the
+    buffer's minimum (every valid row until k have been emitted) and
+    runs as many sweeps as the block's busiest real query has admitted
+    rows, at most min(k̃, k) — not k̃ every tile.  A row at or below the
+    threshold has k emitted rows scoring at least as much with lower
+    ids, so it cannot reach the merged top-k; a row above it keeps its
+    in-tile rank, so it is emitted exactly when the unpruned sweep
+    would emit it.  The strip's shape does not change; skipped slots
+    hold sentinels.  ``count_sweeps=True`` also returns the call's
+    total sweeps (a measurement hook; the served path never asks).
   * Results are EXACTLY the materialized ``lax.top_k`` (values and
     indices, including tie order) whenever k <= k̃, because a row's
     global rank-r element ranks <= r inside its own tile.  k̃ < k trades
     exactness for a smaller strip (recall-style; routed index paths
-    never do this).
+    never do this): the top-k of the union of per-tile top-k̃ sets.
   * Ties follow the ``lax.top_k`` convention (lowest id first): tiles
     select by (score desc, id asc) and the merge keeps the lowest strip
     position, which is the lowest id.
@@ -77,8 +89,10 @@ sharded backend's ``n_real`` pad truncation AND the tombstone bitmap
 into the dense selection kernel's id masking, so one compiled program
 serves every shard and every mutation state.
 
-Grids: dense (n_blocks, m_blocks, d_blocks); gather (m/8, r_blocks,
-d_blocks); d innermost for accumulation in a VMEM scratch tile.
+Grids: dense (m_blocks, n_blocks, d_blocks), query blocks parallel and
+row tiles in order for the running threshold; gather (m/8, r_blocks,
+d_blocks), whose per-tile selection keeps k̃ sweeps; d innermost for
+accumulation in a VMEM scratch tile.
 """
 from __future__ import annotations
 
@@ -162,17 +176,22 @@ def _epilogue(dot, scale, bias, offset, qcol, rrow, metric):
     raise ValueError(metric)
 
 
-def _select_topk(scores, valid, col0, k_tilde, vals_ref, ids_ref):
+def _select_topk(scores, avail, col0, trips, vals_ref, ids_ref, top=None):
     """Per-tile partial top-k̃ of ``scores`` (m_blk, n_blk) into the
     transposed (1, k̃₈, m_blk) strip blocks ``vals_ref``/``ids_ref``.
 
-    k̃ VPU max sweeps over the tile, ties to the LOWEST id (the lax.top_k
-    convention) via a min over the argmax candidate set.  ``valid``
+    ``trips`` (a static int, or a traced bound) VPU max sweeps over the
+    columns ``avail`` admits, ties to the LOWEST id (the lax.top_k
+    convention) via a min over the argmax candidate set.  ``avail``
     (not a -inf re-mask) tracks taken columns so rows whose scores are
     genuinely -inf are still emitted once each, in ascending-id order;
-    invalid columns never surface.  Emitted ids are ``col0 + column``;
-    exhausted sweeps and the k̃ -> k̃₈ padding slots emit -inf / the
-    int32-max sentinel.
+    other columns never surface.  Emitted ids are ``col0 + column``;
+    exhausted sweeps, slots past ``trips`` and the k̃ -> k̃₈ padding
+    slots emit -inf / the int32-max sentinel.
+
+    ``top`` (m_blk, L), when given, is the running best-k buffer of the
+    dense scan: each emitted score replaces the buffer's minimum when it
+    beats it, and the updated buffer is returned.
     """
     m_blk, n_blk = scores.shape
     kt_pad = vals_ref.shape[1]
@@ -182,7 +201,7 @@ def _select_topk(scores, valid, col0, k_tilde, vals_ref, ids_ref):
     neg_inf = jnp.float32(-jnp.inf)
 
     def sweep(t, carry):
-        avail, out_v, out_i = carry
+        avail, out_v, out_i, *run = carry
         ok = avail != 0
         masked = jnp.where(ok, scores, neg_inf)
         best = jnp.max(masked, axis=1, keepdims=True)  # (m_blk, 1)
@@ -194,16 +213,26 @@ def _select_topk(scores, valid, col0, k_tilde, vals_ref, ids_ref):
         out_i = jnp.where(
             here, jnp.where(has, bid + col0, _ID_SENTINEL), out_i
         )
-        return jnp.where(local == bid, 0, avail), out_v, out_i
+        if run:  # an exhausted sweep's best is -inf and enters nowhere
+            (buf,) = run
+            lane = jax.lax.broadcasted_iota(jnp.int32, buf.shape, 1)
+            low = jnp.min(buf, axis=1, keepdims=True)
+            first = jnp.min(
+                jnp.where(buf == low, lane, buf.shape[1]), axis=1,
+                keepdims=True,
+            )
+            run = [jnp.where((lane == first) & (best > low), best, buf)]
+        return jnp.where(local == bid, 0, avail), out_v, out_i, *run
 
     init = (
-        valid.astype(jnp.int32),
+        avail.astype(jnp.int32),
         jnp.full((m_blk, slots), neg_inf, jnp.float32),
         jnp.full((m_blk, slots), _ID_SENTINEL, jnp.int32),
-    )
-    _, out_v, out_i = jax.lax.fori_loop(0, k_tilde, sweep, init)
+    ) + (() if top is None else (top,))
+    _, out_v, out_i, *run = jax.lax.fori_loop(0, trips, sweep, init)
     vals_ref[0] = out_v.T[:kt_pad]
     ids_ref[0] = out_i.T[:kt_pad]
+    return run[0] if run else None
 
 
 def _merge_strip(vals, ids, m: int, k: int):
@@ -262,16 +291,21 @@ def _dense_kernel(
     metric: str,
     coarse: bool,
     k_tilde: int | None,
+    k: int | None,
     block_n: int,
     n_real: int,
+    m_real: int,
     use_mask: bool,
+    count_sweeps: bool,
 ):
     """One body for the four dense kernels.  Refs, in order:
     q (m_blk, d_blk), codes (w_blk, n_blk), scale/offset/cluster
     (1, n_blk), ipq (m_blk, C); [qterm (m_blk, 1), rowterm (1, n_blk)]
     unless dot; [qscale, qcorr (m_blk, 1)] if coarse; [mask (1, n_blk)]
     if use_mask; outputs — scores (m_blk, n_blk), or the vals/ids strip
-    blocks when ``k_tilde`` is set; the accumulator scratch."""
+    blocks when ``k_tilde`` is set, then the (1, 8, 128) sweep count if
+    ``count_sweeps``; scratch — the accumulator, then the running best-k
+    buffer (m_blk, round_up(k, 128)) when ``k_tilde`` is set."""
     it = iter(refs)
     q_ref, codes_ref, scale_ref, offset_ref, cluster_ref, ipq_ref = (
         next(it) for _ in range(6)
@@ -283,12 +317,16 @@ def _dense_kernel(
         qscale_ref, qcorr_ref = next(it), next(it)
     mask_ref = next(it) if use_mask else None
     outs = [next(it), next(it)] if k_tilde else [next(it)]
+    sweeps_ref = next(it) if count_sweeps else None
     acc_ref = next(it)
+    top_ref = next(it) if k_tilde else None
 
     kd = pl.program_id(2)
     # program_id must be read outside the pl.when body (interpret mode
     # lowers the body through lax.cond, where the primitive is absent)
-    col0 = pl.program_id(0) * block_n
+    tile = pl.program_id(1)
+    col0 = tile * block_n
+    row0 = pl.program_id(0) * acc_ref.shape[0]
 
     @pl.when(kd == 0)
     def _zero():
@@ -332,16 +370,42 @@ def _dense_kernel(
             # n_real truncation and tombstoned rows, one operand for
             # every shard and every mutation state
             valid = valid & (mask_ref[...] != 0)
-        _select_topk(scores, valid, col0, k_tilde, *outs)
+        # running threshold: the k-th best score this query block has
+        # emitted in earlier row tiles (lanes past k hold +inf, so never
+        # the minimum).  -inf — fewer than k emitted, or -inf among the
+        # best k — admits every valid row.  A row at or below it has k
+        # emitted rows at least as good and of lower id, so it cannot
+        # reach the merged top-k; the rows above it keep their in-tile
+        # ranks.
+        lane = jax.lax.broadcasted_iota(jnp.int32, (1, top_ref.shape[1]), 1)
+        top = jnp.where(
+            tile == 0, jnp.where(lane < k, -jnp.inf, jnp.inf), top_ref[...]
+        )
+        thr = jnp.min(top, axis=1, keepdims=True)  # (m_blk, 1)
+        above = valid & ((scores > thr) | (thr == -jnp.inf))
+        # as many sweeps as the block's busiest real query needs (no
+        # tile rank past k reaches the top-k either)
+        real = jax.lax.broadcasted_iota(jnp.int32, thr.shape, 0) + row0
+        need = jnp.sum(above.astype(jnp.int32), axis=1, keepdims=True)
+        trips = jnp.minimum(
+            jnp.max(jnp.where(real < m_real, need, 0)), min(k_tilde, k)
+        )
+        top_ref[...] = _select_topk(scores, above, col0, trips, *outs, top)
+        if count_sweeps:
+            sweeps_ref[...] = jnp.where(
+                tile == 0, 0, sweeps_ref[...]
+            ) + trips
 
 
 def _dense_call(
     codes, q, scale, offset, cluster, ipq, qterm, rowterm, *,
     b, metric, block_m, block_n, block_d, interpret, compute_dtype,
     coarse_q=None, n_valid=None, row_valid=None, k=None, k_tilde=None,
+    count_sweeps=False,
 ):
     """Shared wrapper of the dense kernels: materialized (m, n) scores,
-    or — with ``k`` — the merged fused top-k (scores, ids)."""
+    or — with ``k`` — the merged fused top-k (scores, ids), plus the
+    call's total selection sweeps (int32 scalar) if ``count_sweeps``."""
     assert metric in METRICS, metric
     n, W = codes.shape
     m, d_pad = q.shape
@@ -355,7 +419,9 @@ def _dense_call(
     bw = bd // kpw
     m_p = _round_up(m, bm)
     d_p = _round_up(d_pad, bd)
-    grid = (pl.cdiv(n, bn), m_p // bm, d_p // bd)
+    # query blocks outermost: the row tiles of one block run in order,
+    # carrying its running selection threshold
+    grid = (m_p // bm, pl.cdiv(n, bn), d_p // bd)
 
     def col(v):  # per-query (m,) -> (m_p, 1) f32
         return jnp.pad(v.astype(jnp.float32), (0, m_p - m)).reshape(m_p, 1)
@@ -363,8 +429,8 @@ def _dense_call(
     def row(v, dt=jnp.float32):  # per-row (n,) -> (1, n), never padded
         return v.astype(dt).reshape(1, n)
 
-    row_spec = pl.BlockSpec((1, bn), lambda i, j, kd: (0, i))
-    col_spec = pl.BlockSpec((bm, 1), lambda i, j, kd: (j, 0))
+    row_spec = pl.BlockSpec((1, bn), lambda j, i, kd: (0, i))
+    col_spec = pl.BlockSpec((bm, 1), lambda j, i, kd: (j, 0))
     operands = [
         jnp.pad(q, ((0, m_p - m), (0, d_p - d_pad))),
         codes.T,  # (W, n): a free view of the column-major codes
@@ -372,10 +438,10 @@ def _dense_call(
         jnp.pad(ipq, ((0, m_p - m), (0, 0))),
     ]
     in_specs = [
-        pl.BlockSpec((bm, bd), lambda i, j, kd: (j, kd)),
-        pl.BlockSpec((bw, bn), lambda i, j, kd: (kd, i)),
+        pl.BlockSpec((bm, bd), lambda j, i, kd: (j, kd)),
+        pl.BlockSpec((bw, bn), lambda j, i, kd: (kd, i)),
         row_spec, row_spec, row_spec,
-        pl.BlockSpec((bm, ipq.shape[1]), lambda i, j, kd: (j, 0)),
+        pl.BlockSpec((bm, ipq.shape[1]), lambda j, i, kd: (j, 0)),
     ]
     if metric != "dot":
         operands += [col(qterm), row(rowterm)]
@@ -399,33 +465,43 @@ def _dense_call(
         in_specs.append(row_spec)
 
     kt = None
+    scratch = [pltpu.VMEM((bm, bn), jnp.int32 if coarse else jnp.float32)]
     if k is None:
-        out_specs = pl.BlockSpec((bm, bn), lambda i, j, kd: (j, i))
+        out_specs = pl.BlockSpec((bm, bn), lambda j, i, kd: (j, i))
         out_shape = jax.ShapeDtypeStruct((m_p, n), jnp.float32)
     else:
         kt, out_specs, out_shape = _strip_outputs(
-            k, k_tilde, bn, grid[0], grid[1], bm,
-            lambda i, j, kd: (j, i, 0),
+            k, k_tilde, bn, grid[1], grid[0], bm,
+            lambda j, i, kd: (j, i, 0),
         )
+        scratch.append(pltpu.VMEM((bm, _round_up(k, 128)), jnp.float32))
+        if count_sweeps:
+            out_specs.append(
+                pl.BlockSpec((1, 8, 128), lambda j, i, kd: (j, 0, 0)))
+            out_shape.append(
+                jax.ShapeDtypeStruct((grid[0], 8, 128), jnp.int32))
     out = pl.pallas_call(
         functools.partial(
             _dense_kernel, b=b, n_d_blocks=grid[2],
             compute_dtype=compute_dtype, metric=metric, coarse=coarse,
-            k_tilde=kt, block_n=bn, n_real=n, use_mask=use_mask,
+            k_tilde=kt, k=k, block_n=bn, n_real=n, m_real=m,
+            use_mask=use_mask, count_sweeps=count_sweeps,
         ),
         grid=grid,
         in_specs=in_specs,
         out_specs=out_specs,
         out_shape=out_shape,
-        scratch_shapes=[
-            pltpu.VMEM((bm, bn), jnp.int32 if coarse else jnp.float32)
-        ],
+        scratch_shapes=scratch,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary", "arbitrary")
+        ),
         interpret=interpret,
     )(*operands)
     if k is None:
         return out[:m]
-    s, i = _merge_strip(*out, m, k)
-    return s, jnp.where(i == _ID_SENTINEL, -1, i)
+    s, i = _merge_strip(*out[:2], m, k)
+    i = jnp.where(i == _ID_SENTINEL, -1, i)
+    return (s, i, jnp.sum(out[2][:, 0, 0])) if count_sweeps else (s, i)
 
 
 @functools.partial(
@@ -472,7 +548,7 @@ def ash_score_pallas(
     jax.jit,
     static_argnames=(
         "b", "k", "k_tilde", "metric", "block_m", "block_n", "block_d",
-        "interpret", "compute_dtype",
+        "interpret", "compute_dtype", "count_sweeps",
     ),
 )
 def ash_score_topk_pallas(
@@ -496,7 +572,8 @@ def ash_score_topk_pallas(
     block_d: int = DEFAULT_BLOCK_D,
     interpret: bool = False,
     compute_dtype=jnp.bfloat16,
-) -> tuple[jax.Array, jax.Array]:
+    count_sweeps: bool = False,
+) -> tuple[jax.Array, ...]:
     """Fused scan + selection: top-k (scores, ids), each (m, k).
 
     The (m, n) score matrix never exists — each output tile emits its
@@ -515,6 +592,9 @@ def ash_score_topk_pallas(
       sharded backend's per-shard ``n_real`` pad-row truncation.
     * ``row_valid`` ((n,) bool): rows whose entry is 0 are excluded
       from selection — the index layers' tombstone bitmap.
+
+    ``count_sweeps=True`` also returns the call's total selection
+    sweeps (int32 scalar; module docstring, "Running threshold").
     """
     return _dense_call(
         codes, q_proj, scale, offset, cluster, ip_q_landmarks,
@@ -522,6 +602,7 @@ def ash_score_topk_pallas(
         block_n=block_n, block_d=block_d, interpret=interpret,
         compute_dtype=compute_dtype, n_valid=n_valid,
         row_valid=row_valid, k=k, k_tilde=k_tilde,
+        count_sweeps=count_sweeps,
     )
 
 
@@ -813,7 +894,7 @@ def ash_score_coarse_pallas(
     jax.jit,
     static_argnames=(
         "b", "k", "k_tilde", "metric", "block_m", "block_n", "block_d",
-        "interpret",
+        "interpret", "count_sweeps",
     ),
 )
 def ash_score_coarse_topk_pallas(
@@ -838,7 +919,8 @@ def ash_score_coarse_topk_pallas(
     block_n: int = DEFAULT_BLOCK_N,
     block_d: int = DEFAULT_BLOCK_D,
     interpret: bool = False,
-) -> tuple[jax.Array, jax.Array]:
+    count_sweeps: bool = False,
+) -> tuple[jax.Array, ...]:
     """Fused coarse scan + shortlist selection: top-k (scores, ids),
     each (m, k) — the FIRST PASS of the coarse -> refine pipeline, so
     ``k`` here is the shortlist size L, not the final k.
@@ -848,6 +930,7 @@ def ash_score_coarse_topk_pallas(
     coarse scores: exactly ``top_k(ash_score_coarse_pallas(...), k)``
     for ``k <= k̃``.  The emitted ids feed
     ``ash_score_gather_topk_pallas`` for the asymmetric refine.
+    ``count_sweeps`` as in :func:`ash_score_topk_pallas`.
     """
     return _dense_call(
         codes, q_int8, scale, offset, cluster, ip_q_landmarks,
@@ -855,4 +938,5 @@ def ash_score_coarse_topk_pallas(
         block_n=block_n, block_d=block_d, interpret=interpret,
         compute_dtype=None, coarse_q=(q_scale, q_corr),
         n_valid=n_valid, row_valid=row_valid, k=k, k_tilde=k_tilde,
+        count_sweeps=count_sweeps,
     )

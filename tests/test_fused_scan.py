@@ -149,6 +149,145 @@ def test_fused_topk_recall_mode_is_valid_subset():
         np.testing.assert_array_equal(gs[r][: len(ids)], scores[r][ids])
 
 
+def _ordered(args, order):
+    """Operands whose scores follow ``order`` along the row ids, the
+    same order for every query: a dominant per-row ``offset`` for
+    ascending / descending, every row a copy of row 0 for equal;
+    ``random`` leaves the random operands as they are."""
+    codes, q, scale, offset, cluster, ipq, qterm, rowterm = args
+    n = codes.shape[0]
+    ids = jnp.arange(n, dtype=jnp.float32)
+    if order == "ascending":
+        offset = ids * 1e3
+    elif order == "descending":
+        offset = (n - ids) * 1e3
+    elif order == "equal":
+        codes, scale, offset, cluster, rowterm = (
+            jnp.broadcast_to(a[:1], a.shape)
+            for a in (codes, scale, offset, cluster, rowterm)
+        )
+    return codes, q, scale, offset, cluster, ipq, qterm, rowterm
+
+
+def _union_of_tile_topk(scores, valid, block_n, k_tilde, k):
+    """numpy oracle of the k̃ < k mode: the top-k, by (score desc, id
+    asc), of the union of every row tile's top-k̃ valid rows, padded
+    with (-inf, -1)."""
+    m, n = scores.shape
+    vals = np.full((m, k), -np.inf, np.float32)
+    ids = np.full((m, k), -1, np.int64)
+    for r in range(m):
+        union = []
+        for c0 in range(0, n, block_n):
+            tile = [i for i in range(c0, min(c0 + block_n, n)) if valid[i]]
+            tile.sort(key=lambda i: (-scores[r, i], i))
+            union += tile[:k_tilde]
+        union.sort(key=lambda i: (-scores[r, i], i))
+        top = union[:k]
+        vals[r, : len(top)] = scores[r, top]
+        ids[r, : len(top)] = top
+    return vals, ids
+
+
+# (metric, row order, row masking, k, k̃): every order that drives the
+# running threshold to an extreme, each metric, both masks, recall mode
+PRUNE_CASES = [
+    (metric, order, None, 20, None)
+    for metric in METRICS
+    for order in ("ascending", "descending", "equal", "random")
+] + [
+    ("l2", "random", "row_valid", 20, None),
+    ("l2", "descending", "row_valid", 20, None),
+    ("dot", "descending", "n_valid", 20, None),
+    ("dot", "random", None, 24, 8),
+    ("cos", "ascending", None, 24, 8),
+]
+
+
+@pytest.mark.parametrize("metric,order,mask,k,k_tilde", PRUNE_CASES)
+def test_fused_topk_pruned_selection_is_exact(metric, order, mask, k, k_tilde):
+    """The running threshold skips only rows that cannot reach the
+    merged top-k: bit-equal to materialize + lax.top_k for k <= k̃ and
+    to the union of per-tile top-k̃ sets for k̃ < k, over 17 row tiles
+    (the last ragged) and two query blocks (the second mostly padding)."""
+    b, d, n, m, C_ = 2, 64, 2100, 9, 8
+    args = _ordered(
+        _mk_inputs(jax.random.PRNGKey(17), b, d, n, m, C_), order
+    )
+    blocks = dict(block_m=8, block_n=128, block_d=128)
+    scores = ash_score_pallas(
+        *args, b=b, metric=metric, interpret=True,
+        compute_dtype=jnp.float32, **blocks,
+    )
+    valid = np.ones(n, bool)
+    masks = (None, None)
+    if mask == "row_valid":
+        valid = np.asarray(
+            jax.random.bernoulli(jax.random.PRNGKey(3), 0.7, (n,))
+        )
+        masks = (None, jnp.asarray(valid))
+    elif mask == "n_valid":
+        valid = np.arange(n) < 1000
+        masks = (jnp.int32(1000), None)
+    gs, gi = ash_score_topk_pallas(
+        *args, *masks, b=b, k=k, k_tilde=k_tilde, metric=metric,
+        interpret=True, compute_dtype=jnp.float32, **blocks,
+    )
+    if k_tilde is None:
+        ws, wi = jax.lax.top_k(
+            jnp.where(jnp.asarray(valid)[None, :], scores, -jnp.inf), k
+        )
+    else:
+        ws, wi = _union_of_tile_topk(
+            np.asarray(scores), valid, 128, k_tilde, k
+        )
+    assert np.array_equal(np.asarray(gs), np.asarray(ws))
+    assert np.array_equal(np.asarray(gi), np.asarray(wi))
+
+
+# (row order, n, m, k = k̃, expected sweeps per call): ascending runs
+# every tile's k̃ sweeps (the ragged last tile only its 6 valid rows,
+# and each of the two query blocks its own), descending only tile 0's
+SWEEP_CASES = [
+    ("ascending", 5 * 128 + 6, 8, 10, 5 * 10 + 6),
+    ("ascending", 5 * 128 + 6, 9, 10, 2 * (5 * 10 + 6)),
+    ("descending", 16 * 128, 8, 10, 10),
+    ("descending", 5 * 128 + 6, 9, 12, 2 * 12),
+]
+
+
+@pytest.mark.parametrize("order,n,m,k,want", SWEEP_CASES)
+def test_fused_topk_sweep_counter(order, n, m, k, want):
+    """The counter reads the sweeps the selection ran, and asking for
+    it leaves the top-k as it is."""
+    b, d, C_ = 2, 64, 8
+    args = _ordered(
+        _mk_inputs(jax.random.PRNGKey(4), b, d, n, m, C_), order
+    )
+    kw = dict(b=b, k=k, metric="l2", interpret=True,
+              compute_dtype=jnp.float32, block_m=8, block_n=128,
+              block_d=128)
+    gs, gi, sweeps = ash_score_topk_pallas(*args, count_sweeps=True, **kw)
+    assert int(sweeps) == want
+    ws, wi = ash_score_topk_pallas(*args, **kw)
+    assert np.array_equal(np.asarray(gs), np.asarray(ws))
+    assert np.array_equal(np.asarray(gi), np.asarray(wi))
+
+
+def test_fused_topk_sweep_counter_prunes_random_rows():
+    """Random scores, m = 8, 64 tiles of 128, k = 16: the threshold
+    leaves under a quarter of the 1,024 sweeps the unpruned selection
+    runs (a simulation of iid scores gives 164–195)."""
+    b, d, n, m, C_ = 2, 64, 64 * 128, 8, 8
+    args = _mk_inputs(jax.random.PRNGKey(6), b, d, n, m, C_)
+    _, _, sweeps = ash_score_topk_pallas(
+        *args, b=b, k=16, metric="l2", interpret=True,
+        compute_dtype=jnp.float32, block_m=8, block_n=128, block_d=128,
+        count_sweeps=True,
+    )
+    assert 16 <= int(sweeps) < 64 * 16 // 4
+
+
 def test_topk_k_exceeding_candidate_strip_raises():
     b, d, n, m, C_ = 2, 64, 120, 2, 2
     args = _mk_inputs(jax.random.PRNGKey(2), b, d, n, m, C_)

@@ -87,25 +87,49 @@ def _compile(fn, *args):
     return compiled
 
 
+def _kernels(compiled):
+    """Instruction names of the compiled program's Pallas kernels."""
+    return re.findall(
+        r'%(\S+) = .*custom_call_target="tpu_custom_call"',
+        compiled.as_text(),
+    )
+
+
+def _dense_topk(spec, m, k, masked, **kw):
+    """The served dense top-k at 1M rows, optionally row-masked."""
+    args = _dense(spec, N, m) + ([spec((N,), jnp.bool_)] if masked else [])
+    compiled = _compile(
+        lambda *a: ash_score_topk_pallas(
+            *a[:8], None, *a[8:], b=B, k=k, metric="l2",
+            compute_dtype=jnp.float32, **kw,
+        ),
+        *args,
+    )
+    names = _kernels(compiled)
+    assert len(names) == 1 and re.fullmatch(
+        r"ash_score_topk_pallas(\.\d+)?", names[0]), names
+    return compiled
+
+
 @pytest.mark.parametrize("k", (10, 100))
 def test_dense_fused_topk_compiles(spec, k):
-    _compile(
-        lambda *a: ash_score_topk_pallas(
-            *a, b=B, k=k, metric="l2", compute_dtype=jnp.float32
-        ),
-        *_dense(spec, N, 8),
-    )
+    _dense_topk(spec, 8, k, masked=False)
 
 
 def test_dense_fused_topk_row_mask_compiles(spec):
     """The tombstone / shard-padding variant: a (1, n) mask operand."""
-    _compile(
-        lambda *a: ash_score_topk_pallas(
-            *a[:8], None, a[8], b=B, k=10, metric="l2",
-            compute_dtype=jnp.float32,
-        ),
-        *_dense(spec, N, 8), spec((N,), jnp.bool_),
-    )
+    _dense_topk(spec, 8, 10, masked=True)
+
+
+@pytest.mark.parametrize("count_sweeps", (False, True))
+@pytest.mark.parametrize("masked", (False, True))
+@pytest.mark.parametrize("k", (10, 100))
+def test_dense_pruned_topk_compiles_at_full_bucket(spec, k, masked,
+                                                   count_sweeps):
+    """The running-threshold selection at the 128-row bucket: its
+    data-dependent sweep count and carried best-k buffer, with and
+    without the sweep counter's output, under the kernel's own name."""
+    _dense_topk(spec, 128, k, masked, count_sweeps=count_sweeps)
 
 
 def test_coarse_fused_topk_compiles(spec):
