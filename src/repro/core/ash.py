@@ -145,9 +145,8 @@ def random_model(
 # ---------------------------------------------------------------------------
 
 
-@functools.partial(jax.jit, static_argnames=("exact",))
-def encode(model: ASHModel, X: jax.Array, exact: bool = True) -> ASHPayload:
-    """Encode database vectors into the ASH payload (Table 1)."""
+def _encode_rows(model: ASHModel, X: jax.Array, exact: bool) -> ASHPayload:
+    """Encode one block of rows (every row independently)."""
     cfg = model.config
     X32 = X.astype(jnp.float32)
     x_tilde, res_norm, assign = L.normalized_residuals(X32, model.landmarks)
@@ -180,6 +179,73 @@ def encode(model: ASHModel, X: jax.Array, exact: bool = True) -> ASHPayload:
         offset=offset.astype(hdr_dtype),
         cluster=assign,
     )
+
+
+# Above this many bytes of temporaries (``_encode_row_bytes`` a row) an
+# encode runs in blocks of ``ENCODE_CHUNK_ROWS`` rows inside one device
+# program.  A whole 1M x 768 corpus at b=4, d=128 would need 18.6 GB on
+# v5e (more than the chip); a block of 32,768 rows needs 0.7 GB there.
+# Below it the corpus is encoded whole: the blocked program's code is
+# larger, and a loaded program's code stays in device memory.
+ENCODE_WHOLE_BYTES = 8 << 30
+ENCODE_CHUNK_ROWS = 32_768
+
+
+def _encode_row_bytes(model: ASHModel) -> int:
+    """Upper estimate of an encode's temporaries per row: four copies of
+    the exact quantizer's d * (2^(b-1) - 1) sort keys, the landmark
+    distances and two input-wide rows, 4 bytes each (compiled for v5e:
+    2.3 KB a row at D=128, b=2, d=64; 18.6 KB at D=768, b=4, d=128)."""
+    d, D = model.W.shape
+    keys = d * (2 ** (model.config.b - 1) - 1)
+    return 4 * (4 * keys + model.landmarks.shape[0] + 2 * D)
+
+
+def encode_chunks(model: ASHModel, n: int) -> int:
+    """Row blocks :func:`encode` runs for ``n`` rows."""
+    if n * _encode_row_bytes(model) <= ENCODE_WHOLE_BYTES:
+        return 1
+    return -(-n // ENCODE_CHUNK_ROWS)
+
+
+def encode(model: ASHModel, X: jax.Array, exact: bool = True) -> ASHPayload:
+    """Encode database vectors into the ASH payload (Table 1).
+
+    Rows are encoded independently; a corpus whose encode would exceed
+    ``ENCODE_WHOLE_BYTES`` of temporaries runs in blocks of
+    ``ENCODE_CHUNK_ROWS`` rows inside one device program, the last
+    block being the final ``ENCODE_CHUNK_ROWS`` rows (it overlaps the
+    one before and rewrites the same values).  The payload equals a
+    single-block encode.
+    """
+    n = X.shape[0]
+    chunk = n if encode_chunks(model, n) == 1 else ENCODE_CHUNK_ROWS
+    return _encode_blocks(model, X, exact, chunk)
+
+
+@functools.partial(jax.jit, static_argnames=("exact", "chunk"))
+def _encode_blocks(model, X, exact, chunk) -> ASHPayload:
+    n = X.shape[0]
+    if n <= chunk:
+        return _encode_rows(model, X, exact)
+
+    def block(i, out):
+        lo = jnp.minimum(i * chunk, n - chunk)
+        part = _encode_rows(
+            model, jax.lax.dynamic_slice_in_dim(X, lo, chunk), exact
+        )
+        return jax.tree_util.tree_map(
+            lambda o, p: jax.lax.dynamic_update_slice_in_dim(o, p, lo, 0),
+            out, part,
+        )
+
+    shapes = jax.eval_shape(
+        functools.partial(_encode_rows, exact=exact), model, X[:chunk]
+    )
+    out = jax.tree_util.tree_map(
+        lambda s: jnp.zeros((n,) + s.shape[1:], s.dtype), shapes
+    )
+    return jax.lax.fori_loop(0, -(-n // chunk), block, out)
 
 
 @jax.jit

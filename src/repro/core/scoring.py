@@ -45,9 +45,13 @@ def prepare_queries(model: ASHModel, q: jax.Array) -> QueryPrep:
 # ---------------------------------------------------------------------------
 
 
-def _recovered_full(model: ASHModel, payload: ASHPayload, V=None):
-    """One decompression pass -> every Table-1 recovery, including the
-    <W mu*, v> inner products (which several quantities reuse)."""
+def recovered_terms(model: ASHModel, payload: ASHPayload, V=None):
+    """Recover (V float, ||v||, ||x-mu*||, <x, mu*>) from the payload.
+
+    ``V`` optionally passes already-unpacked codes so callers that need
+    both the recovered terms and the code matrix decompress the payload
+    once instead of twice.
+    """
     if V is None:
         V = Q.unpack_codes(payload.codes, payload.d, payload.b).astype(
             jnp.float32
@@ -61,28 +65,25 @@ def _recovered_full(model: ASHModel, payload: ASHPayload, V=None):
         offset + scale * ip_Wmu_v
         + model.landmark_sq_norms[payload.cluster]
     )
-    return V, vnorm, res_norm, ip_x_mu, ip_Wmu_v
+    return V, vnorm, res_norm, ip_x_mu
 
 
-def recovered_terms(model: ASHModel, payload: ASHPayload, V=None):
-    """Recover (V float, ||v||, ||x-mu*||, <x, mu*>) from the payload.
+def _x_sq_estimate(model, cluster, res_norm, ip_x_mu):
+    """||x||^2 recovered from the headers: ||x - mu*||^2 + 2 <x, mu*>
+    - ||mu*||^2 — the single definition shared by :func:`payload_stats`
+    (fused cos epilogue), :func:`restore_stats` (index load) and
+    :func:`score_cosine` (reference scorer), and the quantity the l2
+    epilogue recovers too.
 
-    ``V`` optionally passes already-unpacked codes so callers that need
-    both the recovered terms and the code matrix decompress the payload
-    once instead of twice.
-    """
-    return _recovered_full(model, payload, V)[:4]
-
-
-def _x_sq_estimate(model, payload, vnorm, res_norm, ip_Wmu_v):
-    """||x||^2 estimate of Eq. (A.5) — the single definition shared by
-    :func:`payload_stats` (fused cos epilogue) and :func:`score_cosine`
-    (reference scorer), so the two can never desynchronize."""
-    return (
-        res_norm**2
-        + 2.0 * (res_norm / jnp.maximum(vnorm, _EPS)) * ip_Wmu_v
-        + model.landmark_sq_norms[payload.cluster]
-    )
+    Departs from Eq. (A.5), which writes the cross term as
+    2 (||x - mu*|| / ||v||) <W mu*, v>: that sees <x - mu*, mu*> only
+    through the quantized d-dimensional projection (a 0.8-2.4 % spread
+    in ||x||^2 at d=64-128, b=2-4, D=768).  The term is known exactly:
+    OFFSET folds the exact <x, mu*> in at encode, so ``ip_x_mu``
+    recovers it at no extra storage, and only the fp16 headers' rounding
+    is left."""
+    mu_sq = model.landmark_sq_norms[cluster]
+    return res_norm * res_norm + 2.0 * ip_x_mu - mu_sq
 
 
 @jax.jit
@@ -91,16 +92,29 @@ def payload_stats(model: ASHModel, payload: ASHPayload) -> ASHStats:
 
     One decompression pass at encode/build time; afterwards the fused
     l2/cos kernels score straight off the packed codes + these vectors
-    (see ``repro.kernels.ops``).  ``x_sq`` is the Eq. (A.5) squared-norm
-    estimate used by cosine search — identical to the quantity
+    (see ``repro.kernels.ops``).  ``x_sq`` is the recovered squared
+    norm used by cosine search — identical to the quantity
     :func:`score_cosine` derives on the fly.
     """
-    _, vnorm, res_norm, ip_x_mu, ip_Wmu_v = _recovered_full(model, payload)
-    x_sq = _x_sq_estimate(model, payload, vnorm, res_norm, ip_Wmu_v)
+    _, _, res_norm, ip_x_mu = recovered_terms(model, payload)
+    return restore_stats(
+        model, payload.cluster, res_norm.astype(jnp.float32),
+        ip_x_mu.astype(jnp.float32),
+    )
+
+
+@jax.jit
+def restore_stats(
+    model: ASHModel, cluster: jax.Array, res_norm: jax.Array,
+    ip_x_mu: jax.Array,
+) -> ASHStats:
+    """:class:`ASHStats` from its two stored terms, with no unpack:
+    ``x_sq`` is derived from them (an index saved with an older
+    ``x_sq`` loads to the same statistics as a fresh build)."""
     return ASHStats(
-        res_norm=res_norm.astype(jnp.float32),
-        ip_x_mu=ip_x_mu.astype(jnp.float32),
-        x_sq=x_sq.astype(jnp.float32),
+        res_norm=res_norm,
+        ip_x_mu=ip_x_mu,
+        x_sq=_x_sq_estimate(model, cluster, res_norm, ip_x_mu),
     )
 
 
@@ -270,10 +284,10 @@ def score_cosine(
     model: ASHModel, prep: QueryPrep, payload: ASHPayload,
     *, rowwise: bool = False,
 ) -> jax.Array:
-    """cosSim(q, x_i) using the norm estimate of Eq. (A.5), (m, n)."""
-    V, vnorm, res_norm, _, ip_Wmu_v = _recovered_full(model, payload)
+    """cosSim(q, x_i) with ||x_i|| recovered from the headers, (m, n)."""
+    V, _, res_norm, ip_x_mu = recovered_terms(model, payload)
     ip_qx = _score_dot_from_V(prep, payload, V, rowwise)
-    x_sq = _x_sq_estimate(model, payload, vnorm, res_norm, ip_Wmu_v)
+    x_sq = _x_sq_estimate(model, payload.cluster, res_norm, ip_x_mu)
     x_norm = jnp.sqrt(jnp.maximum(x_sq, _EPS))
     q_norm = jnp.sqrt(jnp.maximum(prep.q_sq_norm, _EPS))
     return ip_qx / (q_norm[..., None] * x_norm[None, :])

@@ -129,11 +129,12 @@ class ASHStats:
 
       res_norm = ||x - mu*||         = SCALE * ||v||
       ip_x_mu  = <x, mu*>            = OFFSET + SCALE <W mu*, v> + ||mu*||^2
-      x_sq     = ||x||^2 estimate    (Eq. A.5, via the cosine-norm identity)
+      x_sq     = ||x||^2             = res_norm^2 + 2 ip_x_mu - ||mu*||^2
 
     Rows are aligned with the owning :class:`ASHPayload`; build with
-    ``scoring.payload_stats``.  Persisted with the index (save/load is
-    bit-identical) so the fused kernels never touch unpacked codes.
+    ``scoring.payload_stats``.  Persisted with the index (``x_sq`` is
+    derived again on load, ``scoring.restore_stats``) so the fused
+    kernels never touch unpacked codes.
     """
 
     res_norm: jax.Array  # (n,) fp32

@@ -311,24 +311,16 @@ def _stats_arrays(stats: Optional[ASHStats]) -> dict[str, Any]:
 def _stats_from_arrays(
     arrays: dict[str, jax.Array], model: ASHModel, payload: ASHPayload
 ) -> ASHStats:
-    """Restore persisted stats bit-identically; rebuild from the
-    payload when loading a pre-stats save."""
+    """Restore persisted stats; rebuild from the payload when loading
+    a pre-stats save.  ``x_sq`` is derived again from the stored
+    ``res_norm`` and ``ip_x_mu`` (no unpack), so a save that holds an
+    older estimate of it loads to the statistics of a fresh build."""
     if all(f"stats.{f}" in arrays for f in _STATS_FIELDS):
-        return ASHStats(
-            **{f: arrays[f"stats.{f}"] for f in _STATS_FIELDS}
+        return S.restore_stats(
+            model, payload.cluster, arrays["stats.res_norm"],
+            arrays["stats.ip_x_mu"],
         )
     return S.payload_stats(model, payload)
-
-
-def _train_or_reuse(
-    key, X, config, *, model=None, learned=True, **train_kw
-) -> ASHModel:
-    if model is not None:
-        return model
-    if learned:
-        model, _ = A.train(key, X, config, **train_kw)
-        return model
-    return A.random_model(key, X.shape[1], config, X_for_landmarks=X)
 
 
 # ---------------------------------------------------------------------------
@@ -710,14 +702,14 @@ class ShardedBackend:
     def build(key, X, config, *, metric, mesh=None, axes=None,
               model=None, learned=True, keep_raw=False, **train_kw):
         mesh, axes = ShardedBackend._resolve_mesh(mesh, axes)
-        model = _train_or_reuse(
-            key, X, config, model=model, learned=learned, **train_kw
+        model, payload, raw = C.train_and_encode(
+            key, X, config, model=model, learned=learned,
+            keep_raw=keep_raw, **train_kw,
         )
-        return ShardedState(
-            metric=metric, model=model, payload=A.encode(model, X),
-            mesh=mesh, axes=axes,
-            raw=X.astype(jnp.bfloat16) if keep_raw else None,
-        )
+        return C.build_stats(lambda: ShardedState(
+            metric=metric, model=model, payload=payload, mesh=mesh,
+            axes=axes, raw=raw,
+        ))
 
     @staticmethod
     def from_parts(model, payload, *, metric, raw=None, mesh=None,
@@ -951,7 +943,10 @@ class AshIndex:
         and any ``repro.core.ash.train`` keyword."""
         impl = _get_backend(backend)
         C.validate_metric(metric)
-        state = impl.build(key, X, config, metric=metric, **opts)
+        with jax.profiler.TraceAnnotation(
+            "ash.build", backend=backend, rows=X.shape[0]
+        ):
+            state = impl.build(key, X, config, metric=metric, **opts)
         return cls(backend, metric, state)
 
     @classmethod

@@ -28,9 +28,10 @@ from typing import Any, Optional
 import jax
 import jax.numpy as jnp
 
+from repro.core import ash as A
 from repro.core import scoring as S
 from repro.core.types import (
-    ASHModel, ASHPayload, ASHStats, CoarseCodes, QueryPrep,
+    ASHConfig, ASHModel, ASHPayload, ASHStats, CoarseCodes, QueryPrep,
 )
 
 NEG_INF = -jnp.inf
@@ -45,6 +46,51 @@ def validate_metric(metric: str) -> str:
             f"unknown metric {metric!r}; expected one of {METRICS}"
         )
     return metric
+
+
+# ---------------------------------------------------------------------------
+# Build stages — each waited for inside its host span (``ash.build.*``,
+# listed in ``serving/engine.py``), so the span covers its device work
+# ---------------------------------------------------------------------------
+
+_span = jax.profiler.TraceAnnotation
+
+
+def train_and_encode(
+    key: jax.Array,
+    X: jax.Array,
+    config: ASHConfig,
+    *,
+    model: Optional[ASHModel] = None,
+    learned: bool = True,
+    keep_raw: bool = False,
+    **train_kw,
+) -> tuple[ASHModel, ASHPayload, Optional[jax.Array]]:
+    """(model, payload, bf16 raw rows or None): train (or reuse
+    ``model``) under ``ash.build.train``, then encode, and copy the raw
+    rows for rerank, under ``ash.build.encode``."""
+    with _span("ash.build.train"):
+        if model is None and learned:
+            model, _ = A.train(key, X, config, **train_kw)
+        elif model is None:
+            model = A.random_model(
+                key, X.shape[1], config, X_for_landmarks=X
+            )
+        jax.block_until_ready(model)
+    n = X.shape[0]
+    with _span("ash.build.encode", rows=n,
+               chunks=A.encode_chunks(model, n)):
+        payload = A.encode(model, X)
+        raw = X.astype(jnp.bfloat16) if keep_raw else None
+        jax.block_until_ready((payload, raw))
+    return model, payload, raw
+
+
+def build_stats(build):
+    """``build()`` (the statistics a backend derives from its payload,
+    and any layout it assembles with them) under ``ash.build.stats``."""
+    with _span("ash.build.stats"):
+        return jax.block_until_ready(build())
 
 
 # ---------------------------------------------------------------------------

@@ -74,20 +74,15 @@ def _build(
     **train_kw,
 ) -> FlatIndex:
     C.validate_metric(metric)
-    if model is None:
-        if learned:
-            model, _ = A.train(key, X, config, **train_kw)
-        else:
-            model = A.random_model(
-                key, X.shape[1], config, X_for_landmarks=X
-            )
-    payload = A.encode(model, X)
-    raw = X.astype(jnp.bfloat16) if keep_raw else None
-    return FlatIndex(
+    model, payload, raw = C.train_and_encode(
+        key, X, config, model=model, learned=learned, keep_raw=keep_raw,
+        **train_kw,
+    )
+    return C.build_stats(lambda: FlatIndex(
         metric=metric, model=model, payload=payload, raw=raw,
         stats=S.payload_stats(model, payload),
         coarse=S.coarse_codes(payload),
-    )
+    ))
 
 
 @functools.partial(

@@ -145,19 +145,17 @@ def _build(
     metric: str = "dot",
     keep_raw: bool = False,
     model: Optional[ASHModel] = None,
-    train_sample: Optional[int] = None,
     **train_kw,
 ) -> IVFIndex:
     """nlist = config.n_landmarks."""
     C.validate_metric(metric)
-    if model is None:
-        model, _ = A.train(
-            key, X, config, train_sample=train_sample, **train_kw
-        )
-    payload = A.encode(model, X)
-    raw = X.astype(jnp.bfloat16) if keep_raw else None
+    model, payload, raw = C.train_and_encode(
+        key, X, config, model=model, keep_raw=keep_raw, **train_kw,
+    )
     ids = jnp.arange(payload.n, dtype=jnp.int32)
-    return _assemble(metric, model, payload, ids, raw)
+    return C.build_stats(
+        lambda: _assemble(metric, model, payload, ids, raw)
+    )
 
 
 def _add(index: IVFIndex, X_new: jax.Array) -> IVFIndex:

@@ -18,7 +18,8 @@ Metric epilogues (``metric=``) — all emit HIGHER-IS-BETTER scores:
              + OFFSET            (Eq. 20; the landmark lookup is itself
              an MXU one-hot matmul)
   l2    2 * base - ||q||^2 - L2CONST_i          == -||q - x_i||^2
-  cos   base * (1/||q||) * (1/||x_i||)          (Eq. A.5 norm estimate)
+  cos   base * (1/||q||) * (1/||x_i||)          (||x_i||^2 recovered as
+             ||x_i - mu*||^2 + 2 <x_i, mu*> - ||mu*||^2, the l2 row term)
 
 The l2/cos row constants are query-independent and recovered once at
 encode/build time into an ``ASHStats`` structure (``repro.core.types``),

@@ -88,13 +88,8 @@ def _metric_operands(
     if stats is None:
         stats = S.payload_stats(model, payload)
     if metric == "l2":
-        res = stats.res_norm.astype(jnp.float32)
-        rowterm = (
-            res * res
-            + 2.0 * stats.ip_x_mu.astype(jnp.float32)
-            - model.landmark_sq_norms[payload.cluster]
-        )  # == ||x||^2 recovered: -l2 = 2<q,x> - ||q||^2 - ||x||^2
-        return prep.q_sq_norm.astype(jnp.float32), rowterm
+        # -l2 = 2<q,x> - ||q||^2 - ||x||^2, ||x||^2 recovered (x_sq)
+        return prep.q_sq_norm.astype(jnp.float32), stats.x_sq
     if metric == "cos":
         qterm = 1.0 / jnp.sqrt(jnp.maximum(prep.q_sq_norm, _EPS))
         rowterm = 1.0 / jnp.sqrt(jnp.maximum(stats.x_sq, _EPS))
@@ -133,7 +128,8 @@ def ash_score(
 
     metric="dot" is a drop-in fused replacement for scoring.score_dot;
     "l2"/"cos" apply the stats-driven epilogues (negated squared
-    distance / Eq. A.5 cosine) without unpacking the database.
+    distance / cosine over the recovered ||x||, ``ASHStats.x_sq``)
+    without unpacking the database.
 
     use_pallas=None (auto): the fused kernel on TPU, the identical-
     semantics jnp oracle on CPU (interpret mode is for validation, far

@@ -130,9 +130,16 @@ span                        times (metadata)
 ``ash.search.wait``         waiting for its result to be ready
 ``ash.fetch``               copying scores and ids to the host
 ``ash.scatter``             per-request slicing and ticket resolution
+``ash.build``               ``AshIndex.build`` (backend, rows)
+``ash.build.train``         training, or reuse of a given model
+``ash.build.encode``        encode in row blocks and the bf16 raw copy
+                            for rerank (rows, chunks)
+``ash.build.stats``         row statistics and the backend's layout
+                            (IVF lists, shard placement)
 ==========================  ==========================================
 
-Inside ``ash.call`` the children run in the order listed.  The driver's
+Inside ``ash.call`` and ``ash.build`` the children run in the order
+listed; each build stage waits for its device work before it closes.  The driver's
 idle wait between ticks carries no span.  The jitted search programs
 carry ``jax.named_scope`` names in their operations' metadata
 (``ash.prep``, ``ash.probe``, ``ash.candidates``, ``ash.gather``,

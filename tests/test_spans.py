@@ -190,6 +190,34 @@ def test_spans_lie_inside_the_window(served):
     assert all(a <= e.start and e.end <= b for e in spans)
 
 
+BUILD_STEPS = ("ash.build.train", "ash.build.encode", "ash.build.stats")
+
+
+@pytest.mark.parametrize("backend", ("flat", "ivf"))
+def test_build_spans_nest_in_order(backend, tmp_path, monkeypatch):
+    """A build is one ``ash.build`` span holding train, encode (its rows
+    and row blocks) and stats, in that order."""
+    A = importlib.import_module("repro.core.ash")
+    monkeypatch.setattr(A, "ENCODE_WHOLE_BYTES", 0)
+    monkeypatch.setattr(A, "ENCODE_CHUNK_ROWS", 512)
+    X = embedding_dataset(jax.random.PRNGKey(9), 1200, DIM)
+    rec = T.Recorder(str(tmp_path))
+    AshIndex.build(jax.random.PRNGKey(10), X,
+                   ASHConfig(b=2, d=16, n_landmarks=8), backend=backend,
+                   keep_raw=True)
+    trace = T.load(rec.stop())
+    (build,) = _ash(trace, "ash.build")
+    assert build.stats["backend"] == backend
+    assert build.stats["rows"] == 1200
+    steps = [e for e in _ash(trace) if e.name.startswith("ash.build.")]
+    assert [e.name for e in steps] == list(BUILD_STEPS)
+    assert all(_inside(e, build) for e in steps)
+    for a, b in zip(steps, steps[1:]):
+        assert a.end <= b.start
+    assert steps[1].stats["rows"] == 1200
+    assert steps[1].stats["chunks"] == 3
+
+
 def _scopes(compiled_text: str) -> set:
     names = " ".join(re.findall(r'op_name="([^"]*)"', compiled_text))
     return set(re.findall(r"ash\.[a-z]+", names))

@@ -37,6 +37,8 @@ from repro.kernels.ash_score import (
 
 N, D, B, C = 1_000_000, 64, 2, 1024
 W = D * B // 32  # packed uint32 words per row
+# the cohere-768-cosine-flat corpus: d=128 at b=4, 16 words a row, cos
+COS_D, COS_B = 128, 4
 # IVF candidates per query at nprobe=16: 16 lists padded to 4096 rows,
 # above the smoke corpus's longest list (1,670 rows at seed 0)
 SMOKE_IVF_R = 16 * 4096
@@ -71,11 +73,12 @@ def spec(topo):
     compilation_cache.reset_cache()
 
 
-def _dense(spec, n, m):
+def _dense(spec, n, m, d=D, b=B):
     """codes, q_proj, scale, offset, cluster, ipq, qterm, rowterm."""
     f32 = jnp.float32
     return [
-        spec((n, W), jnp.uint32), spec((m, D), f32), spec((n,), f32),
+        spec((n, d * b // 32), jnp.uint32), spec((m, d), f32),
+        spec((n,), f32),
         spec((n,), f32), spec((n,), jnp.int32), spec((m, C), f32),
         spec((m,), f32), spec((n,), f32),
     ]
@@ -95,12 +98,13 @@ def _kernels(compiled):
     )
 
 
-def _dense_topk(spec, m, k, masked, **kw):
+def _dense_topk(spec, m, k, masked, d=D, b=B, metric="l2", **kw):
     """The served dense top-k at 1M rows, optionally row-masked."""
-    args = _dense(spec, N, m) + ([spec((N,), jnp.bool_)] if masked else [])
+    args = _dense(spec, N, m, d, b) + (
+        [spec((N,), jnp.bool_)] if masked else [])
     compiled = _compile(
         lambda *a: ash_score_topk_pallas(
-            *a[:8], None, *a[8:], b=B, k=k, metric="l2",
+            *a[:8], None, *a[8:], b=b, k=k, metric=metric,
             compute_dtype=jnp.float32, **kw,
         ),
         *args,
@@ -130,6 +134,15 @@ def test_dense_pruned_topk_compiles_at_full_bucket(spec, k, masked,
     data-dependent sweep count and carried best-k buffer, with and
     without the sweep counter's output, under the kernel's own name."""
     _dense_topk(spec, 128, k, masked, count_sweeps=count_sweeps)
+
+
+@pytest.mark.parametrize("masked", (False, True))
+@pytest.mark.parametrize("m, k", ((8, 10), (32, 100), (128, 100)))
+def test_dense_fused_topk_compiles_at_b4_cos(spec, m, k, masked):
+    """The cosine corpus's scan: the 4-bit unpack of 16 words a row and
+    the cos row and query terms, at each engine bucket, under the
+    kernel's own name."""
+    _dense_topk(spec, m, k, masked, d=COS_D, b=COS_B, metric="cos")
 
 
 def test_coarse_fused_topk_compiles(spec):
