@@ -7,13 +7,13 @@ rerank by hand.
 
 Every backend lowers its search to a :class:`ScanPlan` — a declarative
 description of WHAT to score (a dense row range, optionally truncated
-by ``n_valid``, or per-query gathered candidate lists via ``rows``)
-plus metric / top-k / rerank — and :func:`execute_plan` picks the
-kernel: the fused dense scan family for dense plans, the masked-gather
-family for gathered plans, with materialize-then-``top_k`` fallbacks
-beyond the fused-selection budget.  The fused and fallback routes
-return identical results, so the routing boundary is invisible to
-callers.
+by ``n_valid``, or per-query candidates: rows via ``rows``, whole
+inverted lists via ``lists``) plus metric / top-k / rerank — and
+:func:`execute_plan` picks the kernel: the fused dense scan family for
+dense plans, the masked-gather family for gathered plans, with
+materialize-then-``top_k`` fallbacks beyond the fused-selection budget.
+The fused and fallback routes return identical results, so the routing
+boundary is invisible to callers.
 
 Score convention: **higher is better** for every metric — L2 scores are
 negated squared distances.  Invalid candidates carry ``NEG_INF`` scores
@@ -200,11 +200,16 @@ class ScanPlan:
         folded into the same kernel mask operand so no plan variant
         recompiles).
       * gathered (``rows`` = (m, R) int32): query i scores its own
-        candidate list ``rows[i]`` (IVF partial probes); pad entries
-        carry id -1 and score ``-inf``.  Tombstones must be dropped
-        from the candidate lists (mapped to -1) BEFORE planning — the
-        gather kernel then never DMAs a deleted row (``row_valid`` on a
-        gathered plan is an error, not a silent no-op).
+        candidate list ``rows[i]`` (the int8 refine's shortlists); pad
+        entries carry id -1 and score ``-inf``.  Tombstones must be
+        dropped from the candidate lists (mapped to -1) BEFORE planning
+        (``row_valid`` on a gathered plan is an error, not a silent
+        no-op).
+      * probed lists (``lists`` = ``kernels.ash_score.ProbedLists``):
+        query i scores whole contiguous inverted lists (IVF partial
+        probes), candidate slots in ``lists.rows()`` order, tombstones
+        dropped by its ``live`` bitmap.  The fused gather kernel reads
+        the lists in place; every other route scores ``lists.rows()``.
 
     HOW to select: top-``k`` per query; ``rerank > 0`` retrieves a
     ``max(rerank, k)`` shortlist by ASH scores and re-ranks it with
@@ -231,6 +236,7 @@ class ScanPlan:
     k: int
     rerank: int = 0
     rows: Optional[jax.Array] = None
+    lists: Any = None
     n_valid: Any = None
     row_valid: Optional[jax.Array] = None
     ids: Optional[jax.Array] = None
@@ -280,7 +286,7 @@ def execute_plan(
             "shortlist= sets the coarse first-pass size and requires "
             "coarse='int8'"
         )
-    if plan.rows is None:
+    if plan.rows is None and plan.lists is None:
         return _execute_dense(
             model, prep, payload, plan, stats=stats, raw=raw,
             coarse_cache=coarse_cache,
@@ -376,10 +382,20 @@ def _execute_dense(model, prep, payload, plan, *, stats, raw,
 
 def _execute_gather(model, prep, payload, plan, *, stats, raw,
                     coarse_cache=None):
-    """Gathered-candidate lowering (IVF partial probes)."""
+    """Gathered-candidate lowering (IVF partial probes, int8 refine).
+    Probed lists feed the fused gather top-k as they are; the other
+    routes take their candidate rows."""
     from repro.kernels import ops as K
 
-    R = plan.rows.shape[1]
+    lists = plan.lists
+    if lists is None:
+        R = plan.rows.shape[1]
+    else:
+        R = lists.starts.shape[1] * lists.stride
+
+    def rows():
+        return plan.rows if lists is None else lists.rows()
+
     fused = plan.use_pallas is not False
     cap = fused_topk_limit()
 
@@ -391,7 +407,7 @@ def _execute_gather(model, prep, payload, plan, *, stats, raw,
         L = max(plan.shortlist or default_shortlist(), refine_k)
         if L < R:
             ss, srows = K.coarse_refine_gather_topk(
-                model, prep, payload, plan.rows, refine_k,
+                model, prep, payload, rows(), refine_k,
                 shortlist=L, metric=plan.metric, stats=stats,
                 coarse=coarse_cache, use_pallas=plan.use_pallas,
             )
@@ -408,16 +424,18 @@ def _execute_gather(model, prep, payload, plan, *, stats, raw,
     def shortlist(size):
         if fused and size <= cap:
             return K.ash_score_gather_topk(
-                model, prep, payload, plan.rows, size,
+                model, prep, payload,
+                plan.rows if lists is None else lists, size,
                 metric=plan.metric, stats=stats,
                 use_pallas=plan.use_pallas,
             )
+        cand = rows()
         sc = K.ash_score_gather(
-            model, prep, payload, plan.rows, metric=plan.metric,
+            model, prep, payload, cand, metric=plan.metric,
             stats=stats, use_pallas=plan.use_pallas,
         )
         s, pos = jax.lax.top_k(sc, size)
-        return s, jnp.take_along_axis(plan.rows, pos, axis=1)
+        return s, jnp.take_along_axis(cand, pos, axis=1)
 
     if plan.rerank and raw is not None:
         ss, srows = shortlist(min(max(plan.rerank, plan.k), R))

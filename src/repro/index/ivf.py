@@ -4,9 +4,12 @@ The ASH landmarks ARE the IVF centroids (Section 2 of the paper): the
 coarse quantizer used for residual centering doubles as the routing
 structure, so OFFSET/SCALE come for free per list.
 
-JAX needs static shapes, so inverted lists are stored padded to the
-longest list; search gathers ``nprobe`` padded lists per query, scores
-them with the asymmetric estimator, masks padding, and top-k's.
+Rows are sorted by cluster, so each inverted list is a contiguous row
+range of the payload; ``invlists`` also stores them padded to the
+longest list (static shapes).  A partial probe hands each query's
+``nprobe`` lists to the gather kernel as row ranges: it reads their
+blocks in place, scores them with the asymmetric estimator, masks what
+lies outside a list, and top-k's.
 Queries with fewer than k valid candidates pad results with score
 ``-inf`` / id ``-1`` (never aliased to row 0).
 
@@ -29,6 +32,7 @@ from repro.core.types import (
     pytree_dataclass,
 )
 from repro.index import common as C
+from repro.kernels.ash_score import ProbedLists
 
 NEG_INF = C.NEG_INF
 
@@ -47,8 +51,9 @@ class IVFIndex:
     stats: Optional[ASHStats] = None
     # Row-validity bitmap, row-aligned with ``payload``: False rows are
     # tombstoned (deleted).  Dense full-probe scans mask them via the
-    # kernel mask operand; partial probes drop them from the candidate
-    # lists before the gather kernel DMAs anything.  None = all live.
+    # kernel mask operand; partial probes mask them in the gather
+    # kernel (the oracle drops them from the candidate rows).  None =
+    # all live.
     live: Optional[jax.Array] = None
     # Meta: id the next added row receives (see effective_next_id).
     next_id: Optional[int] = None
@@ -405,6 +410,23 @@ def _score_probed(
     )
 
 
+def probed_lists(index: IVFIndex, probe: jax.Array) -> ProbedLists:
+    """The (m, nprobe) probed lists as payload row ranges.  Lists are
+    contiguous in the cluster-sorted payload, so a list's row of
+    ``invlists`` reads ``start, start + 1, ...`` then ``-1`` padding:
+    its first entry is the start and its non-pad entries count its
+    rows."""
+    inv = index.invlists
+    lens = jnp.sum(inv >= 0, axis=1, dtype=jnp.int32)
+    return ProbedLists(
+        starts=jnp.maximum(inv[:, 0], 0)[probe],
+        lens=lens[probe],
+        clusters=probe.astype(jnp.int32),
+        live=index.live,
+        stride=inv.shape[1],
+    )
+
+
 def _score_probed_impl(
     index: IVFIndex,
     prep: QueryPrep,
@@ -414,23 +436,16 @@ def _score_probed_impl(
     coarse: Optional[str] = None,
     shortlist: Optional[int] = None,
 ) -> tuple[jax.Array, jax.Array]:
-    """Gather each query's candidate lists and lower to a gathered
-    ``ScanPlan`` — the masked-gather kernel family scores straight off
-    the packed codes (pad ids mask to ``-inf``) and fuses the
-    selection; no (m, nprobe*L) score matrix reaches HBM on TPU."""
-    m = prep.q.shape[0]
+    """Hand each query's probed lists to a gathered ``ScanPlan`` as
+    contiguous row ranges (``ProbedLists``): on TPU the gather kernel
+    reads the lists' blocks from the resident payload, scores them off
+    the packed codes (list padding and tombstones mask to ``-inf``) and
+    fuses the selection; no per-slot gather and no (m, nprobe*L) score
+    matrix reaches HBM."""
     with jax.named_scope("ash.candidates"):
-        cand_rows = index.invlists[probe].reshape(m, -1)  # (m, nprobe*L)
-        if index.live is not None:
-            # drop tombstoned rows pre-DMA: mapped to the -1 pad id, the
-            # gather kernel never issues a copy for them and the
-            # epilogue masks the slot to -inf — identical to list
-            # padding
-            cand_rows = jnp.where(
-                index.live[jnp.maximum(cand_rows, 0)], cand_rows, -1
-            )
+        lists = probed_lists(index, probe)
     plan = C.ScanPlan(
-        metric=index.metric, k=k, rerank=rerank, rows=cand_rows,
+        metric=index.metric, k=k, rerank=rerank, lists=lists,
         ids=index.ids, coarse=coarse, shortlist=shortlist,
     )
     return C.execute_plan(

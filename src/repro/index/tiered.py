@@ -21,12 +21,12 @@ HBM backend compiles — ``ivf._score_probed`` for partial probes,
 identical and only the gather-source length differs.  That is the
 load-bearing choice for bit-identity: the union preserves the global
 row order restricted to the probed lists (ascending contiguous slices
-→ a monotone index shift), so the in-graph ``invlists[probe]`` gather
-produces slot-for-slot the same candidate values, and reusing the HBM
-backend's own jit (rather than a lookalike graph) keeps XLA's fusion
-and rounding decisions aligned — a separately-jitted clone of the same
-math has been observed to drift by one ulp under some XLA host
-configurations.  Results are bit-identical to ``backend="ivf"`` at
+→ a monotone index shift), so the probed lists' row ranges
+(``ivf.probed_lists``) give slot-for-slot the same candidates, and
+reusing the HBM backend's own jit (rather than a lookalike graph)
+keeps XLA's fusion and rounding decisions aligned — a separately-jitted
+clone of the same math has been observed to drift by one ulp under
+some XLA host configurations.  Results are bit-identical to ``backend="ivf"`` at
 equal probe sets for every option combination (rerank,
 ``coarse="int8"``, m=1 padding, covering nprobe, tombstones).
 
@@ -415,8 +415,8 @@ class TieredIVFBackend:
             pp.union_lists, pp.n_pad - pp.n_union
         )
         # ... then execute through the HBM backend's OWN jitted
-        # gather (in-graph invlists[probe] + tombstone drop): same
-        # traced graph, so same fusion/rounding — see module doc
+        # gather (probed row ranges + tombstone mask): same traced
+        # graph, so same fusion/rounding — see module doc
         return IV._score_probed(
             uidx, prep, jnp.asarray(probe, dtype=jnp.int32), k,
             rerank, coarse=coarse, shortlist=shortlist,

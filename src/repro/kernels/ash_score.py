@@ -77,13 +77,28 @@ from O(m·n) fp32 to O(m · n/block_n · k̃).
 
 Masked-gather variants (:func:`ash_score_gather_pallas`,
 :func:`ash_score_gather_topk_pallas`): the same epilogues and fused
-selection over PER-QUERY candidate lists (IVF partial probes).  XLA
-gathers the probed rows' PACKED words (W words per candidate) and
-header values into per-query, lane-dense (m, ·, R) operands; the kernel
-unpacks and scores eight queries per grid step on the VPU (each query
-has its own candidates, so the product is a batched mat-vec) and fuses
-the selection.  Neither the unpacked codes nor the (m, R) score matrix
-reach HBM.  Pad entries (row id -1) are masked to ``-inf``.
+selection over PER-QUERY candidates, scored eight queries per grid step
+on the VPU (each query has its own candidates, so the product is a
+batched mat-vec).  Neither the unpacked codes nor the (m, R) score
+matrix reach HBM.  Two forms share the kernel body and differ only in
+how the operands arrive and how slots are numbered:
+
+  * list form (IVF partial probes, :class:`ProbedLists`): each query's
+    probed lists are contiguous row ranges of the cluster-sorted
+    payload, so the kernel reads them in place.  The list geometry is
+    scalar-prefetched; eight BlockSpecs per payload operand (one per
+    query of the step) index the resident ``codes.T`` and (1, n) rows
+    at block ``start // r_blk + l`` of the step's list, clamped to its
+    last block, so a list costs the blocks it spans and an empty list
+    or a step past its end issues no copy.  One landmark bias per
+    (query, list) replaces the per-row cluster lookup.  Rows outside
+    the list, and tombstoned rows, are masked; slot ``p * stride + t``
+    is the same slot the row table of the same lists would give.
+  * rows form (arbitrary row sets: the int8 refine's shortlists, and
+    the materializing route): XLA gathers the rows' PACKED words (W
+    words per candidate) and header values into per-query, lane-dense
+    (m, ·, R) operands.  Pad entries (row id -1) are masked to
+    ``-inf``.
 
 Row-validity masking: a runtime (1, n) int32 mask operand folds the
 sharded backend's ``n_real`` pad truncation AND the tombstone bitmap
@@ -91,9 +106,10 @@ into the dense selection kernel's id masking, so one compiled program
 serves every shard and every mutation state.
 
 Grids: dense (m_blocks, n_blocks, d_blocks), query blocks parallel and
-row tiles in order for the running threshold; gather (m/8, r_blocks,
-d_blocks), whose per-tile selection keeps k̃ sweeps; d innermost for
-accumulation in a VMEM scratch tile.
+row tiles in order for the running threshold; gather rows form (m/8,
+r_blocks, d_blocks) and list form (m/8, nprobe, list blocks, d_blocks),
+whose per-tile selections keep k̃ sweeps; d innermost for accumulation
+in a VMEM scratch tile.
 """
 from __future__ import annotations
 
@@ -106,6 +122,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import quantization as Q
+from repro.core.types import pytree_dataclass
 
 DEFAULT_BLOCK_M = 128
 DEFAULT_BLOCK_N = 2048
@@ -612,6 +629,61 @@ def ash_score_topk_pallas(
 # ---------------------------------------------------------------------------
 
 
+@pytree_dataclass(meta_fields=("stride",))
+class ProbedLists:
+    """Each query's probed inverted lists, as the contiguous payload row
+    ranges of a cluster-sorted payload: list ``clusters[i, p]`` holds the
+    rows ``[starts[i, p], starts[i, p] + lens[i, p])``, all of that
+    cluster.  Candidate slot ``p * stride + t`` of query i is row
+    ``starts[i, p] + t`` — the slot order of ``invlists[probe]``, whose
+    padded width is ``stride``.  ``live`` ((n,) bool, or None) drops
+    tombstoned rows."""
+
+    starts: jax.Array  # (m, nprobe) int32
+    lens: jax.Array  # (m, nprobe) int32
+    clusters: jax.Array  # (m, nprobe) int32 probed list ids
+    live: jax.Array | None
+    stride: int
+
+    def rows(self) -> jax.Array:
+        """The (m, nprobe * stride) candidate-row table, -1 past each
+        list's end and for tombstoned rows: ``invlists[probe]`` with
+        the tombstone drop, for the callers that take arbitrary rows."""
+        m = self.starts.shape[0]
+        t = jnp.arange(self.stride, dtype=jnp.int32)
+        rows = jnp.where(
+            t < self.lens[..., None], self.starts[..., None] + t, -1
+        ).reshape(m, -1)
+        if self.live is not None:
+            rows = jnp.where(self.live[jnp.maximum(rows, 0)], rows, -1)
+        return rows
+
+
+def _gather_accumulate(acc_ref, kd, q, codes, b, compute_dtype):
+    """Per-query mat-vec on the VPU: (8, d_blk, 1) x (8, d_blk, r_blk),
+    exact f32 products of the (compute_dtype-rounded) query and the
+    small-integer grid values, reduced over d into the accumulator."""
+
+    @pl.when(kd == 0)
+    def _zero():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    vals = _unpack_lanes(codes, b, jnp.float32)
+    q = q.astype(compute_dtype).astype(jnp.float32)
+    acc_ref[...] += jnp.sum(q * vals, axis=1)
+
+
+def _gather_emit(acc, scale, bias, offset, qterm, rowterm, metric, valid,
+                 col0, k_tilde, outs):
+    """Epilogue of one gather tile, then its partial top-k̃ (slot ids
+    ``col0 + column``) or its -inf-masked scores."""
+    scores = _epilogue(acc, scale, bias, offset, qterm, rowterm, metric)
+    if k_tilde:
+        _select_topk(scores, valid, col0, k_tilde, *outs)
+    else:
+        outs[0][...] = jnp.where(valid, scores, jnp.float32(-jnp.inf))
+
+
 def _gather_kernel(
     *refs,
     b: int,
@@ -621,9 +693,10 @@ def _gather_kernel(
     k_tilde: int | None,
     block_r: int,
 ):
-    """Refs: q (8, d_blk, 1), codes (8, w_blk, r_blk), scale/bias/
-    offset/rows (8, r_blk); [qterm (8, 1), rowterm (8, r_blk)] unless
-    dot; outputs (scores, or the vals/ids strip blocks); accumulator."""
+    """Rows form.  Refs: q (8, d_blk, 1), codes (8, w_blk, r_blk),
+    scale/bias/offset/rows (8, r_blk); [qterm (8, 1), rowterm (8,
+    r_blk)] unless dot; outputs (scores, or the vals/ids strip blocks);
+    accumulator."""
     q_ref, codes_ref, scale_ref, bias_ref, offset_ref, rows_ref = refs[:6]
     rest = refs[6:]
     qterm_ref = rowterm_ref = None
@@ -633,32 +706,108 @@ def _gather_kernel(
     kd = pl.program_id(2)
     r0 = pl.program_id(1) * block_r
 
-    @pl.when(kd == 0)
-    def _zero():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    # per-query mat-vec on the VPU: (8, d_blk, 1) x (8, d_blk, r_blk),
-    # exact f32 products of the (compute_dtype-rounded) query and the
-    # small-integer grid values, reduced over d
-    vals = _unpack_lanes(codes_ref[...], b, jnp.float32)
-    q = q_ref[...].astype(compute_dtype).astype(jnp.float32)
-    acc_ref[...] += jnp.sum(q * vals, axis=1)
+    _gather_accumulate(
+        acc_ref, kd, q_ref[...], codes_ref[...], b, compute_dtype
+    )
 
     @pl.when(kd == n_d_blocks - 1)
     def _finish():
-        scores = _epilogue(
+        # pad-id masking IS the validity mask: list padding, dropped
+        # (tombstoned) rows and R-padding all carry id -1
+        _gather_emit(
             acc_ref[...], scale_ref[...], bias_ref[...], offset_ref[...],
             None if qterm_ref is None else qterm_ref[...],
             None if rowterm_ref is None else rowterm_ref[...],
-            metric,
+            metric, rows_ref[...] >= 0, r0, k_tilde, outs,
         )
-        # pad-id masking IS the validity mask: list padding, dropped
-        # (tombstoned) rows and R-padding all carry id -1
-        valid = rows_ref[...] >= 0
-        if k_tilde:
-            _select_topk(scores, valid, r0, k_tilde, *outs)
-        else:
-            outs[0][...] = jnp.where(valid, scores, jnp.float32(-jnp.inf))
+
+
+def _list_kernel(
+    geo_ref,
+    *refs,
+    b: int,
+    n_d_blocks: int,
+    compute_dtype,
+    metric: str,
+    k_tilde: int,
+    block_r: int,
+    nprobe: int,
+    stride: int,
+    use_live: bool,
+    count_blocks: bool,
+):
+    """List form.  ``geo_ref`` (SMEM): (start, len) of each (query,
+    probe), flattened.  Refs: q (8, d_blk, 1), bias (1, 8, 1); [qterm
+    (8, 1)] unless dot; then per query of the block its own blocks of
+    the resident payload — codes (w_blk, r_blk), scale, offset,
+    [rowterm] unless dot, [live] if ``use_live``, each (1, r_blk);
+    outputs: the vals/ids strip blocks, [the (1, 8, 128) block count];
+    accumulator.  Step (qb, p, l) scores block ``start // r_blk + l`` of
+    each query's p-th list; a step where no query has a block left
+    writes sentinels and computes nothing."""
+    q_ref, bias_ref, *rest = refs
+    qterm_ref = rest.pop(0) if metric != "dot" else None
+    fields = 3 + (metric != "dot") + use_live
+    bq = _GATHER_BLOCK_Q
+    per_q = [rest[j * fields:(j + 1) * fields] for j in range(bq)]
+    rest = rest[bq * fields:]
+    outs = rest[:2]
+    blocks_ref = rest[2] if count_blocks else None
+    acc_ref = rest[-1]
+    # program_id must be read outside the pl.when bodies (interpret
+    # mode lowers them through lax.cond, where the primitive is absent)
+    qb, p, blk, kd = (pl.program_id(a) for a in range(4))
+
+    # each query's list: its row range and the step's first row, as
+    # (8, 1) columns, and how many of the eight still have a block
+    lane = jax.lax.broadcasted_iota(jnp.int32, (bq, 1), 0)
+    lo = hi = row0 = jnp.zeros((bq, 1), jnp.int32)
+    busy = jnp.int32(0)
+    for j in range(bq):
+        e = ((qb * bq + j) * nprobe + p) * 2
+        start, n = geo_ref[e], geo_ref[e + 1]
+        first = start // block_r
+        n_blocks = (start - first * block_r + n + block_r - 1) // block_r
+        busy = busy + ((n > 0) & (blk < n_blocks)).astype(jnp.int32)
+        lo = jnp.where(lane == j, start, lo)
+        hi = jnp.where(lane == j, start + n, hi)
+        row0 = jnp.where(lane == j, (first + blk) * block_r, row0)
+
+    if count_blocks:
+        @pl.when(kd == 0)
+        def _count():
+            fresh = (p == 0) & (blk == 0)
+            blocks_ref[...] = jnp.where(fresh, 0, blocks_ref[...]) + busy
+
+    def rows_of(f):  # the eight (1, r_blk) blocks of field f: (8, r_blk)
+        return jnp.concatenate([q_refs[f][...] for q_refs in per_q])
+
+    @pl.when(busy > 0)
+    def _score():
+        codes = jnp.concatenate([q_refs[0][...][None] for q_refs in per_q])
+        _gather_accumulate(
+            acc_ref, kd, q_ref[...], codes, b, compute_dtype
+        )
+
+        @pl.when(kd == n_d_blocks - 1)
+        def _finish():
+            row = row0 + jax.lax.broadcasted_iota(
+                jnp.int32, (bq, block_r), 1
+            )
+            valid = (row >= lo) & (row < hi)
+            if use_live:
+                valid = valid & (rows_of(fields - 1) != 0)
+            _gather_emit(
+                acc_ref[...], rows_of(1), bias_ref[0], rows_of(2),
+                None if qterm_ref is None else qterm_ref[...],
+                None if metric == "dot" else rows_of(3),
+                metric, valid, row0 - lo + p * stride, k_tilde, outs,
+            )
+
+    @pl.when((busy == 0) & (kd == n_d_blocks - 1))
+    def _idle():
+        outs[0][...] = jnp.full(outs[0].shape, -jnp.inf, jnp.float32)
+        outs[1][...] = jnp.full(outs[1].shape, _ID_SENTINEL, jnp.int32)
 
 
 def _gather_call(
@@ -749,6 +898,134 @@ def _gather_call(
     return _merge_strip(*out, m, k), rows_p[:m]
 
 
+def _list_call(
+    codes, lists, q, scale, offset, ipq, qterm, rowterm, *,
+    b, metric, block_r, block_d, interpret, compute_dtype, k, k_tilde,
+    count_blocks,
+):
+    """List form of the fused gather top-k: (scores, slot positions),
+    each (m, k), [and the call's (query, list-block) copies].  The
+    kernel reads each probed list's blocks straight from the resident
+    payload (``codes.T`` and (1, n) rows), eight BlockSpecs per operand
+    whose index maps read the scalar-prefetched list geometry."""
+    assert metric in METRICS, metric
+    n, W = codes.shape
+    m, d_pad = q.shape
+    nprobe, L = lists.starts.shape[1], lists.stride
+    kpw = Q.codes_per_word(b)
+    assert W * kpw == d_pad, (W, kpw, d_pad)
+
+    bq = _GATHER_BLOCK_Q
+    br = min(block_r, _round_up(nprobe * L, 128), _round_up(n, 128))
+    bd = min(block_d, d_pad)
+    assert bd % kpw == 0
+    bw = bd // kpw
+    m_p = _round_up(m, bq)
+    d_p = _round_up(d_pad, bd)
+    n_blk = pl.cdiv(L + br - 1, br)  # blocks an unaligned list can span
+    grid = (m_p // bq, nprobe, n_blk, d_p // bd)
+
+    def pad_q(a):  # per-(query, probe) (m, nprobe) -> (m_p, nprobe)
+        return jnp.pad(a.astype(jnp.int32), ((0, m_p - m), (0, 0)))
+
+    def lanes(a):  # (m_p, nprobe) -> (8, m_p / 8 * nprobe), grid order
+        a = a.reshape(m_p // bq, bq, nprobe).transpose(1, 0, 2)
+        return a.reshape(bq, -1)
+
+    def unlanes(a):
+        a = a.reshape(bq, m_p // bq, nprobe).transpose(1, 0, 2)
+        return a.reshape(m_p, nprobe)
+
+    with jax.named_scope("ash.gather"):
+        start, lens = pad_q(lists.starts), pad_q(lists.lens)
+        # an empty list (and every pad query's) points at the block its
+        # query lane read last, in grid order, so it costs no copy: its
+        # start moves to that block, its length stays 0
+        last = lanes((start + jnp.maximum(lens, 1) - 1) // br)
+        seq = jnp.arange(last.shape[1], dtype=jnp.int32)
+        src = jax.lax.cummax(jnp.where(lanes(lens) > 0, seq, -1), axis=1)
+        held = jnp.take_along_axis(last, jnp.maximum(src, 0), axis=1)
+        held = unlanes(jnp.where(src >= 0, held, 0))
+        start = jnp.where(lens > 0, start, held * br)
+        geo = jnp.stack([start, lens], axis=-1).reshape(-1)
+        # every row of list c has cluster c: one landmark bias per list
+        bias = jnp.take_along_axis(
+            jnp.pad(ipq.astype(jnp.float32), ((0, m_p - m), (0, 0))),
+            pad_q(lists.clusters), axis=1,
+        ).T[:, :, None]  # (nprobe, m_p, 1)
+        q3 = jnp.pad(q, ((0, m_p - m), (0, d_p - d_pad)))[:, :, None]
+        fields = [scale, offset]
+        if metric != "dot":
+            fields.append(rowterm)
+        fields = [f.astype(jnp.float32).reshape(1, n) for f in fields]
+        if lists.live is not None:
+            fields.append(lists.live.astype(jnp.int32).reshape(1, n))
+
+    def block(j):  # query lane j's block of its p-th list at step l
+        def index(qb, p, l, geo):
+            # lax ops (not jnp's): each jnp call here is one more trace
+            # per BlockSpec, bucket and process; s, ln >= 0
+            e = ((qb * bq + j) * nprobe + p) * 2
+            s, ln = geo[e], geo[e + 1]
+            end = jax.lax.div(s + jax.lax.max(ln, 1) - 1, br)
+            return jax.lax.min(jax.lax.div(s, br) + l, end)
+        return index
+
+    operands = [q3, bias]
+    in_specs = [
+        pl.BlockSpec((bq, bd, 1), lambda qb, p, l, kd, geo: (qb, kd, 0)),
+        pl.BlockSpec((1, bq, 1), lambda qb, p, l, kd, geo: (p, qb, 0)),
+    ]
+    if metric != "dot":
+        operands.append(
+            jnp.pad(qterm.astype(jnp.float32), (0, m_p - m))[:, None])
+        in_specs.append(
+            pl.BlockSpec((bq, 1), lambda qb, p, l, kd, geo: (qb, 0)))
+    for j in range(bq):
+        at = block(j)
+        operands += [codes.T, *fields]
+        in_specs.append(pl.BlockSpec(
+            (bw, br),
+            lambda qb, p, l, kd, geo, at=at: (kd, at(qb, p, l, geo)),
+        ))
+        in_specs += [pl.BlockSpec(
+            (1, br),
+            lambda qb, p, l, kd, geo, at=at: (0, at(qb, p, l, geo)),
+        )] * len(fields)
+
+    kt, out_specs, out_shape = _strip_outputs(
+        k, k_tilde, br, nprobe * n_blk, grid[0], bq,
+        lambda qb, p, l, kd, geo: (qb, p * n_blk + l, 0),
+    )
+    if count_blocks:
+        out_specs.append(
+            pl.BlockSpec((1, 8, 128), lambda qb, p, l, kd, geo: (qb, 0, 0)))
+        out_shape.append(jax.ShapeDtypeStruct((grid[0], 8, 128), jnp.int32))
+    out = pl.pallas_call(
+        functools.partial(
+            _list_kernel, b=b, n_d_blocks=grid[3],
+            compute_dtype=compute_dtype, metric=metric, k_tilde=kt,
+            block_r=br, nprobe=nprobe, stride=L,
+            use_live=lists.live is not None, count_blocks=count_blocks,
+        ),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=grid,
+            in_specs=in_specs,
+            out_specs=out_specs,
+            scratch_shapes=[pltpu.VMEM((bq, br), jnp.float32)],
+        ),
+        out_shape=out_shape,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=(
+                "parallel", "arbitrary", "arbitrary", "arbitrary")
+        ),
+        interpret=interpret,
+    )(geo, *operands)
+    merged = _merge_strip(*out[:2], m, k)
+    return merged + ((jnp.sum(out[2][:, 0, 0]),) if count_blocks else ())
+
+
 @functools.partial(
     jax.jit,
     static_argnames=(
@@ -791,12 +1068,12 @@ def ash_score_gather_pallas(
     jax.jit,
     static_argnames=(
         "b", "k", "k_tilde", "metric", "block_r", "block_d", "interpret",
-        "compute_dtype",
+        "compute_dtype", "count_blocks",
     ),
 )
 def ash_score_gather_topk_pallas(
     codes: jax.Array,
-    rows: jax.Array,  # (m, R) int32 candidate rows, -1 = padding
+    rows: jax.Array | ProbedLists,  # (m, R) int32 rows, -1 = padding
     q_proj: jax.Array,
     scale: jax.Array,
     offset: jax.Array,
@@ -813,7 +1090,8 @@ def ash_score_gather_topk_pallas(
     block_d: int = DEFAULT_BLOCK_D,
     interpret: bool = False,
     compute_dtype=jnp.bfloat16,
-) -> tuple[jax.Array, jax.Array]:
+    count_blocks: bool = False,
+) -> tuple[jax.Array, ...]:
     """Fused masked-gather scan + selection: (scores, payload rows),
     each (m, k).
 
@@ -822,7 +1100,33 @@ def ash_score_gather_topk_pallas(
     break to the lowest candidate POSITION, the ``lax.top_k``
     convention) for ``k <= k̃``.  Slots without a candidate (pad ids,
     or k beyond the emitted strip) come back score ``-inf`` / row -1.
+
+    ``rows`` is either an arbitrary (m, R) row table (rows form: XLA
+    gathers the candidates' words and headers first) or
+    :class:`ProbedLists`, whole contiguous lists (list form: the kernel
+    reads each list's blocks from the resident payload, and the cluster
+    column is not read).  Both give the same results bit for bit for
+    the same candidates, ``ProbedLists.rows()``.  ``count_blocks=True``
+    (list form) also returns the call's (query, list-block) copies, a
+    measurement hook the served path never asks for.
     """
+    if isinstance(rows, ProbedLists):
+        s, pos, *blocks = _list_call(
+            codes, rows, q_proj, scale, offset, ip_q_landmarks, qterm,
+            rowterm, b=b, metric=metric, block_r=block_r, block_d=block_d,
+            interpret=interpret, compute_dtype=compute_dtype, k=k,
+            k_tilde=k_tilde, count_blocks=count_blocks,
+        )
+        # slot p * stride + t of query i is row starts[i, p] + t
+        L, nprobe = rows.stride, rows.starts.shape[1]
+        start = jnp.take_along_axis(
+            rows.starts.astype(jnp.int32),
+            jnp.clip(pos // L, 0, nprobe - 1), axis=1,
+        )
+        out = (s, jnp.where(pos == _ID_SENTINEL, -1, start + pos % L))
+        return out + tuple(blocks)
+    if count_blocks:
+        raise ValueError("count_blocks counts the list form's copies")
     (out_s, out_p), rows_p = _gather_call(
         codes, rows, q_proj, scale, offset, cluster, ip_q_landmarks,
         qterm, rowterm, b=b, metric=metric, block_r=block_r,

@@ -19,6 +19,7 @@ from repro.core.types import (
 )
 from repro.kernels import ref
 from repro.kernels.ash_score import (
+    ProbedLists,
     ash_score_coarse_pallas,
     ash_score_coarse_topk_pallas,
     ash_score_gather_pallas,
@@ -255,7 +256,7 @@ def ash_score_gather_topk(
     model: ASHModel,
     prep: QueryPrep,
     payload: ASHPayload,
-    rows: jax.Array,
+    rows: jax.Array | ProbedLists,
     k: int,
     *,
     metric: str = "dot",
@@ -270,7 +271,9 @@ def ash_score_gather_topk(
 
     Equal to ``top_k(ash_score_gather(...), k)`` with positions mapped
     back through ``rows`` — on TPU without the (m, R) score matrix ever
-    reaching HBM.  Requires ``k <= rows.shape[1]``.
+    reaching HBM.  Requires ``k <= R``.  ``rows`` may be
+    :class:`ProbedLists` (whole contiguous lists): the kernel then
+    reads the lists in place, and the oracle scores ``rows.rows()``.
     """
     if use_pallas is None:
         use_pallas = not _auto_interpret()
@@ -279,6 +282,8 @@ def ash_score_gather_topk(
     codes, q_proj, scale, offset, cluster, ipq = _score_args(prep, payload)
     qterm, rowterm = _metric_operands(model, prep, payload, stats, metric)
     if not use_pallas:
+        if isinstance(rows, ProbedLists):
+            rows = rows.rows()
         scores = ref.ash_score_gather_ref(
             codes, rows, q_proj, scale, offset, cluster, ipq,
             qterm, rowterm, b=payload.b, metric=metric,

@@ -24,6 +24,7 @@ from repro.index import common as C
 from repro.index import distributed as DX
 from repro.kernels import ops, ref
 from repro.kernels.ash_score import (
+    ProbedLists,
     ash_score_gather_pallas,
     ash_score_gather_topk_pallas,
     ash_score_pallas,
@@ -164,6 +165,89 @@ def test_dense_topk_dynamic_n_valid_masks_rows():
 
 
 # ---------------------------------------------------------------------------
+# List form: whole contiguous lists read in place by the gather kernel
+# ---------------------------------------------------------------------------
+
+# list lengths of a cluster-sorted payload: an empty list (3), one
+# longer than a 128-row block (5), the rest ragged, so most starts are
+# not block-aligned
+LIST_LENS = (37, 0, 150, 0, 61, 300, 9, 128, 77, 1)
+
+
+def _mk_lists(key, b, m, live_frac=None, d=64, block_rows=128):
+    """A cluster-sorted synthetic payload, per-query probes (query 1
+    probes the empty list and the long one; queries 1 and 2 probe the
+    same lists) and the matching :class:`ProbedLists`."""
+    lens = np.asarray(LIST_LENS)
+    nlist, n = lens.size, int(lens.sum())
+    starts = np.concatenate([[0], np.cumsum(lens)[:-1]])
+    codes, q, scale, offset, _, ipq, qterm, rowterm = _mk_inputs(
+        key, b, d, n, m, nlist
+    )
+    cluster = jnp.asarray(np.repeat(np.arange(nlist), lens), jnp.int32)
+    rng = np.random.default_rng(b * 100 + m)
+    probe = np.stack([rng.permutation(nlist)[:4] for _ in range(m)])
+    if m > 2:
+        probe[1] = (1, 5, 3, 0)
+        probe[2] = probe[1]
+    live = None
+    if live_frac is not None:
+        live = jnp.asarray(rng.random(n) > live_frac)
+    lists = ProbedLists(
+        starts=jnp.asarray(starts[probe], jnp.int32),
+        lens=jnp.asarray(lens[probe], jnp.int32),
+        clusters=jnp.asarray(probe, jnp.int32),
+        live=live, stride=int(lens.max()),
+    )
+    return (codes, q, scale, offset, cluster, ipq, qterm, rowterm), lists
+
+
+@pytest.mark.parametrize("m, live_frac", ((2, None), (11, 0.25)))
+@pytest.mark.parametrize("b", (2, 4))
+@pytest.mark.parametrize("metric", METRICS)
+def test_list_form_bit_identical_to_rows_form(metric, b, m, live_frac):
+    """The gather kernel reading whole lists in place returns exactly
+    what it returns for the same candidates as a row table: values,
+    rows and tie order — over an empty list, a list longer than a
+    block, unaligned starts, two queries probing the same lists,
+    tombstones, the served m=2 pad and a query block with pad rows."""
+    (codes, q, *rest), lists = _mk_lists(
+        jax.random.PRNGKey(b * 13 + m), b, m, live_frac
+    )
+    rows = lists.rows()
+    assert (np.asarray(rows) >= 0).sum() > 0
+    kw = dict(b=b, metric=metric, interpret=True,
+              compute_dtype=jnp.float32, block_r=128)
+    for k in (1, 10, 40):
+        gs, gr = ash_score_gather_topk_pallas(codes, lists, q, *rest, k=k,
+                                              **kw)
+        ws, wr = ash_score_gather_topk_pallas(codes, rows, q, *rest, k=k,
+                                              **kw)
+        assert np.array_equal(np.asarray(gs), np.asarray(ws)), (metric, k)
+        assert np.array_equal(np.asarray(gr), np.asarray(wr)), (metric, k)
+    # the empty and tombstoned slots never surface
+    got = np.asarray(gr)
+    if live_frac is not None:
+        alive = np.asarray(lists.live)
+        assert alive[got[got >= 0]].all()
+
+
+@pytest.mark.parametrize("block_r", (128, 256))
+def test_list_form_counts_block_copies(block_r):
+    """``count_blocks`` is the number of (query, list-block) copies:
+    each real probe of a non-empty list reads the blocks its row range
+    spans, and the five pad queries of the block read nothing."""
+    (codes, q, *rest), lists = _mk_lists(jax.random.PRNGKey(7), 2, 3)
+    _, _, blocks = ash_score_gather_topk_pallas(
+        codes, lists, q, *rest, b=2, k=10, metric="l2", interpret=True,
+        compute_dtype=jnp.float32, block_r=block_r, count_blocks=True,
+    )
+    starts, lens = np.asarray(lists.starts), np.asarray(lists.lens)
+    want = np.where(lens > 0, -(-(starts % block_r + lens) // block_r), 0)
+    assert int(blocks) == int(want.sum()) > 0
+
+
+# ---------------------------------------------------------------------------
 # Index-layer routing on a real encoded payload
 # ---------------------------------------------------------------------------
 
@@ -247,6 +331,25 @@ def test_ivf_partial_probe_fused_equals_materialized(index_setup, metric):
     ws, wids = materialized(state, idx.prepare(Qm))
     assert np.array_equal(np.asarray(s), np.asarray(ws))
     assert np.array_equal(np.asarray(ids), np.asarray(wids))
+
+
+def test_probed_lists_are_the_padded_lists(index_setup):
+    """The row ranges a partial probe hands the kernel are the padded
+    inverted lists: ``probed_lists(...).rows()`` equals
+    ``invlists[probe]`` with tombstoned rows dropped, slot for slot,
+    after an add and deletes."""
+    from repro.index import ivf as IV
+
+    X, Qm, cfg, model, kb = index_setup
+    idx = AshIndex.build(kb, X[:2000], cfg, backend="ivf", model=model)
+    idx.add(X[2000:2400])
+    assert idx.delete(np.arange(0, 2400, 7)) > 0
+    st = idx._state
+    probe = IV._probe_lists(st, idx.prepare(Qm), 3)
+    got = IV.probed_lists(st, probe).rows()
+    want = st.invlists[probe].reshape(Qm.shape[0], -1)
+    want = jnp.where(st.live[jnp.maximum(want, 0)], want, -1)
+    assert np.array_equal(np.asarray(got), np.asarray(want))
 
 
 def test_ivf_partial_probe_single_row_matches_batch(index_setup):

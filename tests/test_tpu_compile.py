@@ -30,6 +30,7 @@ from repro.data.synthetic import embedding_dataset
 from repro.index import AshIndex
 from repro.kernels.ash_score import (
     DEFAULT_BLOCK_N,
+    ProbedLists,
     ash_score_coarse_topk_pallas,
     ash_score_gather_topk_pallas,
     ash_score_topk_pallas,
@@ -42,6 +43,9 @@ COS_D, COS_B = 128, 4
 # IVF candidates per query at nprobe=16: 16 lists padded to 4096 rows,
 # above the smoke corpus's longest list (1,670 rows at seed 0)
 SMOKE_IVF_R = 16 * 4096
+# the glove-100-angular IVF corpus: 1,183,514 rows in 1,024 lists, the
+# longest 2,876 rows, 16 probed per query, d=64 at b=2
+GLOVE_N, GLOVE_L, GLOVE_NPROBE = 1_183_514, 2_876, 16
 
 
 @pytest.fixture(scope="module")
@@ -174,6 +178,37 @@ def test_gather_fused_topk_compiles_at_ivf_width(spec):
     )
 
 
+@pytest.mark.parametrize("tombstones", (False, True))
+@pytest.mark.parametrize("m", (8, 128))
+def test_gather_list_form_compiles_at_glove_geometry(spec, m, tombstones):
+    """The list form at the served glove shapes: eight blocks of each
+    payload operand a step, their index maps reading the prefetched
+    list geometry, within the chip's scalar and vector memory, under
+    the kernel's own name."""
+    f32, i32 = jnp.float32, jnp.int32
+    n = GLOVE_N
+    args = [
+        spec((n, W), jnp.uint32), spec((m, D), f32), spec((n,), f32),
+        spec((n,), f32), spec((n,), i32), spec((m, C), f32),
+        spec((m,), f32), spec((n,), f32),
+        spec((m, GLOVE_NPROBE), i32), spec((m, GLOVE_NPROBE), i32),
+        spec((m, GLOVE_NPROBE), i32),
+    ] + ([spec((n,), jnp.bool_)] if tombstones else [])
+
+    def lists_topk(codes, q, scale, offset, cluster, ipq, qterm, rowterm,
+                   starts, lens, clusters, live=None):
+        lists = ProbedLists(starts=starts, lens=lens, clusters=clusters,
+                            live=live, stride=GLOVE_L)
+        return ash_score_gather_topk_pallas(
+            codes, lists, q, scale, offset, cluster, ipq, qterm, rowterm,
+            b=B, k=100, metric="l2", compute_dtype=f32,
+        )
+
+    names = _kernels(_compile(lists_topk, *args))
+    assert len(names) == 1 and re.fullmatch(
+        r"ash_score_gather_topk_pallas(\.\d+)?", names[0]), names
+
+
 @pytest.mark.parametrize("m", (8, 128))
 def test_dense_fused_topk_memory_at_10m_rows(spec, m):
     """Temporaries stay within twice the codes plus the logical
@@ -261,3 +296,29 @@ def test_served_programs_keep_kernel_names_and_carry_scopes(served_programs):
     seen = set().union(*(_op_scopes(t) for t in served_programs.values()))
     assert seen == SCOPES
     assert "ash.gather" in _op_scopes(served_programs["ivf"])
+
+
+def test_served_ivf_program_gathers_no_payload_rows(served_programs):
+    """The IVF program reads the probed lists in the kernel: no XLA
+    gather takes the (n, W) codes, and every gather over an (n,) row
+    column — the ids, for the top-k's rows — yields at most a shortlist
+    per query, never a row per candidate slot."""
+    text = served_programs["ivf"]
+    shapes = {
+        name: (dt, tuple(int(x) for x in dims.split(",") if x))
+        for name, dt, dims in re.findall(
+            r"%(\S+) = (\w+)\[([\d,]*)\]", text)
+    }
+    n, W_ivf = 4096, 4  # the fixture's corpus, d=64 at b=2
+    shortlist = 8 * 100  # m x rerank
+    gathers = re.findall(
+        r"= \w+\[([\d,]*)\]\S* gather\(%([^,\s)]+)", text)
+    assert gathers
+    for out_dims, operand in gathers:
+        dt, dims = shapes[operand]
+        out = 1
+        for x in (int(v) for v in out_dims.split(",") if v):
+            out *= x
+        assert dims != (n, W_ivf), (operand, dt, dims)
+        if dims == (n,):
+            assert out <= shortlist, (operand, dt, dims, out_dims)
