@@ -148,10 +148,9 @@ def serving_engine():
     # actually lives: closed-loop clients each submit a 1-row request
     # and block on it.  The direct baseline pays per-request dispatch,
     # prep and its own gather, and the callers serialize; the frontend
-    # driver batches concurrent arrivals into fused calls and serves
-    # repeated queries out of the prep LRU.  Clients draw from the Qm
-    # pool — a hot set of 256 queries, the query-repetition shape real
-    # traffic has.  Hot-set probes share lists heavily, so the union
+    # driver batches concurrent arrivals into fused calls.  Clients
+    # draw from the Qm pool — a hot set of 256 queries, the
+    # query-repetition shape real traffic has.  Hot-set probes share lists heavily, so the union
     # bill grows slowly with group size: row_budget at 0.5n sits
     # above the hot-pool union at both costed rungs (~0.39n for an
     # 8-group, ~0.44n for a 16-group) — correlated traffic rides the
@@ -222,21 +221,6 @@ def serving_engine():
             f"clients={c}" + extra,
         ))
 
-    # prep-cache effect: identical query stream served twice; hit rate
-    # is measured over the warm pass only (counters are cumulative)
-    engine = QueryEngine(index, batch_buckets=(32,), max_wait_s=0.005)
-    _stream_through(engine, Qm, reqs, 10, None)
-    hits0, miss0 = engine.stats.prep_hits, engine.stats.prep_misses
-    t0 = time.perf_counter()
-    _stream_through(engine, Qm, reqs, 10, None)
-    dt = time.perf_counter() - t0
-    hits = engine.stats.prep_hits - hits0
-    misses = engine.stats.prep_misses - miss0
-    hit_rate = hits / max(1, hits + misses)
-    rows.append(row(
-        "serving/engine_flat_warm_cache", 1e6 * dt / len(reqs),
-        f"qps={n_rows / dt:.0f};prep_hit_rate={hit_rate:.2f}",
-    ))
     return rows
 
 

@@ -39,8 +39,8 @@ def main():
 
     for nprobe in (4, 16, 64):
         # untimed warmup pass compiles every bucket trace this stream
-        # will hit (throwaway engine so the timed pass starts cold on
-        # the prep cache too)
+        # will hit (throwaway engine so the timed pass starts with
+        # fresh stats)
         warm = QueryEngine(index, batch_buckets=(8, 32),
                            max_wait_s=0.002)
         for q in queries:

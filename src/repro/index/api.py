@@ -995,8 +995,9 @@ class AshIndex:
     def prepare(self, queries: jax.Array) -> QueryPrep:
         """Precompute the QUERY-COMPUTE projections (Eq. 20) for
         ``queries``; feed to :meth:`search_prepped`.  Row i of the prep
-        depends only on row i of ``queries``, so prep rows are cacheable
-        and batchable across requests (the serving engine does both)."""
+        depends only on row i of ``queries``, so prep rows are batchable
+        across requests (the serving engine prepares each padded bucket
+        once)."""
         return S.prepare_queries(self.model, queries)
 
     def search_prepped(

@@ -252,8 +252,8 @@ def make_sharded_search_prepped(
     """Like :func:`make_sharded_search` but takes a precomputed
     ``QueryPrep`` (replicated) instead of raw queries, so the
     QUERY-COMPUTE projections run once on the host instead of
-    redundantly on every shard — and so the serving engine's prep cache
-    can feed this backend too."""
+    redundantly on every shard — and so the serving engine's prepared
+    buckets can feed this backend too."""
     return _make_searcher(
         mesh, model, axes, k, metric=metric, n_real=n_real,
         from_prep=True, rerank=rerank, fused=fused,

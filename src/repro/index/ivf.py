@@ -464,7 +464,7 @@ def _search(
     shortlist: Optional[int] = None,
 ) -> tuple[jax.Array, jax.Array]:
     """Composition of ``prepare_queries`` and :func:`_search_prepped`,
-    so engine (prep-cached) and direct paths share compiled arithmetic."""
+    so engine (prepared) and direct paths share compiled arithmetic."""
     prep = S.prepare_queries(index.model, queries)
     return _search_prepped(
         index, prep, k=k, nprobe=nprobe, rerank=rerank,

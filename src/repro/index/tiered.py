@@ -11,8 +11,8 @@ per-list in host memory, sliced along the contiguous-list row order
 A search lowers through ``common.plan_paged_probe``: resolve the probe
 set (the same coarse top-k expression the HBM backend jits, run as its
 own tiny jit so the probed lists are host-visible), look each probed
-list up in the device-resident block cache (the shared
-:class:`repro.serving.cache.ByteLRU`), batch all misses into ONE
+list up in the device-resident block cache (a byte-bounded
+:class:`ByteLRU`), batch all misses into ONE
 host→device transfer, and concatenate the resident blocks into an
 ascending-list union ``IVFIndex`` whose inverted lists are rebased to
 union-local rows.  Scoring then calls the SAME jitted entry points the
@@ -46,6 +46,8 @@ in-graph exactly like the HBM backend masks them.
 from __future__ import annotations
 
 import functools
+from collections import OrderedDict
+from typing import Any, Dict, Iterator
 
 import jax
 import jax.numpy as jnp
@@ -60,7 +62,6 @@ from repro.index import ivf as IV
 from repro.index.api import (
     IVFBackend, _model_arrays, register_backend,
 )
-from repro.serving.cache import ByteLRU
 
 DEFAULT_HOT_BYTES = 64 << 20
 
@@ -69,6 +70,93 @@ _FIELDS = (
     "codes", "scale", "offset", "cluster",
     "res_norm", "ip_x_mu", "x_sq", "cvalues", "ids",
 )
+
+
+def _default_nbytes(value: Any) -> int:
+    """Byte size of a cached value: a single array-like, or any
+    tuple/list/dict of array-likes (anything exposing ``.nbytes``)."""
+    if hasattr(value, "nbytes"):
+        return int(value.nbytes)
+    if isinstance(value, dict):
+        value = value.values()
+    return sum(_default_nbytes(v) for v in value)
+
+
+class ByteLRU:
+    """LRU mapping hashable keys to values under a byte budget: the
+    device-resident hot set of inverted-list blocks.
+
+    ``max_bytes`` bounds the summed size of cached values (summed
+    ``.nbytes`` over each value's arrays).  A value larger than the
+    whole budget is admitted and immediately evicted — ``put`` never
+    raises, a zero-byte budget simply caches nothing (every lookup
+    misses, which is exactly the cold-cache semantics the paging tests
+    rely on).
+
+    ``hits`` / ``misses`` / ``evictions`` count ``get`` outcomes and
+    evicted entries for ``tier_stats``.  Not internally locked: the
+    tiered backend serializes access through the per-index mutation
+    barrier.
+    """
+
+    def __init__(self, max_bytes: int):
+        self.max_bytes = int(max_bytes)
+        self._data: "OrderedDict[Any, Any]" = OrderedDict()
+        self._sizes: Dict[Any, int] = {}
+        self.nbytes = 0
+        self.hits = 0
+        self.misses = 0
+        self.evictions = 0
+
+    def __len__(self) -> int:
+        return len(self._data)
+
+    def keys(self) -> Iterator:
+        return iter(self._data.keys())
+
+    def get(self, key, default=None):
+        """Look up ``key``; a hit refreshes its recency."""
+        entry = self._data.get(key)
+        if entry is None:
+            self.misses += 1
+            return default
+        self._data.move_to_end(key)
+        self.hits += 1
+        return entry
+
+    def put(self, key, value) -> None:
+        """Insert or replace ``key``, then evict LRU-first until the
+        budget holds."""
+        old = self._data.pop(key, None)
+        if old is not None:
+            self.nbytes -= self._sizes.pop(key)
+        size = _default_nbytes(value)
+        self._data[key] = value
+        self._sizes[key] = size
+        self.nbytes += size
+        while self._data and self.nbytes > self.max_bytes:
+            key, _ = self._data.popitem(last=False)
+            self.nbytes -= self._sizes.pop(key)
+            self.evictions += 1
+
+    def clear(self) -> None:
+        """Drop every entry (counters keep their lifetime values)."""
+        self._data.clear()
+        self._sizes.clear()
+        self.nbytes = 0
+
+    def stats(self) -> Dict[str, int]:
+        """Gauge snapshot (counters are lifetime, not interval)."""
+        total = self.hits + self.misses
+        return {
+            "entries": len(self._data),
+            "nbytes": self.nbytes,
+            "max_bytes": self.max_bytes,
+            "hits": self.hits,
+            "misses": self.misses,
+            "hit_rate": self.hits / total if total else 0.0,
+            "evictions": self.evictions,
+        }
 
 
 class TieredState:

@@ -6,7 +6,7 @@ paper's end-to-end scenario.
       --bits 2 --reduce 2 --landmarks 64 --queries 1000 --req-batch 8
 
 Requests of ``--req-batch`` rows stream through a ``QueryEngine``
-(flush-on-size/timeout, bucketed jit traces, prep cache); the launcher
+(flush-on-size/timeout, bucketed jit traces); the launcher
 reports build time, QPS, p50/p99 request latency, engine stats, and
 10-recall@{10,100} against exact ground truth.  ``--engine ivf`` serves
 through the inverted-file index with coarse routing (the paper's Fig. 9
@@ -50,14 +50,10 @@ def _device_label() -> str:
 
 
 def _print_engine_report(engine, mut_tickets=()):
-    """The shared observability block: engine snapshot, prep cache,
-    flush-reason mix, queue/compaction telemetry."""
+    """The shared observability block: engine snapshot, flush-reason
+    mix, queue/compaction telemetry."""
     snap = engine.stats.snapshot()
     print(f"[engine] {snap}")
-    print(f"[prep-cache] hit_rate={snap['prep_hit_rate']:.3f} "
-          f"({snap['prep_hits']}/{snap['prep_hits'] + snap['prep_misses']} "
-          f"rows) resident={engine.prep_cache_bytes / 1024:.1f}KiB "
-          f"budget={engine.config.prep_cache_bytes / 2**20:.0f}MiB")
     reasons = ", ".join(
         f"{r}={c}" for r, c in snap["flushes"].items() if c
     )
@@ -433,9 +429,9 @@ def main(argv=None):
 
     # warmup on a throwaway engine: compile EVERY bucket shape the
     # stream can hit (steady-state size flushes AND whatever bucket the
-    # final remainder pads to) without pre-warming the timed engine's
-    # prep cache or polluting its stats — a trace compiled inside the
-    # timed window would be charged to QPS/p99
+    # final remainder pads to) without polluting the timed engine's
+    # stats — a trace compiled inside the timed window would be charged
+    # to QPS/p99
     warm = QueryEngine(
         index, batch_buckets=buckets,
         max_wait_s=args.max_wait_ms / 1e3,

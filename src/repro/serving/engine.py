@@ -35,13 +35,9 @@ Mechanics:
   "drain").  Flushes triggered inside ``submit`` never raise — a
   failing fused call resolves every affected ticket with the error,
   re-raised by that ticket's ``result()``.
-* **Prep cache** — per-query-row LRU over the QUERY-COMPUTE projections
-  (``prepare_queries``): repeated queries skip the projection matmuls
-  entirely.  Keyed by (index name, query-row hash); row preps are exact,
-  so cache hits stay bit-identical.  Byte-bounded
-  (``prep_cache_bytes``; ``prep_cache_entries`` as an optional extra
-  row bound), with the live footprint on ``engine.prep_cache_bytes``
-  and the hit rate in ``engine.stats.snapshot()``.
+* **Prep** — each fused call prepares its padded bucket once
+  (``AshIndex.prepare``: the query-side projections) on the device,
+  then searches the prepared bucket.
 * **Registry** — one engine fronts several ``AshIndex`` backends (flat,
   IVF, sharded) for tenant/namespace routing via ``index=``.
 * **k > n** — clamped to the index size and padded back out with score
@@ -71,8 +67,8 @@ Threading model
 The engine core is thread-safe.  The lock discipline has two tiers:
 
 * ``self._lock`` — a global re-entrant lock over the cheap shared
-  state: the request queue, mutation bookkeeping, the prep LRU and the
-  stats counters.  ``submit``/``submit_add``/``submit_delete`` only
+  state: the request queue, mutation bookkeeping and the stats
+  counters.  ``submit``/``submit_add``/``submit_delete`` only
   ever hold this lock (submission is cheap and never blocks behind a
   fused call).
 * per-index execution locks (``mutation_barrier(name)``) — ONE fused
@@ -121,11 +117,8 @@ span                        times (metadata)
                             sequence number that the call's tickets
                             carry as ``RequestStats.call_id``; rows,
                             bucket, k, nprobe — 0 for a dense scan)
-``ash.prep``                query prep for the bucket (hits, misses)
-``ash.prep.hash``           row keys and prep-cache lookup
-``ash.prep.device``         ``prepare`` on the device, to ready
-``ash.prep.fill``           device-to-host copies and cache puts
-``ash.prep.stack``          host stack of cached rows, one device put
+``ash.prep``                ``prepare`` of the bucket on the device,
+                            to ready (rows)
 ``ash.search.dispatch``     ``search_prepped`` until it returns
 ``ash.search.wait``         waiting for its result to be ready
 ``ash.fetch``               copying scores and ids to the host
@@ -155,7 +148,6 @@ the xprof / TensorBoard trace viewer::
 from __future__ import annotations
 
 import dataclasses
-import hashlib
 import itertools
 import threading
 import time
@@ -166,9 +158,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.types import QueryPrep
 from repro.index.api import AshIndex, IVFBackend
-from repro.serving.cache import ByteLRU
 from repro.testing import faults
 
 NEG_INF = float("-inf")
@@ -197,13 +187,6 @@ class EngineConfig:
     the largest bucket round up to a multiple of it (so shapes stay a
     closed set and traces stay bounded).
 
-    The prep cache is BYTE-bounded (``prep_cache_bytes``, summing the
-    numpy footprint of every cached row's projection tuple) so capacity
-    planning works in memory terms regardless of query width;
-    ``prep_cache_entries`` is an optional additional row-count bound
-    (None = rows limited by bytes only).  Setting either to 0 disables
-    the cache.
-
     ``row_budget`` / ``nprobe_min`` are the IVF tail-latency knobs.
     ``row_budget`` caps the deduped candidate-row bill (union of live
     rows across the probed lists of every query in a fused call) of
@@ -226,8 +209,6 @@ class EngineConfig:
     k_buckets: Tuple[int, ...] = (10, 100)
     max_pending: int = 1024  # queue bound, in query rows
     max_wait_s: float = 0.002  # flush-on-timeout age
-    prep_cache_bytes: int = 64 << 20  # LRU byte budget; 0 disables
-    prep_cache_entries: Optional[int] = None  # extra row bound; 0 disables
     # IVF cost model: candidate-row bill cap per fused call (None = off)
     row_budget: Optional[int] = None
     # relative cost of one candidate row under the int8 coarse first
@@ -266,14 +247,6 @@ class EngineConfig:
             v = getattr(self, name)
             if tuple(sorted(v)) != tuple(v) or min(v) < 1:
                 raise ValueError(f"{name} must be ascending positive: {v}")
-        if self.prep_cache_bytes < 0:
-            raise ValueError(
-                f"prep_cache_bytes must be >= 0: {self.prep_cache_bytes}"
-            )
-        if self.prep_cache_entries is not None and self.prep_cache_entries < 0:
-            raise ValueError(
-                f"prep_cache_entries must be >= 0: {self.prep_cache_entries}"
-            )
         if self.max_pending_mutations < 1:
             raise ValueError(
                 f"max_pending_mutations must be >= 1: "
@@ -306,10 +279,6 @@ class EngineConfig:
             raise ValueError(
                 f"pressure_age_s must be > 0: {self.pressure_age_s}"
             )
-
-    @property
-    def prep_cache_enabled(self) -> bool:
-        return self.prep_cache_bytes > 0 and self.prep_cache_entries != 0
 
 
 def _bucketize(buckets: Tuple[int, ...], n: int) -> int:
@@ -344,8 +313,6 @@ class RequestStats:
     # the fused call that served this request: the ``call`` of its
     # ash.call span, an engine-wide sequence number from 1
     call_id: int = 0
-    prep_hits: int = 0  # this request's rows found in the prep cache
-    prep_misses: int = 0
     # "size" | "budget" (the group's deduped candidate-row bill hit
     # EngineConfig.row_budget) | "timeout" | "deadline" | "manual" |
     # "pressure" | "barrier" (the group was flushed because a mutation
@@ -380,8 +347,6 @@ class EngineStats:
     batches: int = 0  # fused scoring calls
     batched_rows: int = 0  # real rows served
     padded_rows: int = 0  # zero rows added by bucketing
-    prep_hits: int = 0
-    prep_misses: int = 0
     mutations: int = 0  # submit_add/submit_delete calls
     added_rows: int = 0  # rows ingested via applied mutation batches
     deleted_rows: int = 0  # rows tombstoned via applied batches
@@ -437,15 +402,15 @@ class EngineStats:
         fill = self.batched_rows / max(
             1, self.batched_rows + self.padded_rows
         )
-        looked_up = self.prep_hits + self.prep_misses
         snap = {
             "requests": self.requests,
             "batches": self.batches,
             "rows": self.batched_rows,
             "bucket_fill": round(fill, 3),
-            "prep_hits": self.prep_hits,
-            "prep_misses": self.prep_misses,
-            "prep_hit_rate": round(self.prep_hits / max(1, looked_up), 3),
+            # kept for bench/harness.py, which reads both keys: every
+            # row is prepared, none is looked up in a cache
+            "prep_hits": 0,
+            "prep_misses": self.batched_rows,
             "mutations": self.mutations,
             "added_rows": self.added_rows,
             "deleted_rows": self.deleted_rows,
@@ -673,11 +638,6 @@ class QueryEngine:
         self._indexes: Dict[str, AshIndex] = {}
         self._pending: "OrderedDict[tuple, list[_Request]]" = OrderedDict()
         self._pending_rows = 0
-        self._prep_cache = ByteLRU(
-            config.prep_cache_bytes,
-            max_entries=config.prep_cache_entries,
-            nbytes_of=self._entry_nbytes,
-        )
         # queued mutations, per index: add tickets (rows already staged
         # on the AshIndex), delete id lists, and the oldest submission
         # time (drives the poll() age check)
@@ -689,13 +649,6 @@ class QueryEngine:
         # live list sizes
         self._coarse_parts: Dict[str, tuple] = {}
         self._list_sizes: Dict[str, tuple] = {}
-        # (name, row digest) -> full best-first list order.  Coarse
-        # assignment depends only on the landmarks (fixed per binding;
-        # mutations never move them), so repeated queries skip the
-        # host matmul+argsort entirely; storing the FULL order makes
-        # hits nprobe-independent (a degraded probe reads a prefix)
-        self._probe_orders: "OrderedDict[tuple, np.ndarray]" = \
-            OrderedDict()
         # per-group running bill: group -> (mutation epoch, probed-list
         # mask, billed live rows).  submit() folds each new probe in
         # incrementally so the budget check stays O(nprobe) per request
@@ -725,8 +678,7 @@ class QueryEngine:
 
     def register(self, name: str, index: AshIndex) -> "QueryEngine":
         """Route ``submit(..., index=name)`` to ``index``.  Re-binding a
-        name drops its cached preps (a new index means a new model) and
-        first applies any queued mutations against the OLD binding —
+        name first applies any queued mutations against the OLD binding —
         their rows are already staged on that index, so erroring the
         tickets would strand rows that the old index still ingests on
         its next ``apply_pending``.  An apply failure lands on the
@@ -739,13 +691,10 @@ class QueryEngine:
                 self._index_locks[name] = threading.RLock()
         if rebind:
             self._try_flush(self._apply_mutations, name)
-            self.invalidate_prep_cache(name)
         with self._lock:
             self._indexes[name] = index
             self._coarse_parts.pop(name, None)
             self._list_sizes.pop(name, None)
-            for key in [k for k in self._probe_orders if k[0] == name]:
-                del self._probe_orders[key]
             for g in [g for g in self._group_bills if g[0] == name]:
                 del self._group_bills[g]
         return self
@@ -791,21 +740,6 @@ class QueryEngine:
             if lock is None:
                 lock = self._index_locks[name] = threading.RLock()
             return lock
-
-    def invalidate_prep_cache(self, name: Optional[str] = None) -> None:
-        with self._lock:
-            if name is None:
-                self._prep_cache.clear()
-                return
-            for key in [k for k in self._prep_cache.keys()
-                        if k[0] == name]:
-                self._prep_cache.pop(key)
-
-    @property
-    def prep_cache_bytes(self) -> int:
-        """Current byte footprint of the prep LRU (for capacity
-        planning against ``EngineConfig.prep_cache_bytes``)."""
-        return self._prep_cache.nbytes
 
     # -- IVF candidate-row cost model ---------------------------------
 
@@ -871,18 +805,7 @@ class QueryEngine:
         """Approximate coarse assignment, host numpy: (m, nprobe) list
         ids, best-first (so a degraded nprobe reads a column prefix).
         Matches the in-jit routing up to matmul summation order —
-        plenty for row accounting, and never touched by execution.
-        Single-row probes (the dominant serving shape) are served from
-        a per-query LRU of full list orders when the traffic repeats."""
-        pkey = None
-        if q.shape[0] == 1:
-            pkey = (name, hashlib.blake2b(
-                q.tobytes(), digest_size=16).digest())
-            with self._lock:
-                order = self._probe_orders.get(pkey)
-                if order is not None:
-                    self._probe_orders.move_to_end(pkey)
-                    return order[None, :nprobe]
+        plenty for row accounting, and never touched by execution."""
         with self._lock:
             parts = self._coarse_parts.get(name)
         if parts is None:
@@ -898,17 +821,6 @@ class QueryEngine:
                 self._coarse_parts[name] = parts
         lm_t, half = parts
         coarse = q @ lm_t - half[None, :]
-        if pkey is not None:
-            # single-row fast path: a full argsort of one nlist-sized
-            # row beats partition + gather, and caching the whole
-            # order serves any later nprobe as a prefix
-            order = np.argsort(-coarse[0], kind="stable").astype(
-                np.int32)
-            with self._lock:
-                self._probe_orders[pkey] = order
-                while len(self._probe_orders) > 8192:
-                    self._probe_orders.popitem(last=False)
-            return order[None, :nprobe]
         if nprobe >= coarse.shape[1]:
             order = np.argsort(-coarse, axis=1, kind="stable")
             return order[:, :nprobe].astype(np.int32)
@@ -1768,12 +1680,10 @@ class QueryEngine:
                     nprobe=nprobe or 0,
                 )
 
-                with _span("ash.prep") as prep_span:
-                    prep, hit_rows = self._prep_for(
-                        name, idx, rows, n_real
+                with _span("ash.prep", rows=n_real):
+                    prep = jax.block_until_ready(
+                        idx.prepare(jnp.asarray(rows))
                     )
-                    hits = int(hit_rows.sum())
-                    prep_span.set_metadata(hits=hits, misses=n_real - hits)
                 # after prep: the stat is the fused call
                 t_score = time.perf_counter()
                 with _span("ash.search.dispatch"):
@@ -1831,8 +1741,6 @@ class QueryEngine:
                     st.bucket_rows = bucket
                     st.scoring_us = scoring_us
                     st.call_id = call_id
-                    st.prep_hits = int(hit_rows[offset:offset + m].sum())
-                    st.prep_misses = m - st.prep_hits
                     st.flush_reason = reason
                     if r.probe is not None and nprobe is not None:
                         st.effective_nprobe = nprobe
@@ -1845,95 +1753,3 @@ class QueryEngine:
         if missed:
             with self._lock:
                 self.stats.deadline_missed += missed
-
-    # -- prep cache ---------------------------------------------------
-
-    def _prep_for(
-        self, name: str, idx: AshIndex, rows: np.ndarray, n_real: int
-    ) -> Tuple[QueryPrep, np.ndarray]:
-        """QueryPrep for the padded bucket ``rows``, reusing cached
-        per-row projections.  Returns (prep, per-row hit flags for the
-        real rows)."""
-        bucket = rows.shape[0]
-        hit_rows = np.zeros(n_real, dtype=bool)
-        if not self.config.prep_cache_enabled:
-            with self._lock:
-                self.stats.prep_misses += n_real
-            with _span("ash.prep.device"):
-                return idx.prepare(jnp.asarray(rows)), hit_rows
-
-        with _span("ash.prep.hash"):
-            keys = [
-                (name, hashlib.blake2b(rows[i].tobytes(),
-                                       digest_size=16).digest())
-                for i in range(bucket)
-            ]
-            row_preps: list = [None] * bucket
-            miss = []
-            with self._lock:
-                for i, key in enumerate(keys):
-                    cached = self._prep_cache.get(key)
-                    if cached is not None:
-                        row_preps[i] = cached
-                        if i < n_real:
-                            hit_rows[i] = True
-                    else:
-                        miss.append(i)
-                self.stats.prep_hits += int(hit_rows.sum())
-                self.stats.prep_misses += n_real - int(hit_rows.sum())
-
-        if not miss:
-            with _span("ash.prep.stack"):
-                return self._stack_prep(row_preps), hit_rows
-        if len(miss) == bucket:
-            # cold bucket: one prepare over the padded rows, no restack
-            # (only real rows are cached — pad rows recur only while
-            # buckets run underfilled and would waste LRU capacity)
-            with _span("ash.prep.device"):
-                prep = jax.block_until_ready(
-                    idx.prepare(jnp.asarray(rows))
-                )
-            with _span("ash.prep.fill"):
-                self._cache_prep_rows(keys, prep, range(n_real))
-            return prep, hit_rows
-        # warm bucket: prepare only the misses (padded to a bucket shape
-        # so prepare traces stay bounded), then merge with cached rows
-        mb = _bucketize(self.config.batch_buckets, len(miss))
-        miss_rows = _pad_rows(rows[miss], mb)
-        with _span("ash.prep.device"):
-            mp = jax.block_until_ready(idx.prepare(jnp.asarray(miss_rows)))
-        with _span("ash.prep.fill"):
-            mp_np = tuple(np.asarray(a) for a in
-                          (mp.q, mp.q_proj, mp.ip_q_landmarks,
-                           mp.q_sq_norm))
-            for j, i in enumerate(miss):
-                row_preps[i] = tuple(a[j] for a in mp_np)
-            with self._lock:
-                for i in miss:
-                    if i < n_real:
-                        self._prep_cache.put(keys[i], row_preps[i])
-        with _span("ash.prep.stack"):
-            return self._stack_prep(row_preps), hit_rows
-
-    def _cache_prep_rows(self, keys, prep: QueryPrep, idxs) -> None:
-        arrs = tuple(np.asarray(a) for a in
-                     (prep.q, prep.q_proj, prep.ip_q_landmarks,
-                      prep.q_sq_norm))
-        with self._lock:
-            for i in idxs:
-                self._prep_cache.put(keys[i], tuple(a[i] for a in arrs))
-
-    @staticmethod
-    def _entry_nbytes(entry: tuple) -> int:
-        return sum(int(a.nbytes) for a in entry)
-
-    @staticmethod
-    def _stack_prep(row_preps) -> QueryPrep:
-        # stack on host, then one device_put for all four fields — four
-        # separate jnp.asarray dispatches dominate small-bucket flushes
-        q, q_proj, ipl, qsq = jax.device_put(tuple(
-            np.stack([r[f] for r in row_preps]) for f in range(4)
-        ))
-        return QueryPrep(
-            q=q, q_proj=q_proj, ip_q_landmarks=ipl, q_sq_norm=qsq
-        )

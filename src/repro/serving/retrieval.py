@@ -9,8 +9,7 @@ scoring matmul reads packed codes only.
 
 Requests route through the micro-batching :class:`QueryEngine`
 (``repro.serving.engine``): one engine per index (cached here), so
-repeated user vectors hit the prep cache and request shapes collapse
-onto the engine's bucketed jit traces.
+request shapes collapse onto the engine's bucketed jit traces.
 """
 from __future__ import annotations
 

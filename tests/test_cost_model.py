@@ -19,6 +19,7 @@ gauge, config validation, and the "budget" flush reason.
 import time
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -26,6 +27,7 @@ from _hypothesis_compat import given, st
 from repro.core import ASHConfig
 from repro.data.synthetic import embedding_dataset
 from repro.index import AshIndex
+from repro.index.api import IVFBackend
 from repro.serving.engine import EngineConfig, QueryEngine, _Request
 
 N = 2500
@@ -264,39 +266,27 @@ def test_effective_nprobe_ladder(setup):
     assert off._effective_nprobe(8, 1.0) == 8
 
 
-def test_probe_order_lru(setup):
-    """Single-row probes are served from a per-query LRU of full list
-    orders: a repeat hit returns the same lists as the cold path, a
-    smaller nprobe reads a prefix of the cached order, rebinding the
-    index name invalidates its entries, and the cache stays bounded."""
+@pytest.mark.parametrize("nprobe", (1, 4))
+@pytest.mark.parametrize("m", (1, 6))
+def test_host_probe_matches_device_probe(setup, m, nprobe):
+    """The engine's one host probe path gives, row by row and
+    best-first, the lists ``IVFBackend.probe_sets`` computes on the
+    device, and its nprobe columns are the prefix of the full order
+    (what a degraded nprobe reads)."""
     index, Qm = setup
     engine = _engine(setup, row_budget=N)
     name = engine.index_names[0]
-    q = np.ascontiguousarray(Qm[:1])
+    q = np.ascontiguousarray(Qm[:m])
 
-    cold = engine._host_probe(name, index, q, 4)
-    assert len(engine._probe_orders) == 1
-    hot = engine._host_probe(name, index, q, 4)
-    np.testing.assert_array_equal(cold, hot)
-    assert len(engine._probe_orders) == 1  # a hit, not a new entry
-    # the cache stores the FULL order, so any later nprobe is a prefix
-    np.testing.assert_array_equal(
-        engine._host_probe(name, index, q, 2), cold[:, :2]
+    host = engine._host_probe(name, index, q, nprobe)
+    assert host.shape == (m, nprobe) and host.dtype == np.int32
+    device = IVFBackend.probe_sets(
+        index._state, index.prepare(jnp.asarray(q)), nprobe
     )
-    # and it agrees with the uncached multi-row path
-    multi = engine._host_probe(name, index, np.repeat(q, 2, axis=0), 4)
-    np.testing.assert_array_equal(multi[0], cold[0])
-
-    # rebinding a name drops its cached orders (new landmarks)
-    engine.register(name, index)
-    assert len(engine._probe_orders) == 0
-
-    # bounded: at the cap, each insert evicts the least-recent entry
-    for j in range(8192):
-        engine._probe_orders[("other", j)] = np.arange(1, dtype=np.int32)
-    engine._host_probe(name, index, q, 4)
-    assert len(engine._probe_orders) == 8192
-    assert ("other", 0) not in engine._probe_orders
+    np.testing.assert_array_equal(host, device)
+    full = engine._host_probe(name, index, q, NLIST)
+    np.testing.assert_array_equal(full[:, :nprobe], host)
+    assert all(sorted(row) == list(range(NLIST)) for row in full)
 
 
 def test_queue_pressure_gauge(setup):

@@ -1,5 +1,5 @@
 """Micro-batching QueryEngine: bit-identical parity vs direct search,
-bucket/flush semantics, prep-cache accounting, trace reuse."""
+bucket/flush semantics, one query prep per fused call, trace reuse."""
 import jax
 import jax.numpy as jnp
 import numpy as onp
@@ -43,12 +43,12 @@ def _engine(indexes, **kw):
 @pytest.mark.parametrize("backend", BACKENDS)
 def test_parity_bit_identical(setup, backend):
     """Batched+padded engine results == per-request direct search,
-    bit-for-bit (scores AND ids), cold and warm prep cache."""
+    bit-for-bit (scores AND ids), for new rows and repeated rows."""
     X, Qm, indexes = setup
     idx = indexes[backend]
     kw = {"nprobe": 4} if backend == "ivf" else {}
     eng = _engine({backend: idx})
-    for round_ in range(2):  # round 2 serves fully from the prep cache
+    for round_ in range(2):  # round 2 repeats round 1's rows
         sizes = [1, 3, 2, 5, 1]
         offs = onp.cumsum([0] + sizes)
         tickets = [
@@ -61,7 +61,6 @@ def test_parity_bit_identical(setup, backend):
             ds, di = idx.search(Qm[offs[i]:offs[i + 1]], k=7, **kw)
             assert jnp.array_equal(jnp.asarray(s), ds), (backend, round_, i)
             assert jnp.array_equal(jnp.asarray(ids), di), (backend, round_, i)
-    assert eng.stats.prep_hits > 0  # round 2 actually hit the cache
 
 
 @pytest.mark.parametrize("backend", ("flat", "ivf"))
@@ -196,104 +195,53 @@ def test_bounded_queue_applies_backpressure(setup):
     assert t3.stats.flush_reason == "manual"
 
 
-def test_prep_cache_hit_miss_counts(setup):
+@pytest.mark.parametrize("n", (3, 20, 100))
+@pytest.mark.parametrize("backend", ("flat", "ivf"))
+def test_one_prepare_per_call(setup, monkeypatch, backend, n):
+    """Each fused call prepares its padded bucket once, as one (bucket,
+    D) block, and serves results bit-identical to direct search."""
     X, Qm, indexes = setup
-    eng = _engine({"flat": indexes["flat"]}, batch_buckets=(4,))
-    t1 = eng.submit(Qm[:2], k=5, index="flat")
+    idx = indexes[backend]
+    kw = {"nprobe": 4} if backend == "ivf" else {}
+    buckets = (8, 32, 128)
+    eng = _engine({backend: idx}, batch_buckets=buckets)
+    shapes = []
+    real_prepare = idx.prepare
+
+    def prepare(queries):
+        shapes.append(tuple(queries.shape))
+        return real_prepare(queries)
+
+    monkeypatch.setattr(idx, "prepare", prepare)
+    Q = onp.random.default_rng(n).standard_normal(
+        (n, X.shape[1])).astype(onp.float32)
+    offs = list(range(0, n, 7)) + [n]
+    tickets = [eng.submit(Q[a:b], k=7, index=backend, **kw)
+               for a, b in zip(offs, offs[1:])]
     eng.flush()
-    assert t1.stats.prep_hits == 0 and t1.stats.prep_misses == 2
-    t2 = eng.submit(Qm[:2], k=5, index="flat")  # identical rows
-    t3 = eng.submit(Qm[2:3], k=5, index="flat")  # fresh row
-    eng.flush()
-    assert t2.stats.prep_hits == 2 and t2.stats.prep_misses == 0
-    assert t3.stats.prep_hits == 0 and t3.stats.prep_misses == 1
-    assert eng.stats.prep_hits == 2
-    assert eng.stats.prep_misses == 3
-    # results served off cached preps are still exact
-    assert jnp.array_equal(
-        jnp.asarray(t2.result()[1]), jnp.asarray(t1.result()[1])
-    )
+    bucket = next(b for b in buckets if n <= b)
+    assert eng.stats.batches == 1
+    assert shapes == [(bucket, X.shape[1])]
+    for (a, b), t in zip(zip(offs, offs[1:]), tickets):
+        s, ids = t.result()
+        ds, di = idx.search(Q[a:b], k=7, **kw)
+        assert jnp.array_equal(jnp.asarray(s), ds), (backend, n, a)
+        assert jnp.array_equal(jnp.asarray(ids), di), (backend, n, a)
 
 
-def test_prep_cache_disabled_and_eviction(setup):
-    X, Qm, indexes = setup
-    eng = _engine({"flat": indexes["flat"]}, prep_cache_entries=0)
-    eng.search(Qm[:2], k=5, index="flat")
-    eng.search(Qm[:2], k=5, index="flat")
-    assert eng.stats.prep_hits == 0 and eng.stats.prep_misses == 4
-    eng = _engine({"flat": indexes["flat"]}, batch_buckets=(4,),
-                  prep_cache_entries=2)
-    eng.search(Qm[:4], k=5, index="flat")
-    assert len(eng._prep_cache) == 2  # LRU evicted down to the bound
-
-
-def test_prep_cache_byte_bound_evicts(setup):
-    """The LRU is byte-bounded: inserts beyond prep_cache_bytes evict
-    oldest rows, and the live footprint is exposed for capacity
-    planning."""
-    X, Qm, indexes = setup
-    eng = _engine({"flat": indexes["flat"]}, batch_buckets=(4,))
-    eng.search(Qm[:1], k=5, index="flat")
-    per_row = eng.prep_cache_bytes
-    assert per_row > 0
-    # budget for exactly 2 rows: the third insert evicts the oldest
-    eng = _engine({"flat": indexes["flat"]}, batch_buckets=(4,),
-                  prep_cache_bytes=2 * per_row)
-    eng.search(Qm[:4], k=5, index="flat")
-    assert len(eng._prep_cache) == 2
-    assert eng.prep_cache_bytes == 2 * per_row
-    # eviction keeps the most-recent rows: Qm[2:4] now hit, Qm[0] misses
-    eng.search(Qm[2:4], k=5, index="flat")
-    assert eng.stats.prep_hits == 2
-    eng.search(Qm[:1], k=5, index="flat")
-    assert eng.stats.prep_hits == 2  # Qm[0] was evicted
-    # results served under eviction pressure stay exact
-    s, ids = eng.search(Qm[:4], k=5, index="flat")
-    ds, di = indexes["flat"].search(Qm[:4], k=5)
-    assert jnp.array_equal(jnp.asarray(ids), di)
-
-
-def test_prep_cache_bytes_zero_disables(setup):
-    X, Qm, indexes = setup
-    eng = _engine({"flat": indexes["flat"]}, prep_cache_bytes=0)
-    eng.search(Qm[:2], k=5, index="flat")
-    eng.search(Qm[:2], k=5, index="flat")
-    assert eng.stats.prep_hits == 0 and eng.stats.prep_misses == 4
-    assert eng.prep_cache_bytes == 0
-
-
-def test_prep_cache_invalidate_restores_byte_accounting(setup):
-    X, Qm, indexes = setup
-    eng = _engine(indexes, batch_buckets=(4,))
-    eng.search(Qm[:2], k=5, index="flat")
-    eng.search(Qm[:2], k=5, index="ivf", nprobe=4)
-    assert eng.prep_cache_bytes > 0
-    before = eng.prep_cache_bytes
-    eng.invalidate_prep_cache("flat")
-    assert 0 < eng.prep_cache_bytes < before
-    eng.invalidate_prep_cache()
-    assert eng.prep_cache_bytes == 0 and len(eng._prep_cache) == 0
-
-
-def test_snapshot_reports_hit_rate(setup):
+def test_snapshot_counts_prepared_rows(setup):
+    """``prep_misses`` counts the real rows prepared (pad rows not
+    included) and ``prep_hits`` stays 0: no row is looked up in a
+    cache, and the benchmark harness reads both keys."""
     X, Qm, indexes = setup
     eng = _engine({"flat": indexes["flat"]}, batch_buckets=(4,))
     eng.search(Qm[:2], k=5, index="flat")
-    eng.search(Qm[:2], k=5, index="flat")
+    eng.search(Qm[:2], k=5, index="flat")  # the same rows again
+    eng.search(Qm[2:5], k=5, index="flat")
     snap = eng.stats.snapshot()
-    assert snap["prep_hit_rate"] == pytest.approx(0.5)
-
-
-def test_pad_rows_not_cached(setup):
-    """Zero-pad rows of an underfilled bucket never enter the prep
-    cache — LRU capacity is spent on real queries only."""
-    X, Qm, indexes = setup
-    eng = _engine({"flat": indexes["flat"]}, batch_buckets=(4,))
-    eng.search(Qm[:2], k=5, index="flat")  # cold: 2 real + 2 pad rows
-    assert len(eng._prep_cache) == 2
-    # warm: Qm[1] hits, Qm[2] misses, 2 pad rows miss but stay uncached
-    eng.search(Qm[1:3], k=5, index="flat")
-    assert len(eng._prep_cache) == 3
+    assert snap["rows"] == 7 and snap["batches"] == 3
+    assert snap["prep_misses"] == 7
+    assert snap["prep_hits"] == 0
 
 
 def test_ivf_nprobe_clamps_before_grouping(setup):

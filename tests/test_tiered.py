@@ -17,7 +17,7 @@ from _hypothesis_compat import given, st
 from repro.core import ASHConfig
 from repro.data.synthetic import embedding_dataset
 from repro.index import AshIndex
-from repro.index.tiered import TieredIVFBackend
+from repro.index.tiered import ByteLRU, TieredIVFBackend
 from repro.serving.engine import EngineConfig, QueryEngine
 
 METRICS = ("dot", "l2", "cos")
@@ -85,6 +85,48 @@ def test_search_matches_ivf_bitwise(setup, metric):
                 hbm.search(Qm[:1], k=5, **kw),
                 msg=f"m=1 hot={hot} kw={kw}",
             )
+
+
+def _blk(n_floats):
+    return (np.zeros(n_floats, np.float32),)
+
+
+def test_byte_lru_evicts_least_recent_under_budget():
+    """The hot set's LRU: entries sized by their arrays' bytes, a hit
+    refreshes recency, and a put past the budget evicts the least
+    recently used entry first, with hits, misses and evictions
+    counted."""
+    lru = ByteLRU(3 * 64)
+    for key in "abc":
+        lru.put(key, _blk(16))  # 64 bytes each: exactly the budget
+    assert list(lru.keys()) == ["a", "b", "c"] and lru.nbytes == 192
+    assert lru.get("a") is not None  # "a" becomes the most recent
+    assert lru.get("z") is None
+    lru.put("d", _blk(16))  # evicts "b", now the least recent
+    assert list(lru.keys()) == ["c", "a", "d"]
+    assert lru.get("b") is None
+    # replacing "c" with 128 bytes frees its 64 first, then evicts "a"
+    lru.put("c", _blk(32))
+    assert list(lru.keys()) == ["d", "c"] and lru.nbytes == 192
+    assert lru.stats() == {
+        "entries": 2, "nbytes": 192, "max_bytes": 192, "hits": 1,
+        "misses": 2, "hit_rate": 1 / 3, "evictions": 2,
+    }
+
+
+def test_byte_lru_admits_then_evicts_an_oversize_entry():
+    """An entry larger than the whole budget is admitted (``put`` never
+    raises) and evicted at once, along with everything it displaced."""
+    lru = ByteLRU(100)
+    lru.put("small", _blk(4))
+    lru.put("big", _blk(64))  # 256 bytes > the 100-byte budget
+    assert len(lru) == 0 and lru.nbytes == 0
+    assert lru.evictions == 2
+    assert lru.get("big") is None and lru.misses == 1
+    lru.put("small", _blk(4))  # the budget still admits what fits
+    assert list(lru.keys()) == ["small"] and lru.nbytes == 16
+    lru.clear()  # a cold hot set, counters kept
+    assert len(lru) == 0 and lru.nbytes == 0 and lru.evictions == 2
 
 
 def test_zero_budget_pages_every_probe(setup):
